@@ -13,7 +13,6 @@ Public surface mirrors the reference's top level
 factories, and the global exception hook.
 """
 
-from chainermn_tpu import _compat  # noqa: F401  (jax version shims; keep first)
 from chainermn_tpu.comm import (
     CommunicatorBase,
     XlaCommunicator,

@@ -52,6 +52,7 @@ from chainermn_tpu.collectives.quantized import (
     quantized_wire_bytes,
     wire_ratio,
 )
+from chainermn_tpu.utils import on_tpu
 
 
 @dataclasses.dataclass
@@ -160,8 +161,8 @@ def measure_strategies(
         if db is not None and _CACHE[key]:
             _persist_measured(db, comm, intra, _CACHE[key])
         return _CACHE[key]
-    if jax.devices()[0].platform != "tpu":
-        _CACHE[key] = {}
+    if not on_tpu():
+        _CACHE[key] = {}  # not on a chip: nothing measured, nothing stored
         return {}
     import time
 

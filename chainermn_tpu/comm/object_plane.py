@@ -44,10 +44,7 @@ _ALIVE_KEY = "og/liveness/seed"
 
 # set by post_abort (the global except hook's MPI_Abort analog); checked by
 # every liveness probe so peers of a crashed rank raise within one probe
-# interval instead of waiting out their collective budgets. The flag is a
-# CHILD key under the directory on purpose: key_value_dir_get (present on
-# every jaxlib generation) only lists children, so probes on clients
-# without key_value_try_get can still read it without blocking.
+# interval instead of waiting out their collective budgets.
 _ABORT_KEY = "og/abort"
 _ABORT_FLAG = _ABORT_KEY + "/flag"
 
@@ -76,24 +73,14 @@ def post_abort(reason: str) -> None:
 
 
 def _read_abort(client) -> Optional[str]:
-    """The posted abort reason, or None — without ever blocking.
-
-    Newer clients expose ``key_value_try_get``; older ones only have
-    ``key_value_dir_get``, which returns instantly and lists the abort
-    flag because it is a child of the abort directory. A blocking get is
-    NOT an option here: this runs on every probe slice of every guarded
-    wait, and a missing key would stall it for the full get deadline."""
-    if hasattr(client, "key_value_try_get"):
-        try:
-            return client.key_value_try_get(_ABORT_FLAG)
-        except Exception:  # NotFound: nobody aborted
-            return None
+    """The posted abort reason, or None — without ever blocking
+    (``key_value_try_get``). A blocking get is NOT an option here: this
+    runs on every probe slice of every guarded wait, and a missing key
+    would stall it for the full get deadline."""
     try:
-        for _key, reason in client.key_value_dir_get(_ABORT_KEY):
-            return reason
-    except Exception:
-        pass
-    return None
+        return client.key_value_try_get(_ABORT_FLAG)
+    except Exception:  # NotFound: nobody aborted (or coordinator gone —
+        return None    # the liveness probe owns that case)
 
 
 def _client():

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from chainermn_tpu.utils import on_tpu
+
 _NEG_INF = -1e30  # finite stand-in: -inf breaks max/exp chains on the VPU
 
 # tuned default tile sizes (v5e, 2026-07-30 sweep — BASELINE.md); clamped
@@ -47,15 +49,8 @@ def _dimsem(dims=("parallel", "parallel", "arbitrary")):
     """Grid dims (batch*heads, tile, tile): the first two are independent,
     only the innermost accumulates — declaring this lets Mosaic pipeline
     the HBM block copies across grid steps instead of serializing
-    copy→compute. None when the API is unavailable."""
-    for cls_name in ("CompilerParams", "TPUCompilerParams"):
-        cls = getattr(pltpu, cls_name, None)
-        if cls is not None:
-            try:
-                return cls(dimension_semantics=dims)
-            except Exception:
-                continue
-    return None
+    copy→compute."""
+    return pltpu.CompilerParams(dimension_semantics=dims)
 
 
 _DIMSEM = _dimsem()
@@ -302,6 +297,7 @@ def _flash_fwd_3d(q, k, v, *, causal, scale, block_q, block_k, interpret,
         ],
         interpret=interpret,
         compiler_params=_DIMSEM,
+        name="flash_fwd",
     )(*operands)
     return out, lse
 
@@ -587,6 +583,7 @@ def _flash_bwd_3d(q, k, v, do, lse, dr, *, causal, scale, block_q, block_k,
                             pltpu.VMEM((lk, d), jnp.float32)],
             interpret=interpret,
             compiler_params=_DIMSEM_FUSED,
+            name="flash_bwd_fused",
         )(*operands)
 
     dq = pl.pallas_call(
@@ -600,6 +597,7 @@ def _flash_bwd_3d(q, k, v, do, lse, dr, *, causal, scale, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_DIMSEM,
+        name="flash_bwd_dq",
     )(*operands)
 
     # dk/dv iterate q innermost; same index maps with (b, ki, qi). Outputs
@@ -629,6 +627,7 @@ def _flash_bwd_3d(q, k, v, do, lse, dr, *, causal, scale, block_q, block_k,
                         pltpu.VMEM((bk, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_DIMSEM,
+        name="flash_bwd_dkv",
     )(*operands)
     return dq, dk, dv
 
@@ -761,7 +760,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q, block_k, interpret,
                          f"{layout!r}")
     block_k = _window_cap(block_k, window)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     if layout == "bhld":
         # head-major wire format: [B, H, L, D] ↔ [B*H, L, D] is a free
@@ -811,7 +810,7 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, window,
         # tune them independently
         block_q, block_k = bwd_blocks
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     block_k = _window_cap(block_k, window)
     sc = scale if scale is not None else q.shape[-1] ** -0.5
     if layout == "bhld":

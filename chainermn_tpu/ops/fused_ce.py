@@ -46,6 +46,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from chainermn_tpu.ops.flash_attention import _dimsem, _sds
+from chainermn_tpu.utils import match_vma, on_tpu
 
 _NEG = -1e30
 # rows-outer passes accumulate across the vocab (innermost) dim only →
@@ -53,6 +54,25 @@ _NEG = -1e30
 # vocab outer, so both its dims must be 'arbitrary'-safe
 _DIMSEM_ROWS = _dimsem(("parallel", "arbitrary"))
 _DIMSEM_DW = _dimsem(("arbitrary", "arbitrary"))
+
+
+def _traced(compute):
+    """Run ``compute`` under a traced, always-true predicate. The Pallas
+    interpreter evaluates the kernel on the caller's values, and under
+    shard_map's check_vma its fresh (axis-invariant) scratch then meets
+    batch-varying tiles in one primitive; a traced cond keeps the body
+    opaque to that check (flash_attention._fa_kernel does the same)."""
+    pl.when(pl.program_id(0) >= 0)(compute)
+
+
+def _dlogits_tile(h_ref, w_ref, y_ref, lse_ref, col0):
+    """softmax − onehot for one [R, VT] logits tile, rebuilt from lse."""
+    logits = jax.lax.dot_general(
+        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    p = jnp.exp(logits - lse_ref[...])
+    cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+    return p - jnp.where(cols == y_ref[...] - col0, 1.0, 0.0)
 
 
 def _fwd_kernel(h_ref, w_ref, y_ref, lse_ref, tl_ref, am_ref,
@@ -66,32 +86,34 @@ def _fwd_kernel(h_ref, w_ref, y_ref, lse_ref, tl_ref, am_ref,
         t_acc[:] = jnp.zeros_like(t_acc)
         a_acc[:] = jnp.zeros_like(a_acc)
 
-    logits = jax.lax.dot_general(
-        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)        # [R, VT]
-    m_prev = m_acc[:, :1]
-    m_cur = jnp.max(logits, -1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    alpha = jnp.exp(m_prev - m_new)
-    s_acc[:, :1] = s_acc[:, :1] * alpha + jnp.sum(
-        jnp.exp(logits - m_new), -1, keepdims=True)
-    m_acc[:, :1] = m_new
-    # target logit: the tile holding each row's label contributes it
-    y_loc = y_ref[...] - vi * vt                    # [R, 1]
-    cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    hit = cols == y_loc
-    t_acc[:, :1] += jnp.sum(jnp.where(hit, logits, 0.0), -1,
-                            keepdims=True)
-    # running argmax (metric): strictly-greater keeps the FIRST max,
-    # matching jnp.argmax tie-breaking
-    better = m_cur > m_prev
-    # first-match argmax without lax.argmax (Mosaic-safe): the smallest
-    # column index attaining the tile max
-    is_max = logits == m_cur
-    arg_cur = vi * vt + jnp.min(
-        jnp.where(is_max, cols, jnp.int32(2 ** 30)), -1, keepdims=True)
-    a_acc[:, :1] = jnp.where(better, arg_cur.astype(jnp.float32),
-                             a_acc[:, :1])
+    @_traced
+    def _compute():
+        logits = jax.lax.dot_general(
+            h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # [R, VT]
+        m_prev = m_acc[:, :1]
+        m_cur = jnp.max(logits, -1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        s_acc[:, :1] = s_acc[:, :1] * alpha + jnp.sum(
+            jnp.exp(logits - m_new), -1, keepdims=True)
+        m_acc[:, :1] = m_new
+        # target logit: the tile holding each row's label contributes it
+        y_loc = y_ref[...] - vi * vt                    # [R, 1]
+        cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
+        hit = cols == y_loc
+        t_acc[:, :1] += jnp.sum(jnp.where(hit, logits, 0.0), -1,
+                                keepdims=True)
+        # running argmax (metric): strictly-greater keeps the FIRST max,
+        # matching jnp.argmax tie-breaking
+        better = m_cur > m_prev
+        # first-match argmax without lax.argmax (Mosaic-safe): the
+        # smallest column index attaining the tile max
+        is_max = logits == m_cur
+        arg_cur = vi * vt + jnp.min(
+            jnp.where(is_max, cols, jnp.int32(2 ** 30)), -1, keepdims=True)
+        a_acc[:, :1] = jnp.where(better, arg_cur.astype(jnp.float32),
+                                 a_acc[:, :1])
 
     @pl.when(vi == nv - 1)
     def _fin():
@@ -107,16 +129,12 @@ def _dh_kernel(h_ref, w_ref, y_ref, lse_ref, dh_ref, dh_acc, *, vt, nv):
     def _init():
         dh_acc[:] = jnp.zeros_like(dh_acc)
 
-    logits = jax.lax.dot_general(
-        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    p = jnp.exp(logits - lse_ref[...])              # softmax tile
-    y_loc = y_ref[...] - vi * vt
-    cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    dl = p - jnp.where(cols == y_loc, 1.0, 0.0)     # [R, VT]
-    dh_acc[:] += jax.lax.dot_general(
-        dl.astype(w_ref.dtype), w_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [R, D]
+    @_traced
+    def _compute():
+        dl = _dlogits_tile(h_ref, w_ref, y_ref, lse_ref, vi * vt)
+        dh_acc[:] += jax.lax.dot_general(
+            dl.astype(w_ref.dtype), w_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [R, D]
 
     @pl.when(vi == nv - 1)
     def _fin():
@@ -131,16 +149,12 @@ def _dw_kernel(h_ref, w_ref, y_ref, lse_ref, dw_ref, dw_acc, *, vt, nr):
     def _init():
         dw_acc[:] = jnp.zeros_like(dw_acc)
 
-    logits = jax.lax.dot_general(
-        h_ref[...], w_ref[...], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    p = jnp.exp(logits - lse_ref[...])
-    y_loc = y_ref[...] - vi * vt
-    cols = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-    dl = p - jnp.where(cols == y_loc, 1.0, 0.0)
-    dw_acc[:] += jax.lax.dot_general(
-        h_ref[...], dl.astype(h_ref.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [D, VT]
+    @_traced
+    def _compute():
+        dl = _dlogits_tile(h_ref, w_ref, y_ref, lse_ref, vi * vt)
+        dw_acc[:] += jax.lax.dot_general(
+            h_ref[...], dl.astype(h_ref.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [D, VT]
 
     @pl.when(ri == nr - 1)
     def _fin():
@@ -154,7 +168,6 @@ def _pad_rows_to(x, n, fill=0):
     return jnp.pad(x, pad, constant_values=fill)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def fused_ce_head(h, w, y, block_rows: int = 256, block_v: int = 2048):
     """``mean CE( h @ w , y )`` + argmax accuracy, logits never in HBM.
 
@@ -164,18 +177,20 @@ def fused_ce_head(h, w, y, block_rows: int = 256, block_v: int = 2048):
     (y gets no gradient). Rows are padded internally to the block size;
     padded rows are masked out of both loss and accuracy.
 
-    Under shard_map's varying-axis tracking, a REPLICATED head kernel
-    next to batch-varying hidden states would fail the kernel's dot with
-    mixed vma operands; ``_fwd`` pcasts ``w`` to ``h``'s varying axes
-    (inside ``_fwd`` — the custom_vjp PRIMAL body is swapped for
-    ``_fwd_rule`` under differentiation, so a pcast here would never run
-    on a training path; ``_fwd`` is shared by both, and the pcast ``w``
-    rides the residuals into ``_bwd_rule``). The compiled TPU path then
-    runs fine inside shard_map (bench.py's gated LM config is exactly
-    that); the INTERPRET-mode fallback still trips on kernel-internal
-    constants under check_vma — on the CPU mesh, call it outside
-    shard_map or with check_vma=False.
+    Under shard_map's varying-axis tracking a REPLICATED head kernel next
+    to batch-varying hidden states would fail the kernel's dot with mixed
+    vma operands, so ``w`` is pcast to ``h``'s varying axes HERE, outside
+    the custom_vjp: autodiff then transposes the pcast into the psum that
+    gives ``dW`` the replicated type of the primal ``w`` (the contract
+    custom_vjp checks at trace time), exactly as it does for a plain
+    ``h @ w``. ``allreduce_grad`` sees an already-reduced gradient and
+    only scales it.
     """
+    return _ce_head(h, match_vma(w, h), y, block_rows, block_v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _ce_head(h, w, y, block_rows, block_v):
     loss, acc, _ = _fwd(h, w, y, block_rows, block_v)
     return loss, acc
 
@@ -203,15 +218,13 @@ def _run_fwd(h, w, y, block_rows, block_v, interpret):
         scratch_shapes=[pltpu.VMEM((block_rows, 128), jnp.float32)] * 4,
         interpret=interpret,
         compiler_params=_DIMSEM_ROWS,
+        name="fused_ce_fwd",
     )(h, w, y)
     return lse, tl, am
 
 
 def _fwd(h, w, y, block_rows, block_v):
-    from chainermn_tpu.utils import match_vma
-
-    interpret = jax.default_backend() != "tpu"
-    w = match_vma(w, h)  # shard_map vma alignment (see fused_ce_head)
+    interpret = not on_tpu()
     n0, d = h.shape
     v = w.shape[1]
     if v % block_v:
@@ -239,7 +252,7 @@ def _fwd_rule(h, w, y, block_rows, block_v):
 def _bwd_rule(block_rows, block_v, res, g):
     dloss = g[0]  # d(acc) is discarded — a metric, not an objective
     hp, w, yp, lse, n0 = res
-    interpret = jax.default_backend() != "tpu"
+    interpret = not on_tpu()
     n, d = hp.shape
     v = w.shape[1]
     nr, nv = n // block_rows, v // block_v
@@ -267,6 +280,7 @@ def _bwd_rule(block_rows, block_v, res, g):
         scratch_shapes=[pltpu.VMEM((block_rows, d), jnp.float32)],
         interpret=interpret,
         compiler_params=_DIMSEM_ROWS,
+        name="fused_ce_dh",
     )(hp, w, yb, lse_b)
 
     # the dW pass holds a [D, VT] f32 scratch PLUS the [D, VT] weight
@@ -299,6 +313,7 @@ def _bwd_rule(block_rows, block_v, res, g):
         scratch_shapes=[pltpu.VMEM((d, bv_dw), jnp.float32)],
         interpret=interpret,
         compiler_params=_DIMSEM_DW,
+        name="fused_ce_dw",
     )(hp, w, yb, lse_b)
 
     c = dloss / n0
@@ -306,7 +321,7 @@ def _bwd_rule(block_rows, block_v, res, g):
             None)
 
 
-fused_ce_head.defvjp(_fwd_rule, _bwd_rule)
+_ce_head.defvjp(_fwd_rule, _bwd_rule)
 
 
 def fused_lm_loss(model, params, x, y, train=True, mutable=None,

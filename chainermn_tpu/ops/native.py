@@ -8,8 +8,10 @@ XLA's, so the compiled layer here covers the host data path:
 (see chainermn_tpu/training/loader.py).
 
 Builds lazily with g++ on first use (pybind11 is not in the toolchain; a
-plain C ABI + ctypes is). Falls back to numpy implementations when no
-compiler is available — same semantics, fewer threads.
+plain C ABI + ctypes is) from ``native/chainermn_native.cpp`` — the
+library is gitignored, so a checkout always builds its own. When the
+build fails the numpy implementations take over (same semantics, fewer
+threads) and a ``RuntimeWarning`` carries the compiler's message.
 """
 
 from __future__ import annotations
@@ -18,6 +20,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -33,20 +36,25 @@ def _build_and_load() -> Optional[ctypes.CDLL]:
     so = os.path.join(_SRC_DIR, "libchainermn_native.so")
     src = os.path.join(_SRC_DIR, "chainermn_native.cpp")
     if not os.path.exists(so) or (
-        os.path.exists(src) and os.path.getmtime(src) > os.path.getmtime(so)
+        os.path.getmtime(src) > os.path.getmtime(so)
     ):
+        # concurrent first users each build, then publish atomically
+        tmp = f"{so[:-3]}.{os.getpid()}.tmp.so"
         try:
             subprocess.run(
                 ["g++", "-O3", "-std=c++17", "-fPIC", "-pthread", "-shared",
-                 "-o", so, src],
+                 "-o", tmp, src],
                 check=True, capture_output=True, timeout=120,
             )
-        except Exception:
+            os.replace(tmp, so)
+        except (OSError, subprocess.SubprocessError) as e:
+            err = getattr(e, "stderr", None) or b""
+            warnings.warn(
+                f"chainermn_native: building {src} failed ({e}); the numpy "
+                f"fallbacks take over.\n{err.decode('utf-8', 'replace')}",
+                RuntimeWarning)
             return None
-    try:
-        lib = ctypes.CDLL(so)
-    except OSError:
-        return None
+    lib = ctypes.CDLL(so)
 
     i64p = ctypes.POINTER(ctypes.c_int64)
     vpp = ctypes.POINTER(ctypes.c_void_p)

@@ -22,6 +22,7 @@ from typing import Any, NamedTuple, Optional
 
 import jax
 import optax
+from jax.sharding import NamedSharding, PartitionSpec
 
 from chainermn_tpu.comm.base import CommunicatorBase
 from chainermn_tpu.optimizers.zero import (  # noqa: F401
@@ -241,10 +242,30 @@ def create_multi_node_optimizer(
                 inner=inner, prev_grads=reduced, is_first=jnp.array(False)
             )
 
+    def on_mesh(state):
+        """A state born at the driver level lives where the step will
+        leave it. optax creates leaves no parameter places (adam's step
+        counter): uncommitted, on one device. The step returns them on the
+        mesh, the array's type changes with that, and jit would trace and
+        compile the step a second time for its second call. Leaves traced
+        inside jit/shard_map pass through."""
+        rep = NamedSharding(communicator.mesh, PartitionSpec())
+
+        def place(leaf):
+            if not isinstance(leaf, jax.Array) or isinstance(
+                    leaf, jax.core.Tracer):
+                return leaf
+            sh = leaf.sharding
+            if isinstance(sh, NamedSharding) and sh.mesh == rep.mesh:
+                return leaf
+            return jax.device_put(leaf, rep)
+
+        return jax.tree_util.tree_map(place, state)
+
     if not stateful:
 
         def init(params):
-            return inner_init(params)
+            return on_mesh(inner_init(params))
 
         def update(grads, state, params=None, **extra):
             grads, _ = reduce_fn(grads, ())
@@ -256,7 +277,7 @@ def create_multi_node_optimizer(
 
     def init_st(params):
         return _ReducerWrappedState(
-            inner=inner_init(params),
+            inner=on_mesh(inner_init(params)),
             reducer=reducer.init_global(params),
         )
 

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 
 from chainermn_tpu.ops.flash_attention import DEFAULT_BLOCKS
+from chainermn_tpu.utils import on_tpu
 from jax import lax
 
 
@@ -162,7 +163,7 @@ def _ring_flash_fwd(q, k, v, axis_name, causal, scale, block_q, block_k,
     from chainermn_tpu.utils import match_vma
 
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     scale = scale if scale is not None else q.shape[-1] ** -0.5
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)
@@ -210,7 +211,7 @@ def _ring_flash_bwd(axis_name, causal, scale, block_q, block_k, interpret,
 
     q, k, v, out, lse = res
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = not on_tpu()
     sc = scale if scale is not None else q.shape[-1] ** -0.5
     n = lax.axis_size(axis_name)
     my = lax.axis_index(axis_name)

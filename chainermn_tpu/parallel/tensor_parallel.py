@@ -26,6 +26,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from chainermn_tpu.utils import match_vma
+
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def copy_to_tp_region(x, axis_name):
@@ -42,11 +44,13 @@ def copy_to_tp_region(x, axis_name):
 
 
 def _copy_fwd(x, axis_name):
-    return x, None
+    # a zero-size witness of x's varying axes: custom_vjp checks that the
+    # cotangent comes back with the primal's type
+    return x, x[..., :0]
 
 
-def _copy_bwd(axis_name, _, g):
-    return (lax.psum(g, axis_name),)
+def _copy_bwd(axis_name, witness, g):
+    return (match_vma(lax.psum(g, axis_name), witness),)
 
 
 copy_to_tp_region.defvjp(_copy_fwd, _copy_bwd)
@@ -136,7 +140,7 @@ def vocab_parallel_cross_entropy(logits, targets, axis_name: str):
     logits = logits.astype(jnp.float32)
     # the max shift is gradient-neutral (it cancels in softmax); pmax has
     # no differentiation rule, so route it through a zero-cotangent VJP
-    m = _pmax_stop_gradient(jnp.max(logits, -1), axis_name)
+    m = pmax_stop_gradient(jnp.max(logits, -1), axis_name)
     z = lax.psum(jnp.sum(jnp.exp(logits - m[..., None]), -1), axis_name)
     local_t = targets - lo
     in_shard = (local_t >= 0) & (local_t < vl)
@@ -146,23 +150,9 @@ def vocab_parallel_cross_entropy(logits, targets, axis_name: str):
     return m + jnp.log(z) - tlogit
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
-def _pmax_stop_gradient(x, axis_name):
+def pmax_stop_gradient(x, axis_name):
     """lax.pmax treated as a constant by differentiation (no pmax VJP
-    exists in JAX; the logsumexp max shift needs none)."""
-    return lax.pmax(x, axis_name)
-
-
-def _pmax_sg_fwd(x, axis_name):
-    return lax.pmax(x, axis_name), None
-
-
-def _pmax_sg_bwd(axis_name, _, g):
-    return (jnp.zeros_like(g),)
-
-
-_pmax_stop_gradient.defvjp(_pmax_sg_fwd, _pmax_sg_bwd)
-
-# public alias: a pmax whose gradient is defined (zero cotangent) — for
-# metrics computed alongside a differentiated loss
-pmax_stop_gradient = _pmax_stop_gradient
+    exists in JAX): for the logsumexp max shift, which needs none, and
+    for metrics computed alongside a differentiated loss. The gradient
+    stops BEFORE the collective, so its missing rule is never asked."""
+    return lax.pmax(lax.stop_gradient(x), axis_name)

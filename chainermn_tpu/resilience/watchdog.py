@@ -59,7 +59,7 @@ class Watchdog:
     """One process's heartbeat publisher + peer monitor.
 
     ``client`` duck-types the jax.distributed coordinator client
-    (``key_value_set``, ``key_value_try_get``/``blocking_key_value_get``)
+    (``key_value_set``, ``key_value_try_get``)
     so tests can drive it with a fake; production passes None and the
     real client is resolved lazily.
     """
@@ -131,14 +131,9 @@ class Watchdog:
 
     @staticmethod
     def _try_get(client, key: str) -> Optional[str]:
-        if hasattr(client, "key_value_try_get"):
-            try:
-                return client.key_value_try_get(key)
-            except Exception:  # NotFound
-                return None
         try:
-            return client.blocking_key_value_get(key, 200)
-        except Exception:
+            return client.key_value_try_get(key)
+        except Exception:  # NotFound
             return None
 
     # -- monitoring ------------------------------------------------------

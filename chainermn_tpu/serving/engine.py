@@ -168,7 +168,7 @@ class Engine:
         self.cur_tokens = np.zeros(config.n_slots, np.int32)
         # per-slot sampling state, threaded through the compiled
         # programs (sampling.py encoding: temp<=0 greedy, top_k<=0 full)
-        self._keys = init_keys(config.n_slots)
+        self._keys = self.steps.place(init_keys(config.n_slots))
         self._temps = np.zeros(config.n_slots, np.float32)
         self._topks = np.zeros(config.n_slots, np.int32)
         self._eos = np.full(config.n_slots, -1, np.int32)

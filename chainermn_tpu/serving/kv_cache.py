@@ -494,9 +494,22 @@ class ServingStep:
             repl, cache_sh = self._shardings(mesh, axis)
             kw = dict(in_shardings=(repl, cache_sh, repl),
                       out_shardings=(repl, cache_sh))
+        self.params = self.place(self.params)
+        self.cache = self.place(self.cache, pages=True)
         self._decode_jit = jax.jit(_decode, donate_argnums=donate_args,
                                    **kw)
         self._donate = donate_args
+
+    def place(self, tree, pages: bool = False):
+        """Commit parameters, pages or per-slot state to this step's
+        mesh; identity without one. They live there from the start:
+        arguments committed elsewhere (a trainer's chip 0) would be copied
+        across on every dispatch, and a tree that moves onto the mesh
+        between two calls changes type and retraces the program."""
+        if self._mesh is None:
+            return tree
+        repl, cache_sh = self._shardings(self._mesh, self._axis)
+        return jax.device_put(tree, cache_sh if pages else repl)
 
     def _shardings(self, mesh, axis):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -836,12 +849,12 @@ class ServingStep:
         params are per-call arguments to every jitted program."""
         if self.src_model.qkv_layout == "bhld":
             params = bhld_to_blhd_params(self.src_model, params)
-        self.params = params
+        self.params = self.place(params)
 
     def reset(self):
         """Zero every page and cursor (all slots freed)."""
         dt = (None if self.kv_dtype == "int8-block"
               else self.cache["block_0"]["k"].dtype)
-        self.cache = init_cache(
+        self.cache = self.place(init_cache(
             self.model, self.n_slots, self.capacity, dt,
-            kv_dtype=self.kv_dtype)
+            kv_dtype=self.kv_dtype), pages=True)

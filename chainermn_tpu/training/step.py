@@ -83,9 +83,8 @@ def make_data_parallel_train_step(
     ``scan_steps=K`` compiles K optimizer steps into ONE XLA program via
     ``lax.scan``: the step signature becomes ``step(state, xs, ys)`` where
     ``xs``/``ys`` carry a leading K axis (one batch per inner step) and the
-    returned metrics gain a leading K axis. One dispatch per K steps — on
-    hosts with a high per-dispatch floor (e.g. a tunneled chip) this is the
-    difference between measuring dispatch latency and measuring the device.
+    returned metrics gain a leading K axis. One dispatch per K steps: the
+    host's per-dispatch cost is paid once per K.
 
     ``grad_accum=N`` splits each shard's batch into N micro-batches and
     accumulates gradients over a ``lax.scan`` — same optimizer math as the

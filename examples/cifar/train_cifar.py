@@ -18,10 +18,6 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
 from chainermn_tpu.datasets.standard_formats import load_cifar
 from chainermn_tpu.iterators import SerialIterator
 from chainermn_tpu.models.resnet import CifarResNet

@@ -24,10 +24,6 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
 from chainermn_tpu.datasets.toy import ArrayDataset
 from chainermn_tpu.iterators import SerialIterator
 from chainermn_tpu.models.resnet import ResNet50

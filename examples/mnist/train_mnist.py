@@ -23,9 +23,6 @@ import numpy as np
 import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()  # make JAX_PLATFORMS=cpu work even under site hooks
 from chainermn_tpu.datasets.standard_formats import load_mnist
 from chainermn_tpu.iterators import SerialIterator
 from chainermn_tpu.models import MLP

@@ -35,10 +35,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 import numpy as np
 
 import jax
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
 import flax.linen as nn
 import jax.numpy as jnp
 import optax

@@ -26,14 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import chainermn_tpu  # installs the jax.shard_map shim (_compat)
+import chainermn_tpu
 
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
 from chainermn_tpu.datasets.toy import synthetic_translation
 from chainermn_tpu.iterators import SerialIterator
 from chainermn_tpu.models.seq2seq import Seq2Seq, pad_batch, seq2seq_loss

@@ -28,10 +28,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
 import numpy as np
 
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
 import jax
 import jax.numpy as jnp
 import optax

@@ -21,15 +21,11 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
+import jax
 import numpy as np
+import optax
 
 import chainermn_tpu
-from chainermn_tpu.utils import ensure_platform
-
-ensure_platform()
-
-import jax
-import optax
 
 from chainermn_tpu.iterators import SerialIterator
 from chainermn_tpu.models.transformer import TransformerLM, lm_loss_with_aux
@@ -148,12 +144,19 @@ def main():
                                seed=0)
     train = chainermn_tpu.scatter_dataset(train, comm, shuffle=True, seed=0)
 
-    attention = ("flash" if jax.default_backend() == "tpu"
-                 else "reference")
-    if (args.window or args.qkv_layout == "bhld"
-            or (args.n_kv_heads and attention == "reference")):
-        attention = "flash"  # interpreted off-TPU; required for window
-        #                      and for the head-major bhld layout
+    # the Pallas kernels are the path on a TPU; the CPU mesh takes the
+    # XLA reference because the interpreted kernels are slow, not wrong.
+    # Any other backend is refused rather than handed the reference.
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise SystemExit(f"unsupported backend {backend!r}: the attention "
+                         "kernels target TPU (CPU runs the reference)")
+    attention = "flash" if backend == "tpu" else "reference"
+    if args.window or args.qkv_layout == "bhld" or args.n_kv_heads:
+        attention = "flash"  # interpreted on the CPU; required for window,
+        #                      GQA and the head-major bhld layout
+    if comm.is_master:
+        print(f"backend: {backend}  attention: {attention}")
     lm_kw = dict(
         n_kv_heads=args.n_kv_heads or None,
         attention_window=args.window or None,

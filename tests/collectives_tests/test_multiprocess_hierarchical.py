@@ -36,7 +36,7 @@ import numpy as np
 import jax.numpy as jnp
 import optax
 
-import chainermn_tpu  # installs the jax.shard_map shim (_compat)
+import chainermn_tpu
 from chainermn_tpu.collectives import HierarchicalReducer, HierTopology
 from chainermn_tpu.models import MLP
 from chainermn_tpu.training.step import make_data_parallel_train_step

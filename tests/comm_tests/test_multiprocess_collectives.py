@@ -34,7 +34,7 @@ sys.path.insert(0, os.environ["REPO_ROOT"])
 import numpy as np
 import jax.numpy as jnp
 
-import chainermn_tpu  # installs the jax.shard_map shim (_compat)
+import chainermn_tpu
 
 from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -30,3 +30,27 @@ def test_attention_blocks_plumb_through_lm():
     out = model.apply({"params": params}, jnp.asarray(tok))
     assert out.shape == (2, 64, 32)
     assert np.isfinite(np.asarray(out)).all()
+
+
+def test_on_chip_sweep_with_no_working_candidate_raises(monkeypatch):
+    """'Not on a chip' returns the defaults; 'every candidate failed on
+    the chip' must not look the same — it raises, naming each failure."""
+    import sys
+
+    import pytest
+
+    import chainermn_tpu.ops.autotune as autotune
+
+    # the package re-exports the FUNCTION under the submodule's name
+    fa = sys.modules["chainermn_tpu.ops.flash_attention"]
+
+    def refuse(*a, **kw):
+        raise ValueError("Mosaic says no")
+
+    _CACHE.clear()
+    monkeypatch.setattr(autotune, "on_tpu", lambda: True)
+    monkeypatch.setattr(fa, "flash_attention", refuse)
+    with pytest.raises(RuntimeError, match="every candidate failed"):
+        tune_flash_blocks(1, 128, 2, 32, include_backward=False,
+                          candidates=((128, 128), (64, 128)))
+    assert not _CACHE        # a failed sweep is not memoized as a result

@@ -155,3 +155,47 @@ def test_dw_tile_fallback_non_dividing_halved_tile():
     for a, b in zip(gf, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=2e-4, atol=2e-6)
+
+
+def test_fused_lm_loss_through_data_parallel_step(comm):
+    """The path the gated LM rows and chip_smoke.py take: fused_lm_loss
+    differentiated INSIDE make_data_parallel_train_step's shard_map
+    (check_vma on). The head kernel is replicated and the hidden states
+    vary over the mesh axis, so dW must come back reduced to the primal's
+    replicated type — the custom_vjp contract jax checks at trace time —
+    and allreduce_grad must then only scale it. One SGD(lr=1) step turns
+    the parameter delta into the gradient; loss and every gradient leaf
+    must match the unfused lm_loss_with_aux step."""
+    import functools
+
+    import chainermn_tpu
+    from chainermn_tpu.models.transformer import (
+        TransformerLM, lm_loss_with_aux)
+    from chainermn_tpu.training.step import make_data_parallel_train_step
+
+    model = TransformerLM(vocab=BV * 2, d_model=D, n_heads=2, n_layers=1,
+                          d_ff=64, max_len=16, pos_emb="rope",
+                          attention="reference")
+    rs = np.random.RandomState(4)
+    toks = rs.randint(0, BV * 2, size=(2 * comm.size, 17)).astype(np.int32)
+    x, y = toks[:, :-1], toks[:, 1:]
+    params = comm.bcast_data(
+        model.init(jax.random.PRNGKey(0), x[:1])["params"])
+    opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(1.0), comm)
+
+    def one_step(loss_fn):
+        step = make_data_parallel_train_step(model, opt, comm,
+                                             loss_fn=loss_fn, donate=False)
+        (new, _), m = step((params, opt.init(params)), x, y)
+        grads = jax.tree_util.tree_map(lambda a, b: a - b, params, new)
+        return float(m["main/loss"]), float(m["main/accuracy"]), grads
+
+    lf, af, gf = one_step(functools.partial(fused_lm_loss, block_rows=BR,
+                                            block_v=BV))
+    lr, ar, gr = one_step(lm_loss_with_aux)
+    np.testing.assert_allclose(lf, lr, rtol=1e-5)
+    np.testing.assert_allclose(af, ar, rtol=1e-6)
+    jax.tree_util.tree_map(
+        lambda a, b: np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), rtol=3e-4, atol=2e-6),
+        gf, gr)

@@ -568,11 +568,7 @@ def test_lm_fsdp_scan_matches_replicated(comm):
     params = model.init(jax.random.PRNGKey(0), toks[:1, :-1])["params"]
 
     # baseline: the UNFUSED XLA loss — the comparison then also
-    # cross-validates the fused-CE kernel against XLA's CE. (The fused
-    # loss inside the shard_map baseline would need the interpret-mode
-    # Pallas kernel under check_vma, which trips on kernel-internal
-    # constants — a CPU-interpreter limitation; the compiled TPU path
-    # runs it inside shard_map daily via bench.py's gate.)
+    # cross-validates the fused-CE kernel against XLA's CE
     ropt = chainermn_tpu.create_multi_node_optimizer(optax.adam(1e-2),
                                                      comm)
     rparams = comm.bcast_data(params)

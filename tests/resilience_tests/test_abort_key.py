@@ -1,9 +1,7 @@
-"""The abort poison key must be readable WITHOUT blocking on every
-jaxlib client generation — newer ones have ``key_value_try_get``, older
-ones only ``key_value_dir_get`` (which lists children, which is why the
-flag is a child of the abort directory). A probe that cannot see the
-key silently disables the watchdog's whole bounded-abort contract, so
-both read paths are pinned here."""
+"""The abort poison key must be readable WITHOUT blocking
+(``key_value_try_get``). A probe that cannot see the key silently
+disables the watchdog's whole bounded-abort contract, so the read path
+is pinned here."""
 
 from chainermn_tpu.comm.object_plane import (
     _ABORT_FLAG,
@@ -13,7 +11,8 @@ from chainermn_tpu.comm.object_plane import (
 
 
 class TryGetClient:
-    """Newer client: non-blocking point read, raises on missing key."""
+    """Non-blocking point read, raises on a missing key — the installed
+    jaxlib client's contract."""
 
     def __init__(self):
         self.kv = {}
@@ -24,20 +23,7 @@ class TryGetClient:
         raise KeyError(key)
 
 
-class DirGetClient:
-    """Older client: no try_get; only the directory listing read."""
-
-    def __init__(self):
-        self.kv = {}
-
-    def key_value_dir_get(self, prefix):
-        return sorted(
-            (k, v) for k, v in self.kv.items()
-            if k.startswith(prefix + "/"))
-
-
-def test_flag_is_a_child_of_the_abort_directory():
-    # the property the dir_get fallback depends on
+def test_flag_lives_under_the_abort_directory():
     assert _ABORT_FLAG.startswith(_ABORT_KEY + "/")
 
 
@@ -48,15 +34,8 @@ def test_try_get_client_reads_abort():
     assert _read_abort(client) == "peer 1 died"
 
 
-def test_dir_get_client_reads_abort():
-    client = DirGetClient()
-    assert _read_abort(client) is None
-    client.kv[_ABORT_FLAG] = "peer 1 died"
-    assert _read_abort(client) == "peer 1 died"
-
-
-def test_dir_get_ignores_unrelated_keys():
-    client = DirGetClient()
+def test_read_abort_ignores_unrelated_keys():
+    client = TryGetClient()
     client.kv["og/abortive/other"] = "not an abort"
     client.kv["og/liveness/seed"] = "1"
     assert _read_abort(client) is None
@@ -64,7 +43,14 @@ def test_dir_get_ignores_unrelated_keys():
 
 def test_read_abort_swallows_client_errors():
     class BrokenClient:
-        def key_value_dir_get(self, prefix):
+        def key_value_try_get(self, key):
             raise RuntimeError("coordinator gone")
 
     assert _read_abort(BrokenClient()) is None
+
+
+def test_installed_client_has_try_get():
+    # the one branch kept is the one the installed jaxlib takes
+    from jax._src.lib import _jax
+
+    assert hasattr(_jax.DistributedRuntimeClient, "key_value_try_get")

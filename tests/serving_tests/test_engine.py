@@ -61,13 +61,17 @@ def test_eos_retirement_matches_generate():
     prompt = rng.randint(0, 43, (4,)).astype(np.int32)
     n_new = 8
     ref = np.asarray(generate(model, params, prompt[None], n_new))[0, 4:]
-    eos = int(ref[2])                 # force a mid-stream retirement
+    # force a mid-stream retirement: the stream ends at the FIRST
+    # occurrence of the eos id, so pick one whose first occurrence is
+    # past the prefill token (the stream may repeat a token early)
+    cut = next(i for i in range(1, n_new) if ref[i] not in ref[:i])
+    eos = int(ref[cut])
     cfg = EngineConfig(n_slots=1, capacity=16, max_new_tokens=n_new,
                        prefill_cohort=1, buckets=[4, 16])
     eng = Engine(model, params, cfg)
     req = eng.submit(prompt, eos_id=eos)
     eng.run_until_drained()
-    assert req.tokens == list(ref[:3])          # ends WITH the eos token
+    assert req.tokens == list(ref[:cut + 1])    # ends WITH the eos token
     assert req.state == "done"
 
 

@@ -3,8 +3,7 @@
 The reference's examples pay iterator.next() + concat + to_gpu on the host
 every step (SURVEY.md §3.1); here the native C++ double-buffered gather
 assembles batches off-thread and the uint8→float decode runs on device
-inside the compiled step. tools/bench_loader.py measures the overlap
-(loader-fed ≥95% of pre-staged); these tests pin the functional wiring:
+inside the compiled step. These tests pin the functional wiring:
 mmap'd uint8 file → PrefetchingLoader → StandardUpdater → convergence.
 """
 

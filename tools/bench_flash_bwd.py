@@ -28,6 +28,9 @@ def main():
     from jax import lax
 
     from chainermn_tpu.ops.flash_attention import flash_attention
+    from chainermn_tpu.utils import use_compile_cache
+
+    use_compile_cache()
 
     B = int(sys.argv[1]) if len(sys.argv) > 1 else 8
     H = int(sys.argv[2]) if len(sys.argv) > 2 else 12

@@ -265,6 +265,9 @@ def main(argv=None):
 
     import jax
 
+    from chainermn_tpu.utils import use_compile_cache
+
+    use_compile_cache()
     model, params = _model(args)
     backend = jax.default_backend()
     prompt = np.arange(1, 1 + args.prompt_len,

@@ -17,7 +17,11 @@ serve_lm.py), queues a deterministic batch of prompts, and drains:
   KVHandoff wire (``--wire-format`` f32 | int8-block) → decode engine,
   exposed to ``corrupt_handoff`` faults (fallback = clean re-prefill).
   Add ``--async-conveyor`` to overlap the wire with decode steps.
-* ``--hosts N --host-rank R`` — REAL cross-process disaggregation:
+* ``--hosts N --host-rank R`` — REAL cross-process disaggregation (a
+  CPU-ONLY drill: every rank is an independent process that takes the
+  default backend, and a chip belongs to one process — launch each rank
+  with ``JAX_PLATFORMS=cpu``; serving one engine per chip is the
+  in-process ``Router`` over one-device meshes, see chip_smoke.py):
   ranks 0..P-1 (``--prefill-hosts P``, default 1) prefill and ship
   seq/SHA-framed handoffs; ranks P..N-1 adopt and decode. The wire is
   picked by ``--transport``: ``fs`` (default) is the restart-tolerant
@@ -739,7 +743,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from chainermn_tpu.resilience.supervisor import main_exit_code
+    from chainermn_tpu.utils import use_compile_cache
 
+    use_compile_cache()
     return main_exit_code(lambda: serve(args))
 
 

@@ -145,6 +145,9 @@ def _aot_compile_fn(topology_name):
 
 
 def main():
+    from chainermn_tpu.utils import use_compile_cache
+
+    use_compile_cache()
     argv = sys.argv[1:]
     grad_bytes = int(_flag(argv, "--grad-bytes", DEFAULT_GRAD_BYTES))
     db_path = _flag(argv, "--db")

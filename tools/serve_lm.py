@@ -226,7 +226,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from chainermn_tpu.resilience.supervisor import main_exit_code
+    from chainermn_tpu.utils import use_compile_cache
 
+    use_compile_cache()
     return main_exit_code(lambda: serve(args))
 
 
