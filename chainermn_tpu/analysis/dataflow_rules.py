@@ -886,8 +886,9 @@ _DRAFT_SAMPLERS = {"sample_tokens"}
 #: target-side verification — the blessing that makes a commit legal
 _VERIFY_HINTS = ("verify", "accept")
 #: commit-style sinks a raw draft sample must never reach
-_COMMIT_SINKS = {"emit", "_emit", "commit", "commit_token",
-                 "record_token", "append", "push", "send", "publish"}
+_COMMIT_SINKS = {"emit", "_emit", "_replay", "commit", "commit_token",
+                 "record_token", "record_tokens", "append", "push", "send",
+                 "publish"}
 
 
 def _call_name(call: ast.Call) -> str:

@@ -286,6 +286,7 @@ class FleetReport:
         raws = [r.raw() for r in reports]
         ttft: List[float] = []
         gaps: List[float] = []
+        waits: List[float] = []
         qd: List[int] = []
         occ: List[float] = []
         submitted = completed = aborted = tokens = host_bytes = 0
@@ -294,6 +295,7 @@ class FleetReport:
         for raw in raws:
             ttft.extend(raw["ttft_s"])
             gaps.extend(raw["token_gap_s"])
+            waits.extend(raw.get("queue_wait_s", ()))
             qd.extend(raw["queue_depth_samples"])
             occ.extend(raw["occupancy_samples"])
             submitted += raw["submitted"]
@@ -324,6 +326,7 @@ class FleetReport:
             "draft_tokens_accepted": accepted,
             "ttft_ms": _dist_ms(ttft),
             "itl_ms": _dist_ms(gaps),
+            "queue_wait_ms": _dist_ms(waits),
             "queue_depth": {"mean": (sum(qd) / len(qd) if qd
                                      else float("nan")),
                             "max": max(qd) if qd else 0},

@@ -227,27 +227,29 @@ class TransformerBlock(nn.Module):
                     # zero-lane-absorption property (test_decode_bitwise)
                     # makes bitwise-invisible — chunked == monolithic,
                     # token for token AND cache byte for cache byte.
-                    kc = ck.value.astype(jnp.float32)
-                    vc = cv.value.astype(jnp.float32)
-                    if hkv != self.n_heads:
-                        kc = jnp.repeat(kc, self.n_heads // hkv, axis=2)
-                        vc = jnp.repeat(vc, self.n_heads // hkv, axis=2)
-                    s = jnp.einsum("bqhd,bkhd->bhqk",
-                                   q.astype(jnp.float32), kc) * dh ** -0.5
-                    keys = jnp.arange(cap)
-                    # no-wrap contract: cache slot j holds absolute
-                    # position j, so causality is just keys <= row; rows
-                    # beyond each slot's fill hold garbage but only
-                    # padding queries (ignored downstream) can see them
-                    visible = keys <= rows[..., None]
-                    if self.attention_window is not None:
-                        visible &= keys > (rows[..., None]
-                                           - self.attention_window)
-                    vis = visible[:, None] if per_slot else visible[None, None]
-                    s = jnp.where(vis, s, -jnp.inf)
-                    att = jnp.einsum("bhqk,bkhd->bqhd",
-                                     jax.nn.softmax(s, -1),
-                                     vc).astype(q.dtype)
+                    with jax.named_scope("attend_cache"):
+                        kc = ck.value.astype(jnp.float32)
+                        vc = cv.value.astype(jnp.float32)
+                        if hkv != self.n_heads:
+                            kc = jnp.repeat(kc, self.n_heads // hkv, axis=2)
+                            vc = jnp.repeat(vc, self.n_heads // hkv, axis=2)
+                        s = jnp.einsum("bqhd,bkhd->bhqk",
+                                       q.astype(jnp.float32), kc) * dh ** -0.5
+                        keys = jnp.arange(cap)
+                        # no-wrap contract: cache slot j holds absolute
+                        # position j, so causality is just keys <= row; rows
+                        # beyond each slot's fill hold garbage but only
+                        # padding queries (ignored downstream) can see them
+                        visible = keys <= rows[..., None]
+                        if self.attention_window is not None:
+                            visible &= keys > (rows[..., None]
+                                               - self.attention_window)
+                        vis = (visible[:, None] if per_slot
+                               else visible[None, None])
+                        s = jnp.where(vis, s, -jnp.inf)
+                        att = jnp.einsum("bhqk,bkhd->bqhd",
+                                         jax.nn.softmax(s, -1),
+                                         vc).astype(q.dtype)
                 elif self.attention == "reference":
                     kr, vr = k, v
                     if hkv != self.n_heads:
@@ -260,36 +262,38 @@ class TransformerBlock(nn.Module):
                                           block_k=bk,
                                           window=self.attention_window)
             else:
-                kc = ck.value.astype(jnp.float32)
-                vc = cv.value.astype(jnp.float32)
-                if hkv != self.n_heads:
-                    kc = jnp.repeat(kc, self.n_heads // hkv, axis=2)
-                    vc = jnp.repeat(vc, self.n_heads // hkv, axis=2)
-                # squeezed-q contractions: on XLA these are bitwise-equal
-                # to the corresponding row of the full-forward [L, L]
-                # attention; the q=1 "bqhd,bkhd->bhqk"/"bhqk,bkhd->bqhd"
-                # pair is NOT (different reduction order). The serving
-                # bitwise-parity guarantee lives or dies here —
-                # docs/serving.md §numerics.
-                s = jnp.einsum("bhd,bkhd->bhk",
-                               q[:, 0].astype(jnp.float32),
-                               kc) * dh ** -0.5
-                row = rows[..., -1]              # [b] per-slot, else ()
-                keys = jnp.arange(cap)
-                # ring inversion: slot j holds token position
-                # row - ((row - j) mod cap) — the newest position ≡ j
-                # (mod cap) not exceeding row. Unwritten slots land
-                # negative; wrapped-over history is unreachable by
-                # construction. With cap == max_len and no wrap this
-                # reduces exactly to the old `keys <= row` mask.
-                kpos = row[..., None] - (row[..., None] - keys) % cap
-                visible = kpos >= 0
-                if self.attention_window is not None:
-                    visible &= kpos > row[..., None] - self.attention_window
-                vis = visible[:, None] if per_slot else visible[None, None]
-                s = jnp.where(vis, s, -jnp.inf)
-                att = jnp.einsum("bhk,bkhd->bhd",
-                                 jax.nn.softmax(s, -1), vc)[:, None]
+                with jax.named_scope("attend_cache"):
+                    kc = ck.value.astype(jnp.float32)
+                    vc = cv.value.astype(jnp.float32)
+                    if hkv != self.n_heads:
+                        kc = jnp.repeat(kc, self.n_heads // hkv, axis=2)
+                        vc = jnp.repeat(vc, self.n_heads // hkv, axis=2)
+                    # squeezed-q contractions: on XLA these are bitwise-equal
+                    # to the corresponding row of the full-forward [L, L]
+                    # attention; the q=1 "bqhd,bkhd->bhqk"/"bhqk,bkhd->bqhd"
+                    # pair is NOT (different reduction order). The serving
+                    # bitwise-parity guarantee lives or dies here —
+                    # docs/serving.md §numerics.
+                    s = jnp.einsum("bhd,bkhd->bhk",
+                                   q[:, 0].astype(jnp.float32),
+                                   kc) * dh ** -0.5
+                    row = rows[..., -1]              # [b] per-slot, else ()
+                    keys = jnp.arange(cap)
+                    # ring inversion: slot j holds token position
+                    # row - ((row - j) mod cap) — the newest position ≡ j
+                    # (mod cap) not exceeding row. Unwritten slots land
+                    # negative; wrapped-over history is unreachable by
+                    # construction. With cap == max_len and no wrap this
+                    # reduces exactly to the old `keys <= row` mask.
+                    kpos = row[..., None] - (row[..., None] - keys) % cap
+                    visible = kpos >= 0
+                    if self.attention_window is not None:
+                        visible &= kpos > (row[..., None]
+                                           - self.attention_window)
+                    vis = visible[:, None] if per_slot else visible[None, None]
+                    s = jnp.where(vis, s, -jnp.inf)
+                    att = jnp.einsum("bhk,bkhd->bhd",
+                                     jax.nn.softmax(s, -1), vc)[:, None]
             # falls through to the SHARED projection/FFN tail below — the
             # decode path must never duplicate training-path math
         elif self.pos_emb == "rope":

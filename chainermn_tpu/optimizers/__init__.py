@@ -200,10 +200,15 @@ def create_multi_node_optimizer(
     stateful = bool(reducer is not None and reducer.stateful)
 
     if reducer is None:
-        def reduce_fn(grads, rstate):
+        def reduce_grads(grads, rstate):
             return communicator.allreduce_grad(grads, op), rstate
     else:
-        reduce_fn = reducer.reduce
+        reduce_grads = reducer.reduce
+
+    def reduce_fn(grads, rstate):
+        # the collective AND its scaling (op='mean'), named for the trace
+        with jax.named_scope("grad_reduce"):
+            return reduce_grads(grads, rstate)
 
     import jax.numpy as jnp
 

@@ -39,12 +39,14 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
 import numpy as np
 
+from chainermn_tpu import tracing
 from chainermn_tpu.resilience import chaos
 from chainermn_tpu.serving.kv_cache import ServingStep
 from chainermn_tpu.serving.reports import ServingReport
@@ -131,6 +133,8 @@ class Request:
     prefill_pos: int = 0              # chunked prefill: tokens written
     hold: bool = False                # retire → 'held' (slot kept bound
     #                                   for export_handoff; fleet pools)
+    t_submit: float = dataclasses.field(   # time.perf_counter at creation:
+        default_factory=time.perf_counter)  # the queue's age in engine.step
 
     @property
     def finished(self) -> bool:
@@ -241,10 +245,10 @@ class Engine:
         self._topks[slot] = req.top_k if req.top_k is not None else 0
         self._eos[slot] = req.eos_id if req.eos_id is not None else -1
         self._keys = self._keys.at[slot].set(request_key(req.seed))
+        self.report.record_admit(req.request_id)
 
     def _emit(self, req: Request, token: int) -> None:
         req.tokens.append(int(token))
-        self.report.record_token(req.request_id)
         hit_eos = req.eos_id is not None and token == req.eos_id
         if hit_eos or len(req.tokens) >= req.max_new_tokens:
             if req.hold:
@@ -253,6 +257,23 @@ class Engine:
                 self._retire(req)
         elif req.slot is not None:
             self.cur_tokens[req.slot] = token
+
+    def _replay(self, req: Request, row) -> int:
+        """Emit one request's share of a dispatch's pull: the leading
+        valid tokens of ``row`` (-1 ends them: the device has already
+        applied EOS and budget). One report call for the dispatch, made
+        BEFORE the emits because the last of them may retire the request.
+        Returns the number of tokens emitted."""
+        n = 0
+        while n < len(row) and row[n] >= 0:
+            n += 1
+        if n:
+            self.report.record_tokens(req.request_id, n)
+        for m in range(n):
+            self._emit(req, int(row[m]))
+            if req.finished:
+                return m + 1
+        return n
 
     def _retire(self, req: Request, aborted: bool = False) -> None:
         req.state = "aborted" if aborted else "done"
@@ -550,27 +571,37 @@ class Engine:
             return 0
         self._prefill_defer = 0
         cohort: List[Request] = []
-        while (self.queue and self.free_slots and len(cohort) < s
-               and self._bucket_for(self.queue[0].prompt.size) == bucket):
-            req = self.queue.popleft()
-            self._install(req, self.free_slots.pop(0))
-            self.active[req.slot] = req
-            cohort.append(req)
-        tokens = np.zeros((s, bucket), np.int32)
-        lengths = np.ones(s, np.int32)          # sentinel rows: length 1
-        slot_ids = np.full(s, self.steps.n_slots, np.int32)  # sentinel
-        for i, req in enumerate(cohort):
-            tokens[i, :req.prompt.size] = req.prompt
-            lengths[i] = req.prompt.size
-            slot_ids[i] = req.slot
-        tok, self._keys = self.steps.prefill_sampled(
-            tokens, lengths, slot_ids, self._keys, self._temps,
-            self._topks)
-        self._on_prefill(tokens, lengths, slot_ids)
-        first = np.asarray(tok)                 # [S] int32 — ids, never logits
+        with tracing.span("engine.admit", bucket=bucket) as sp:
+            while (self.queue and self.free_slots and len(cohort) < s
+                   and self._bucket_for(self.queue[0].prompt.size) == bucket):
+                req = self.queue.popleft()
+                self._install(req, self.free_slots.pop(0))
+                self.active[req.slot] = req
+                cohort.append(req)
+            tokens = np.zeros((s, bucket), np.int32)
+            lengths = np.ones(s, np.int32)          # sentinel rows: length 1
+            slot_ids = np.full(s, self.steps.n_slots, np.int32)  # sentinel
+            for i, req in enumerate(cohort):
+                tokens[i, :req.prompt.size] = req.prompt
+                lengths[i] = req.prompt.size
+                slot_ids[i] = req.slot
+            tok, self._keys = self.steps.prefill_sampled(
+                tokens, lengths, slot_ids, self._keys, self._temps,
+                self._topks)
+            self._on_prefill(tokens, lengths, slot_ids)
+            if sp:
+                filled = sum(r.prompt.size for r in cohort)
+                sp.set(admitted=len(cohort), rows=s, prompt_tokens=filled,
+                       padded_tokens=s * bucket - filled)
+        with tracing.span("engine.prefill.wait"):
+            first = np.asarray(tok)         # [S] int32 — ids, never logits
         self.report.record_host_bytes(first.nbytes)
-        for i, req in enumerate(cohort):
-            self._emit(req, int(first[i]))
+        with tracing.span("engine.emit") as sp:
+            for i, req in enumerate(cohort):
+                self._replay(req, first[i:i + 1])
+            if sp:
+                sp.set(tokens=len(cohort),
+                       retired=sum(r.finished for r in cohort))
         return len(cohort)
 
     def _advance_prefill_chunks(self, avail: float) -> int:
@@ -604,32 +635,42 @@ class Engine:
                 if over and not starved and (self.active or dispatched):
                     self._prefill_defer += 1
                     break
-            cohort = [(slot, self.prefilling[slot])
-                      for slot in forced[:s]]
-            for slot, req in sorted(self.prefilling.items(),
-                                    key=lambda kv: kv[1].request_id):
-                if len(cohort) >= s:
-                    break
-                if all(slot != s0 for s0, _ in cohort):
+            with tracing.span("engine.admit", chunk=c) as sp:
+                cohort = [(slot, self.prefilling[slot])
+                          for slot in forced[:s]]
+                for slot, req in sorted(self.prefilling.items(),
+                                        key=lambda kv: kv[1].request_id):
+                    if len(cohort) >= s:
+                        break
+                    if all(slot != s0 for s0, _ in cohort):
+                        cohort.append((slot, req))
+                fresh = 0
+                while len(cohort) < s and self.queue and self.free_slots:
+                    req = self.queue.popleft()
+                    slot = self.free_slots.pop(0)
+                    self._install(req, slot)
+                    self.prefilling[slot] = req
+                    fresh += 1
                     cohort.append((slot, req))
-            while len(cohort) < s and self.queue and self.free_slots:
-                req = self.queue.popleft()
-                slot = self.free_slots.pop(0)
-                self._install(req, slot)
-                self.prefilling[slot] = req
-                admitted += 1
-                cohort.append((slot, req))
+                admitted += fresh
+                if cohort:
+                    self._prefill_defer = 0
+                    spent += len(cohort) * c
+                    tok, valid, final = self._dispatch_chunk(cohort)
+                    if sp:
+                        filled = int(valid[:len(cohort)].sum())
+                        sp.set(admitted=fresh, rows=s, prompt_tokens=filled,
+                               padded_tokens=s * c - filled)
             if not cohort:
                 break
-            self._prefill_defer = 0
-            spent += len(cohort) * c
-            self._dispatch_chunk(cohort)
+            self._finish_chunk(cohort, tok, valid, final)
             dispatched = True
         return admitted
 
-    def _dispatch_chunk(self, cohort) -> None:
-        """One fixed-shape ``[S, C]`` chunk dispatch; completing rows
-        sample their first token on device and move to decode."""
+    def _dispatch_chunk(self, cohort):
+        """Enqueue one fixed-shape ``[S, C]`` chunk dispatch; returns the
+        first-token ids ON DEVICE with the cohort's ``valid`` and ``final``
+        rows for :meth:`_finish_chunk`."""
         cfg = self.config
         c = cfg.prefill_chunk
         s = cfg.prefill_cohort
@@ -650,14 +691,26 @@ class Engine:
             tokens, starts, valid, sids, final, self._keys, self._temps,
             self._topks)
         self._on_prefill_chunk(tokens, starts, valid, sids, final)
-        first = np.asarray(tok)                 # [S] int32 ids (-1 = not final)
+        return tok, valid, final
+
+    def _finish_chunk(self, cohort, tok, valid, final) -> None:
+        """Pull a chunk dispatch's ids; completing rows emit their first
+        token and move to decode."""
+        with tracing.span("engine.prefill.wait"):
+            first = np.asarray(tok)         # [S] int32 ids (-1 = not final)
         self.report.record_host_bytes(first.nbytes)
-        for i, (slot, req) in enumerate(cohort):
-            req.prefill_pos += int(valid[i])
-            if final[i]:
-                del self.prefilling[slot]
-                self.active[slot] = req
-                self._emit(req, int(first[i]))
+        with tracing.span("engine.emit") as sp:
+            done = []
+            for i, (slot, req) in enumerate(cohort):
+                req.prefill_pos += int(valid[i])
+                if final[i]:
+                    del self.prefilling[slot]
+                    self.active[slot] = req
+                    self._replay(req, first[i:i + 1])
+                    done.append(req)
+            if sp:
+                sp.set(tokens=len(done),
+                       retired=sum(r.finished for r in done))
 
     def _decode(self) -> int:
         """One ``decode_k`` dispatch for the whole grid; the host pulls
@@ -665,34 +718,33 @@ class Engine:
         and replays the device's EOS/budget retirement decisions."""
         cfg = self.config
         n = cfg.n_slots
-        live = np.zeros(n, bool)
-        remaining = np.ones(n, np.int32)
-        for slot, req in self.active.items():
-            live[slot] = True
-            remaining[slot] = req.max_new_tokens - len(req.tokens)
-        park = np.zeros(n, np.int32)
-        for slot, req in self.prefilling.items():
-            park[slot] = req.prefill_pos
-        for slot, req in self.held.items():
-            # a held slot's rows await export: pin its cursor to the
-            # real fill so the ride-along garbage steps can't wrap it
-            park[slot] = req.prompt.size + len(req.tokens) - 1
-        toks_dev, self._keys = self.steps.decode_k(
-            self.cur_tokens, self._keys, self._temps, self._topks,
-            self._eos, remaining, live, park, cfg.decode_k)
-        toks = np.asarray(toks_dev)             # [n, k] int32 — the ONLY
-        #                                         per-token host transfer
+        with tracing.span("engine.decode.enqueue", live=len(self.active)):
+            live = np.zeros(n, bool)
+            remaining = np.ones(n, np.int32)
+            for slot, req in self.active.items():
+                live[slot] = True
+                remaining[slot] = req.max_new_tokens - len(req.tokens)
+            park = np.zeros(n, np.int32)
+            for slot, req in self.prefilling.items():
+                park[slot] = req.prefill_pos
+            for slot, req in self.held.items():
+                # a held slot's rows await export: pin its cursor to the
+                # real fill so the ride-along garbage steps can't wrap it
+                park[slot] = req.prompt.size + len(req.tokens) - 1
+            toks_dev, self._keys = self.steps.decode_k(
+                self.cur_tokens, self._keys, self._temps, self._topks,
+                self._eos, remaining, live, park, cfg.decode_k)
+        with tracing.span("engine.decode.wait"):
+            toks = np.asarray(toks_dev)         # [n, k] int32 — the ONLY
+            #                                     per-token host transfer
         self.report.record_host_bytes(toks.nbytes)
-        emitted = 0
-        for slot, req in list(self.active.items()):
-            for j in range(cfg.decode_k):
-                t = int(toks[slot, j])
-                if t < 0:
-                    break
-                self._emit(req, t)
-                emitted += 1
-                if req.finished:
-                    break
+        with tracing.span("engine.emit") as sp:
+            emitted = retired = 0
+            for slot, req in list(self.active.items()):
+                emitted += self._replay(req, toks[slot])
+                retired += req.finished
+            if sp:
+                sp.set(tokens=emitted, retired=retired)
         return emitted
 
     def step(self) -> dict:
@@ -701,17 +753,24 @@ class Engine:
         counters for the caller's loop policy."""
         chaos.on_step(self.iteration)
         self.iteration += 1
-        budget = self.config.token_budget
-        avail = (float("inf") if budget is None
-                 else budget - len(self.active) * self._max_decode_advance())
-        if self.config.prefill_chunk is not None:
-            admitted = self._advance_prefill_chunks(avail)
-        else:
-            admitted = self._admit(avail)
-        emitted = self._decode() if self.active else 0
-        self.report.record_step(
-            len(self.queue),
-            (len(self.active) + len(self.prefilling)) / self.config.n_slots)
+        with tracing.span("engine.step", iteration=self.iteration) as sp:
+            if sp:
+                sp.set(queued=len(self.queue), active=len(self.active),
+                       oldest_wait_s=(
+                           time.perf_counter() - self.queue[0].t_submit
+                           if self.queue else 0.0))
+            budget = self.config.token_budget
+            avail = (float("inf") if budget is None else
+                     budget - len(self.active) * self._max_decode_advance())
+            if self.config.prefill_chunk is not None:
+                admitted = self._advance_prefill_chunks(avail)
+            else:
+                admitted = self._admit(avail)
+            emitted = self._decode() if self.active else 0
+            self.report.record_step(
+                len(self.queue),
+                (len(self.active) + len(self.prefilling))
+                / self.config.n_slots)
         return {"admitted": admitted, "emitted": emitted,
                 "active": len(self.active), "queued": len(self.queue)}
 

@@ -24,8 +24,16 @@ __all__ = ["ServingReport", "ReceivedServingReport", "percentile",
 #: to the ``raw()`` schema so a mixed-version fleet fails loudly instead
 #: of merging mis-shaped telemetry
 #: (1 → 2: speculative-decoding counters — draft_tokens_proposed/
-#: accepted, spec_dispatches, spec_tokens_emitted)
-REPORT_WIRE_VERSION = 2
+#: accepted, spec_dispatches, spec_tokens_emitted; 2 → 3: queue_wait_s,
+#: and token_gap_s spreads a dispatch's gap over its tokens)
+REPORT_WIRE_VERSION = 3
+
+#: every key of ``raw()``: what a received report must carry
+RAW_KEYS = ("ttft_s", "token_gap_s", "queue_wait_s", "queue_depth_samples",
+            "occupancy_samples", "submitted", "completed", "aborted",
+            "tokens_emitted", "host_bytes", "draft_tokens_proposed",
+            "draft_tokens_accepted", "spec_dispatches",
+            "spec_tokens_emitted", "wall_s")
 
 
 def percentile(samples: List[float], q: float) -> float:
@@ -65,6 +73,7 @@ class ServingReport:
         self.spec_tokens_emitted = 0
         self.ttft_s: List[float] = []
         self.token_gap_s: List[float] = []
+        self.queue_wait_s: List[float] = []   # submit → admitted to a slot
         self.queue_depth_samples: List[int] = []
         self.occupancy_samples: List[float] = []
         self._last_token_t: Dict[int, float] = {}
@@ -82,18 +91,34 @@ class ServingReport:
         self.submitted += 1
         self._submit_t[request_id] = now
 
-    def record_token(self, request_id: int) -> None:
+    def record_admit(self, request_id: int) -> None:
+        """The request left the queue for a slot (engine ``_install``)."""
+        sub = self._submit_t.get(request_id)
+        if sub is not None:
+            self.queue_wait_s.append(self._time() - sub)
+
+    def record_tokens(self, request_id: int, n: int) -> None:
+        """One dispatch delivered ``n`` tokens of a request at this
+        instant (``decode_k`` and speculative rounds emit several). The
+        gap since the request's previous token is spread over them:
+        ``(now - prev) / n``, ``n`` times — the cadence a streaming
+        caller would see smoothed, not 0, 0, 0, big."""
         now = self._time()
         self._t_last = now
-        self.tokens_emitted += 1
+        self.tokens_emitted += n
         prev = self._last_token_t.get(request_id)
         if prev is None:
             sub = self._submit_t.get(request_id)
             if sub is not None:
                 self.ttft_s.append(now - sub)
+            # the first dispatch's further tokens have no earlier token
+            # of this request to be measured from: they add no gap
         else:
-            self.token_gap_s.append(now - prev)
+            self.token_gap_s.extend([(now - prev) / n] * n)
         self._last_token_t[request_id] = now
+
+    def record_token(self, request_id: int) -> None:
+        self.record_tokens(request_id, 1)
 
     def record_retire(self, request_id: int, aborted: bool = False) -> None:
         self._t_last = self._time()
@@ -149,6 +174,7 @@ class ServingReport:
         return {
             "ttft_s": list(self.ttft_s),
             "token_gap_s": list(self.token_gap_s),
+            "queue_wait_s": list(self.queue_wait_s),
             "queue_depth_samples": list(self.queue_depth_samples),
             "occupancy_samples": list(self.occupancy_samples),
             "submitted": self.submitted,
@@ -205,6 +231,7 @@ class ServingReport:
             # for the same per-request token-gap distribution
             "itl_ms": self._dist_ms(self.token_gap_s),
             "token_latency_ms": self._dist_ms(self.token_gap_s),
+            "queue_wait_ms": self._dist_ms(self.queue_wait_s),
             "queue_depth": {"mean": (sum(qd) / len(qd) if qd
                                      else float("nan")),
                             "max": max(qd) if qd else 0},
@@ -251,15 +278,7 @@ class ReceivedServingReport:
     else (a received report cannot record new events)."""
 
     def __init__(self, raw: dict):
-        missing = [k for k in ("ttft_s", "token_gap_s",
-                               "queue_depth_samples", "occupancy_samples",
-                               "submitted", "completed", "aborted",
-                               "tokens_emitted", "host_bytes",
-                               "draft_tokens_proposed",
-                               "draft_tokens_accepted",
-                               "spec_dispatches", "spec_tokens_emitted",
-                               "wall_s")
-                   if k not in raw]
+        missing = [k for k in RAW_KEYS if k not in raw]
         if missing:
             raise ValueError(
                 f"serving_report raw block missing keys: {missing}")

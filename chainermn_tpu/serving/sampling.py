@@ -53,6 +53,7 @@ def split_keys(keys):
     return both[:, 0], both[:, 1]
 
 
+@jax.named_scope("sample")   # the one sampling site of every program
 def sample_tokens(logits, keys, temperature, top_k):
     """One sampled token per row, entirely on device.
 

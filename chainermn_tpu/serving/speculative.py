@@ -71,6 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chainermn_tpu import tracing
 from chainermn_tpu.models.transformer import bhld_to_blhd_params
 from chainermn_tpu.serving.engine import Engine, EngineConfig
 from chainermn_tpu.serving.kv_cache import (
@@ -470,44 +471,42 @@ class SpeculativeEngine(Engine):
         cfg = self.config
         n = cfg.n_slots
         w = self.spec_k + 1
-        live = np.zeros(n, bool)
-        remaining = np.ones(n, np.int32)
-        fills = np.zeros(n, np.int32)
-        for slot, req in self.active.items():
-            live[slot] = True
-            remaining[slot] = req.max_new_tokens - len(req.tokens)
-            fills[slot] = req.prompt.size + len(req.tokens) - 1
-        park = np.zeros(n, np.int32)
-        for slot, req in self.prefilling.items():
-            park[slot] = req.prefill_pos
-        for slot, req in self.held.items():
-            park[slot] = req.prompt.size + len(req.tokens) - 1
-        valid = np.where(live & self._spec_full, 2, 1).astype(np.int32)
-        starts = np.where(live, fills - (valid - 1), park)
-        drafts = self.draft.propose(
-            self._spec_prev, self.cur_tokens, valid, starts, self._keys,
-            self._temps, self._topks, live, park, self.spec_k)
-        emitted_dev, self._keys = self._verify(
-            self.cur_tokens, drafts, remaining, live, park)
-        toks = np.asarray(emitted_dev)      # [n, spec_k+1] int32 — the
-        #                                     round's ONLY host pull
+        with tracing.span("engine.decode.enqueue", live=len(self.active)):
+            live = np.zeros(n, bool)
+            remaining = np.ones(n, np.int32)
+            fills = np.zeros(n, np.int32)
+            for slot, req in self.active.items():
+                live[slot] = True
+                remaining[slot] = req.max_new_tokens - len(req.tokens)
+                fills[slot] = req.prompt.size + len(req.tokens) - 1
+            park = np.zeros(n, np.int32)
+            for slot, req in self.prefilling.items():
+                park[slot] = req.prefill_pos
+            for slot, req in self.held.items():
+                park[slot] = req.prompt.size + len(req.tokens) - 1
+            valid = np.where(live & self._spec_full, 2, 1).astype(np.int32)
+            starts = np.where(live, fills - (valid - 1), park)
+            drafts = self.draft.propose(
+                self._spec_prev, self.cur_tokens, valid, starts, self._keys,
+                self._temps, self._topks, live, park, self.spec_k)
+            emitted_dev, self._keys = self._verify(
+                self.cur_tokens, drafts, remaining, live, park)
+        with tracing.span("engine.decode.wait"):
+            toks = np.asarray(emitted_dev)  # [n, spec_k+1] int32 — the
+            #                                 round's ONLY host pull
         self.report.record_host_bytes(toks.nbytes)
-        emitted = 0
-        for slot, req in list(self.active.items()):
-            m = 0
-            for j in range(w):
-                t = int(toks[slot, j])
-                if t < 0:
-                    break
-                self._emit(req, t)
-                m += 1
-                emitted += 1
-                if req.finished:
-                    break
-            # the round's last token is always target-sampled
-            # (correction, bonus, or terminal) → accepted = m - 1
-            self.report.record_spec_round(self.spec_k, max(m - 1, 0), m)
-            self._spec_full[slot] = m == w
-            if m == w:
-                self._spec_prev[slot] = int(toks[slot, w - 2])
+        with tracing.span("engine.emit") as sp:
+            emitted = retired = 0
+            for slot, req in list(self.active.items()):
+                m = self._replay(req, toks[slot])
+                emitted += m
+                retired += req.finished
+                # the round's last token is always target-sampled
+                # (correction, bonus, or terminal) → accepted = m - 1
+                self.report.record_spec_round(self.spec_k, max(m - 1, 0), m)
+                self._spec_full[slot] = m == w
+                if m == w:
+                    self._spec_prev[slot] = int(toks[slot, w - 2])
+            if sp:
+                sp.set(tokens=emitted, retired=retired)
         return emitted

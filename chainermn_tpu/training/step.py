@@ -184,13 +184,14 @@ def make_data_parallel_train_step(
         else:
             (loss, (acc, new_vars)), grads = jax.value_and_grad(
                 f, has_aux=True)(params, x, y, extra, rng)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         if stateful_reducer:
             opt_state = _ReducerWrappedState(
                 opt_state.inner,
                 jax.tree_util.tree_map(lambda r: r[None],
                                        opt_state.reducer))
-        params = optax.apply_updates(params, updates)
         metrics = {
             "main/loss": lax.pmean(loss, axes),
             "main/accuracy": lax.pmean(acc, axes),
@@ -371,8 +372,9 @@ def make_expert_parallel_train_step(
             return lax.pmean(loss, axes), acc
 
         (loss, acc), grads = jax.value_and_grad(f, has_aux=True)(params)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer_update"):
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         metrics = {
             "main/loss": loss,
             "main/accuracy": lax.pmean(acc, axes),

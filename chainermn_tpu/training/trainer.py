@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from chainermn_tpu import tracing
+
 
 def default_converter(batch):
     """List of (x, y) pairs → stacked arrays (the reference's concat_examples)."""
@@ -93,12 +95,18 @@ class StandardUpdater:
         )
 
     def update(self):
-        batch = next(self.iterator)
-        arrays = self.converter(batch)
-        arrays = self.shard_batch(arrays)
-        self.state, metrics = self.step_fn(self.state, *arrays)
-        self.last_metrics = metrics
-        self.iteration += 1
+        with tracing.span("updater.update", iteration=self.iteration):
+            with tracing.span("updater.input") as sp:
+                batch = next(self.iterator)
+                arrays = self.converter(batch)
+                if sp:
+                    sp.set(bytes=sum(getattr(a, "nbytes", 0)
+                                     for a in arrays))
+                arrays = self.shard_batch(arrays)
+            with tracing.span("updater.dispatch"):
+                self.state, metrics = self.step_fn(self.state, *arrays)
+            self.last_metrics = metrics
+            self.iteration += 1
 
     # -- full-state resume (docs/fault_tolerance.md) --------------------
 
@@ -261,7 +269,10 @@ class Trainer:
                     if due:
                         self._materialize_observation(start)
                         for e in due:
-                            e.ext(self)
+                            with tracing.span(
+                                    "trainer.extension",
+                                    name=e.name or type(e.ext).__name__):
+                                e.ext(self)
                 self._materialize_observation(start)
             except BaseException:
                 # last-chance checkpoint: partial-epoch progress survives

@@ -48,3 +48,26 @@ def comm():
     import chainermn_tpu
 
     return chainermn_tpu.create_communicator("xla")
+
+
+@pytest.fixture(scope="session")
+def profiler_session(tmp_path_factory):
+    """``with profiler_session() as logdir:`` runs its body under a
+    ``jax.profiler`` session — the one switch of ``chainermn_tpu.tracing``
+    — with the Python tracer off and annotations only, so the written
+    trace stays small."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def session():
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        logdir = str(tmp_path_factory.mktemp("profiler_session"))
+        jax.profiler.start_trace(logdir, profiler_options=opts)
+        try:
+            yield logdir
+        finally:
+            jax.profiler.stop_trace()
+
+    return session

@@ -232,3 +232,34 @@ def test_fleet_report_wire_rejects_skew():
         FleetReport.from_wire(dict(wire, version=0))
     with pytest.raises(ValueError, match="envelope"):
         FleetReport.from_wire([])
+
+
+def test_queue_waits_are_pooled_like_every_other_sample():
+    """A replica that queues deep and one that admits at once: the fleet's
+    queue-wait percentiles come from the pooled samples."""
+    ra = _report([0.01], tokens=1, host_bytes=4, span_s=1.0)
+    rb = _report([0.01], tokens=1, host_bytes=4, span_s=1.0)
+    ra.queue_wait_s = [0.001] * 9
+    rb.queue_wait_s = [30.0]
+    merged = FleetReport.merge([ra, rb])
+    assert merged["queue_wait_ms"]["n"] == 10
+    assert merged["queue_wait_ms"]["p50"] == 1.0
+    assert merged["queue_wait_ms"]["p99"] == 30000.0
+    back = ServingReport.from_wire(json.loads(json.dumps(rb.to_wire())))
+    assert FleetReport.merge([ra, back])["queue_wait_ms"] == \
+        merged["queue_wait_ms"]
+
+
+def test_spread_gaps_pool_by_token_not_by_dispatch():
+    """token_gap_s holds one sample a token (a dispatch of 4 leaves 4), so
+    pooled inter-token percentiles weigh replicas by tokens."""
+    clock = [0.0]
+    r = ServingReport(time_fn=lambda: clock[0])
+    r.record_submit(0)
+    r.record_tokens(0, 1)
+    clock[0] = 0.8
+    r.record_tokens(0, 4)
+    merged = FleetReport.merge([r, _report([1.0], tokens=2, host_bytes=8,
+                                           span_s=1.0)])
+    assert merged["itl_ms"]["n"] == 5
+    assert merged["itl_ms"]["p50"] == pytest.approx(200.0)

@@ -1,0 +1,171 @@
+"""The engine's spans: under a profiler session every iteration gives its
+phases in order inside its ``engine.step`` span, with admission counted where
+it happens; without one nothing is recorded and the tokens are the same."""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from chainermn_tpu import tracing
+from chainermn_tpu.models.transformer import TransformerLM
+from chainermn_tpu.serving import SpeculativeEngine
+from chainermn_tpu.serving.engine import Engine, EngineConfig
+
+PHASES = ["engine.admit", "engine.prefill.wait", "engine.emit",
+          "engine.decode.enqueue", "engine.decode.wait", "engine.emit"]
+LENS = (3, 5, 4, 12, 4, 6, 3)
+N_NEW = 6
+
+
+@functools.lru_cache(maxsize=None)
+def _setup():
+    model = TransformerLM(vocab=43, d_model=32, n_heads=4, n_layers=1,
+                          d_ff=48, max_len=64, attention="reference",
+                          pos_emb="rope")
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    return model, params
+
+
+def _engine(kind):
+    model, params = _setup()
+    cfg = EngineConfig(n_slots=4, capacity=32, max_new_tokens=N_NEW,
+                       prefill_cohort=2, buckets=[8, 32], decode_k=2,
+                       prefill_chunk=4 if kind == "chunked" else None)
+    if kind == "speculative":
+        return SpeculativeEngine(model, params, model, params, cfg, spec_k=2)
+    return Engine(model, params, cfg)
+
+
+def _drain(eng):
+    rs = np.random.RandomState(0)
+    reqs = [eng.submit(rs.randint(0, 43, (n,)).astype(np.int32), seed=i,
+                       temperature=0.7 if i % 2 else None)
+            for i, n in enumerate(LENS)]
+    eng.run_until_drained()
+    return reqs
+
+
+def _iterations(rows):
+    steps = [r for r in rows if r.name == "engine.step"]
+    return [(s, [r for r in rows if r.parent_id == s.id]) for s in steps]
+
+
+@pytest.mark.parametrize("kind", ["plain", "chunked", "speculative"])
+def test_stepped_without_a_session_nothing_is_recorded(kind):
+    tracing.clear()
+    reqs = _drain(_engine(kind))
+    assert all(r.state == "done" and len(r.tokens) == N_NEW for r in reqs)
+    assert tracing.rows() == []
+
+
+@pytest.fixture(scope="module", params=["plain", "chunked", "speculative"])
+def traced(request, profiler_session):
+    """Each kind of engine drained once without and once under a session."""
+    kind = request.param
+    plain = [list(r.tokens) for r in _drain(_engine(kind))]
+    tracing.clear()
+    with profiler_session():
+        eng = _engine(kind)
+        reqs = _drain(eng)
+    rows = tracing.rows()
+    tracing.clear()
+    return {"kind": kind, "rows": rows, "engine": eng, "plain": plain,
+            "tokens": [list(r.tokens) for r in reqs]}
+
+
+def test_a_session_changes_no_token(traced):
+    assert traced["tokens"] == traced["plain"]
+
+
+def test_every_iteration_holds_its_phases_in_order(traced):
+    its = _iterations(traced["rows"])
+    assert len(its) == traced["engine"].iteration
+    assert [s.attrs["iteration"] for s, _ in its] == list(
+        range(1, len(its) + 1))
+    assert {r.name for r in traced["rows"]} == set(PHASES) | {"engine.step"}
+    for step, kids in its:
+        names = [k.name for k in kids]
+        prefills, decode = names[:-3], names[-3:]
+        if "engine.decode.enqueue" not in names:
+            prefills, decode = names, []
+        assert decode in ([], PHASES[3:])
+        assert len(prefills) % 3 == 0
+        for i in range(0, len(prefills), 3):
+            assert prefills[i:i + 3] == PHASES[:3]
+        if traced["kind"] != "chunked":
+            assert len(prefills) <= 3       # one cohort an iteration
+        assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
+        assert step.t0 <= kids[0].t0 and kids[-1].t1 <= step.t1
+        assert sum(k.t1 - k.t0 for k in kids) <= step.t1 - step.t0
+
+
+def test_admission_is_counted_where_it_happens(traced):
+    its = _iterations(traced["rows"])
+    admits = [k for _, kids in its for k in kids if k.name == "engine.admit"]
+    assert sum(a.attrs["admitted"] for a in admits) == len(LENS)
+    assert sum(a.attrs["prompt_tokens"] for a in admits) == sum(LENS)
+    for a in admits:
+        width = a.attrs["chunk" if traced["kind"] == "chunked" else "bucket"]
+        assert a.attrs["rows"] == 2
+        assert (a.attrs["prompt_tokens"] + a.attrs["padded_tokens"]
+                == 2 * width)
+    if traced["kind"] != "chunked":
+        # FIFO, same-bucket cohorts of two: (3, 5) (4) (12) (4, 6) (3)
+        assert [(a.attrs["bucket"], a.attrs["admitted"],
+                 a.attrs["padded_tokens"]) for a in admits] == [
+            (8, 2, 8), (8, 1, 12), (32, 1, 52), (8, 2, 6), (8, 1, 13)]
+
+
+def test_the_step_span_sees_the_queue_it_started_with(traced):
+    its = _iterations(traced["rows"])
+    first, last = its[0][0], its[-1][0]
+    assert first.attrs["queued"] == len(LENS) and first.attrs["active"] == 0
+    assert 0.0 <= first.attrs["oldest_wait_s"] < 60.0
+    assert last.attrs["queued"] == 0 and last.attrs["oldest_wait_s"] == 0.0
+    waits = [s.attrs["oldest_wait_s"] for s, _ in its if s.attrs["queued"]]
+    assert waits == sorted(waits)       # submitted together: the head ages
+
+
+def test_emit_counts_tokens_and_retirements(traced):
+    emits = [r for r in traced["rows"] if r.name == "engine.emit"]
+    assert sum(e.attrs["tokens"] for e in emits) == len(LENS) * N_NEW
+    assert sum(e.attrs["retired"] for e in emits) == len(LENS)
+    assert traced["engine"].report.tokens_emitted == len(LENS) * N_NEW
+
+
+@pytest.mark.parametrize("chunked", [False, True])
+def test_the_serving_programs_name_their_attention_and_their_sampling(
+        chunked):
+    """Device scopes are metadata of the compiled programs: the decode
+    branch's attention over the page under ``attend_cache`` (the one-token
+    step and the chunk that attends over the whole page), sampling under
+    ``sample``."""
+    from chainermn_tpu.serving.kv_cache import (decode_apply, init_cache,
+                                                prefill_chunk_apply)
+    from chainermn_tpu.serving.sampling import init_keys, sample_tokens
+
+    model, params = _setup()
+    n = 2
+    dm = model.clone(decode=True, chunked_prefill=chunked)
+    cache = init_cache(model, n, 16)
+
+    def program(params, cache, keys):
+        if chunked:
+            logits, cache = prefill_chunk_apply(
+                dm, params, cache, jnp.zeros((n, 4), jnp.int32),
+                jnp.zeros(n, jnp.int32), jnp.full(n, 4, jnp.int32),
+                jnp.arange(n))
+        else:
+            logits, cache = decode_apply(dm, params, cache,
+                                         jnp.zeros(n, jnp.int32))
+        return sample_tokens(logits, keys, jnp.zeros(n), jnp.zeros(n, int))
+
+    text = jax.jit(program).lower(params, cache, init_keys(n)).as_text(
+        debug_info=True)
+    scoped = [l for l in text.splitlines() if "attend_cache/" in l]
+    assert any("dot_general" in l for l in scoped), scoped[:5]
+    assert any("/sample/" in l and "sort" in l for l in text.splitlines())

@@ -15,6 +15,7 @@ REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 _PROBE = """
 import chainermn_tpu
+import chainermn_tpu.tracing
 import chainermn_tpu.serving.engine
 import chainermn_tpu.serving.kv_cache
 import chainermn_tpu.fleet.router
