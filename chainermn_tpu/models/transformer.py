@@ -49,6 +49,41 @@ __all__ = ["TransformerLM", "TransformerBlock", "generate",
            "lm_loss_with_aux", "tp_lm_loss", "bhld_to_blhd_params"]
 
 
+def _grouped_cache_attention(q, kpage, vpage, row, window):
+    """One query per row over the whole cache page, the page read once at
+    the dtype it is stored in.
+
+    q ``[b, n_heads, d]``; kpage/vpage ``[b, cap, h_kv, d]`` (ring pages:
+    slot j of a row holds the newest position ≡ j mod cap); row ``[b]``
+    per-slot cursors or ``()`` — the position of the query. The query
+    heads fold to ``[b, h_kv, n_heads // h_kv, d]`` and contract against
+    the ``h_kv`` heads the page has: no widened K or V exists, and MHA is
+    the ``r == 1`` case of the same code. Both contractions are matmuls
+    with f32 accumulation; scores, mask and softmax are f32; p is rounded
+    to the page's dtype for p·V as the flash kernel rounds it for prefill
+    (ops/flash_attention.py ``_fa_kernel``), so prefill and decode of one
+    model run at one precision. f32 pages (int8-block pages arrive
+    unpacked to f32) keep f32 products: the MXU's default would round
+    them to bf16. Returns ``[b, n_heads, d]`` f32.
+    """
+    b, cap, hkv, dh = kpage.shape
+    exact = (jax.lax.Precision.HIGHEST if kpage.dtype == jnp.float32
+             else None)
+    qg = q.reshape(b, hkv, -1, dh).astype(kpage.dtype)
+    s = jnp.einsum("bgrd,bkgd->bgrk", qg, kpage, precision=exact,
+                   preferred_element_type=jnp.float32) * dh ** -0.5
+    # the ring inversion and window of the reference branch, unchanged
+    kpos = row[..., None] - (row[..., None] - jnp.arange(cap)) % cap
+    visible = kpos >= 0
+    if window is not None:
+        visible &= kpos > row[..., None] - window
+    vis = visible[:, None, None] if jnp.ndim(row) else visible
+    p = jax.nn.softmax(jnp.where(vis, s, -jnp.inf), -1)
+    att = jnp.einsum("bgrk,bkgd->bgrd", p.astype(vpage.dtype), vpage,
+                     precision=exact, preferred_element_type=jnp.float32)
+    return att.reshape(b, -1, dh)
+
+
 class TransformerBlock(nn.Module):
     """Pre-LN block: causal attention + (dense | MoE) FFN.
 
@@ -261,6 +296,17 @@ class TransformerBlock(nn.Module):
                     att = flash_attention(q, k, v, causal=True, block_q=bq,
                                           block_k=bk,
                                           window=self.attention_window)
+            elif self.attention != "reference":
+                # every model that did not ask for the oracle: the page is
+                # read once, at its stored dtype, grouped, on the MXU —
+                # logits within tolerance of the float32 reference, NOT
+                # bitwise a row of the full forward (docs/serving.md
+                # §numerics; the branch below keeps that contract and
+                # shares no arithmetic with this one)
+                with jax.named_scope("attend_cache"):
+                    att = _grouped_cache_attention(
+                        q[:, 0], ck.value, cv.value, rows[..., -1],
+                        self.attention_window)[:, None]
             else:
                 with jax.named_scope("attend_cache"):
                     kc = ck.value.astype(jnp.float32)
