@@ -193,7 +193,14 @@ class TransformerBlock(nn.Module):
             # one sequential step per prompt token). Attention is a
             # [l, cached] product with causal masking inside the slab.
             if self.moe_experts_per_device > 0:
-                raise ValueError("decode does not support the MoE FFN")
+                raise ValueError(
+                    "decode does not support this block's MoE FFN: it is "
+                    "the Switch/GShard TRAINING layer (parallel/"
+                    "expert_parallel.py — capacity factor, dropped "
+                    "overflow, all-to-all), which no decode path runs. "
+                    "Expert models decode and serve as models/hybrid.py's "
+                    "HybridLM, whose feed-forward keeps every token "
+                    "(parallel/expert_share.py)")
             ck = self.variable("cache", "k", jnp.zeros,
                                (b, self.max_len, hkv, dh), self.dtype)
             cv = self.variable("cache", "v", jnp.zeros,

@@ -48,9 +48,9 @@ import numpy as np
 
 from chainermn_tpu import tracing
 from chainermn_tpu.resilience import chaos
-from chainermn_tpu.serving.kv_cache import ServingStep
 from chainermn_tpu.serving.reports import ServingReport
 from chainermn_tpu.serving.sampling import init_keys, request_key
+from chainermn_tpu.serving.state_cache import refuse_recurrent, serving_step
 
 __all__ = ["Engine", "EngineConfig", "Request", "WeightsVersionSkew",
            "default_buckets"]
@@ -157,7 +157,9 @@ class Engine:
             raise ValueError("decode_k must be >= 1")
         if config.prefill_chunk is not None and config.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
-        self.steps = ServingStep(
+        if config.prefill_chunk is not None:
+            refuse_recurrent(model, "chunked prefill")
+        self.steps = serving_step(
             model, params, config.n_slots, config.capacity,
             cache_dtype=config.cache_dtype, mesh=mesh, axis=axis,
             kv_dtype=config.kv_dtype)
@@ -216,10 +218,10 @@ class Engine:
                 f"bucket ({self._buckets[-1]})")
         budget = (max_new_tokens if max_new_tokens is not None
                   else self.config.max_new_tokens)
-        if (self.steps.kv_dtype == "int8-block"
+        if (self.steps.no_wrap
                 and prompt.size + budget > self.config.capacity):
             raise ValueError(
-                f"int8-block pages forbid ring wrap: prompt ({prompt.size})"
+                f"{self.steps.no_wrap}: prompt ({prompt.size})"
                 f" + max_new_tokens ({budget}) exceeds the page capacity "
                 f"({self.config.capacity})")
         req = Request(request_id=next(self._ids), prompt=prompt,
@@ -592,7 +594,8 @@ class Engine:
             if sp:
                 filled = sum(r.prompt.size for r in cohort)
                 sp.set(admitted=len(cohort), rows=s, prompt_tokens=filled,
-                       padded_tokens=s * bucket - filled)
+                       padded_tokens=s * bucket - filled,
+                       state_bytes=len(cohort) * self.steps.slot_bytes)
         with tracing.span("engine.prefill.wait"):
             first = np.asarray(tok)         # [S] int32 — ids, never logits
         self.report.record_host_bytes(first.nbytes)
@@ -718,7 +721,8 @@ class Engine:
         and replays the device's EOS/budget retirement decisions."""
         cfg = self.config
         n = cfg.n_slots
-        with tracing.span("engine.decode.enqueue", live=len(self.active)):
+        with tracing.span("engine.decode.enqueue",
+                          live=len(self.active)) as enq:
             live = np.zeros(n, bool)
             remaining = np.ones(n, np.int32)
             for slot, req in self.active.items():
@@ -737,6 +741,12 @@ class Engine:
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(toks_dev)         # [n, k] int32 — the ONLY
             #                                     per-token host transfer
+            if enq and self.steps.last_decode_stats:
+                # what the model counted on the device during the dispatch
+                # (expert routing): it came back with the tokens, so
+                # reading it waits for nothing
+                enq.set(**{name: np.asarray(v).item() for name, v in
+                           self.steps.last_decode_stats.items()})
         self.report.record_host_bytes(toks.nbytes)
         with tracing.span("engine.emit") as sp:
             emitted = retired = 0

@@ -69,6 +69,16 @@ parity, and peak transient memory during a dispatch is the f32
 working copy — the win is the RESIDENT footprint between dispatches.
 No mesh sharding and no ring wrap in this mode — the engine enforces
 ``prompt + max_new ≤ capacity`` at submit.
+
+Declared state (ISSUE 27): a model that sets ``declares_cache``
+(``models/hybrid.py``) has no K/V rows. It is served by
+``serving/state_cache.py``'s :class:`StateServingStep`, a subclass that keeps
+:class:`ServingStep`'s entry points, jit caches, trace counters and donation
+and replaces what names K/V: the pages (the model's declared ``cache``
+collection — any slot-major leaf, the root ``idx`` the cursor), the three
+programs, the shardings and the slot export. ``state_cache.serving_step``
+picks the class once, at construction; ``cache_spec`` and the K/V arithmetic
+here stay for the models that have K/V.
 """
 
 from __future__ import annotations
@@ -84,7 +94,7 @@ from chainermn_tpu.collectives.quantized import QUANT_BLOCK
 from chainermn_tpu.models.transformer import bhld_to_blhd_params
 from chainermn_tpu.serving.sampling import sample_tokens
 
-__all__ = ["init_cache", "cache_bytes", "cache_spec", "decode_apply",
+__all__ = ["init_cache", "cache_bytes", "cache_spec", "slot_bytes", "decode_apply",
            "prefill_apply", "decode_k_apply", "prefill_chunk_apply",
            "ServingStep", "KV_PAGE_DTYPES", "page_block", "unpack_cache",
            "repack_cache", "cache_is_quantized"]
@@ -114,9 +124,21 @@ def page_block(model) -> int:
 
 
 def _check_servable(model):
+    if getattr(model, "declares_cache", False):
+        raise ValueError(
+            f"{type(model).__name__} declares its own cache: build its step "
+            "with serving.state_cache.serving_step (StateServingStep), "
+            "which takes the pages from what the model declares")
     if model.moe_experts_per_device > 0:
-        raise ValueError("serving does not support MoE models: the "
-                         "decode path has no expert dispatch")
+        raise ValueError(
+            "serving does not support this MoE model: it has the "
+            "Switch/GShard training layer "
+            "(TransformerLM with moe_experts_per_device > 0, "
+            "parallel/expert_parallel.py): its capacity factor drops "
+            "tokens and its dispatch is an all-to-all inside shard_map, "
+            "which the jit decode path has not. Expert models serve as "
+            "models/hybrid.py's HybridLM, whose feed-forward is the "
+            "dropless held-expert share of parallel/expert_share.py")
     if model.tp_axis is not None or getattr(model, "lm_head_tp", False):
         raise ValueError(
             "serving runs the jit decode path; tp_axis/lm_head_tp models "
@@ -149,6 +171,12 @@ def cache_bytes(model, n_slots: int, capacity: int,
         return cells + cells // page_block(model) * 4
     itemsize = jnp.dtype(dtype or model.dtype).itemsize
     return cells * itemsize
+
+
+def slot_bytes(cache) -> int:
+    """Bytes one slot holds across every (slot-major) leaf of ``cache``."""
+    return sum(a.dtype.itemsize * (a.size // a.shape[0])
+               for a in jax.tree_util.tree_leaves(cache))
 
 
 def init_cache(model, n_slots: int, capacity: int, dtype: Any = None,
@@ -449,7 +477,6 @@ class ServingStep:
     def __init__(self, model, params, n_slots: int, capacity: int, *,
                  cache_dtype: Any = None, mesh=None, axis: Optional[str] = None,
                  donate: bool = True, kv_dtype: Optional[str] = None):
-        _check_servable(model)
         self.kv_dtype = _normalize_kv_dtype(kv_dtype)
         if self.kv_dtype == "int8-block" and mesh is not None:
             raise ValueError(
@@ -457,17 +484,12 @@ class ServingStep:
                 "pages: the blockwise scales span the head axis; serve "
                 "int8 pages unsharded or keep f32 pages under the mesh")
         self.src_model = model   # caller's layout: load_params converts from it
-        if model.qkv_layout == "bhld":
-            params = bhld_to_blhd_params(model, params)
-            model = model.clone(qkv_layout="blhd")
-        self.model = model
-        self.dm = model.clone(decode=True)
-        self.dm_chunk = self.dm.clone(chunked_prefill=True)
-        self.params = params
         self.n_slots = int(n_slots)
         self.capacity = int(capacity)
-        self.cache = init_cache(model, n_slots, capacity, cache_dtype,
-                                kv_dtype=self.kv_dtype)
+        self.params = self._init_pages(model, params, cache_dtype)
+        #: bytes one slot holds across all its leaves (engine.admit's
+        #: ``state_bytes`` attribute counts installs in this unit)
+        self.slot_bytes = slot_bytes(self.cache)
         self.decode_traces = 0
         self.decode_k_traces = 0
         self.prefill_traces: Dict[tuple, int] = {}
@@ -478,16 +500,16 @@ class ServingStep:
         self._decode_k_jits: Dict[int, Any] = {}
         self.last_decode_logits = None   # device [n_slots, vocab] —
         #                                  engine's lazy debug/parity hook
+        self.last_decode_stats = None    # what the model's last decode_k
+        #                                  counted on the device, {name:
+        #                                  scalar} (StateServingStep)
         self._mesh = mesh
         self._axis = axis
         donate_args = (1,) if donate else ()
 
         def _decode(params, cache, tokens):
             self.decode_traces += 1      # trace-time only: counts compiles
-            f32c = unpack_cache(cache)
-            start = f32c["block_0"]["idx"]
-            logits, f32c = decode_apply(self.dm, params, f32c, tokens)
-            return logits, repack_cache(cache, f32c, start, 1)
+            return self._decode_program(params, cache, tokens)
 
         kw = {}
         if mesh is not None:
@@ -499,6 +521,57 @@ class ServingStep:
         self._decode_jit = jax.jit(_decode, donate_argnums=donate_args,
                                    **kw)
         self._donate = donate_args
+
+    @property
+    def no_wrap(self) -> Optional[str]:
+        """Why ``prompt + max_new_tokens`` may not pass the capacity (None:
+        the ring wraps); ``Engine.submit`` raises with it."""
+        return ("int8-block pages forbid ring wrap"
+                if self.kv_dtype == "int8-block" else None)
+
+    # -- what names K/V (StateServingStep replaces these) --------------------
+    def _init_pages(self, model, params, cache_dtype):
+        """Set ``model``, ``dm``, ``dm_chunk`` and ``cache``; return the
+        parameters in the layout the programs take."""
+        _check_servable(model)
+        if model.qkv_layout == "bhld":
+            params = bhld_to_blhd_params(model, params)
+            model = model.clone(qkv_layout="blhd")
+        self.model = model
+        self.dm = model.clone(decode=True)
+        self.dm_chunk = self.dm.clone(chunked_prefill=True)
+        self.cache = init_cache(model, self.n_slots, self.capacity,
+                                cache_dtype, kv_dtype=self.kv_dtype)
+        return params
+
+    def _decode_program(self, params, cache, tokens):
+        f32c = unpack_cache(cache)
+        start = f32c["block_0"]["idx"]
+        logits, f32c = decode_apply(self.dm, params, f32c, tokens)
+        return logits, repack_cache(cache, f32c, start, 1)
+
+    def _prefill_program(self, params, cache, tokens, lengths, slot_ids):
+        f32c = unpack_cache(cache)
+        last, f32c = prefill_apply(self.dm, params, f32c, tokens, lengths,
+                                   slot_ids)
+        start, count = self._scatter_window(slot_ids, 0, tokens.shape[1])
+        return last, repack_cache(cache, f32c, start, count)
+
+    #: outputs of ``_decode_k_program`` after the cache (replicated)
+    _decode_k_extra = 0
+
+    def _decode_k_program(self, params, cache, tokens, keys, temps, top_ks,
+                          eos_ids, remaining, live, park, k):
+        f32c = unpack_cache(cache)
+        # every row writes k columns from its PINNED cursor — live rows
+        # from idx, ride-along rows from park (their garbage stays beyond
+        # their real fill)
+        start = jnp.where(jnp.asarray(live, bool), f32c["block_0"]["idx"],
+                          jnp.asarray(park, jnp.int32))
+        toks, last, keys, f32c = decode_k_apply(
+            self.dm, params, f32c, tokens, keys, temps, top_ks, eos_ids,
+            remaining, live, park, k)
+        return toks, last, keys, repack_cache(cache, f32c, start, k)
 
     def place(self, tree, pages: bool = False):
         """Commit parameters, pages or per-slot state to this step's
@@ -574,11 +647,8 @@ class ServingStep:
                          _key=key):
                 self.prefill_traces[_key] = (
                     self.prefill_traces.get(_key, 0) + 1)
-                f32c = unpack_cache(cache)
-                last, f32c = prefill_apply(self.dm, params, f32c, tokens,
-                                           lengths, slot_ids)
-                start, count = self._scatter_window(slot_ids, 0, _key[1])
-                return last, repack_cache(cache, f32c, start, count)
+                return self._prefill_program(params, cache, tokens, lengths,
+                                             slot_ids)
 
             kw = {}
             if self._mesh is not None:
@@ -609,28 +679,20 @@ class ServingStep:
             def _decode_k(params, cache, tokens, keys, temps, top_ks,
                           eos_ids, remaining, live, park, _k=kk):
                 self.decode_k_traces += 1   # trace-time only
-                f32c = unpack_cache(cache)
-                # every row writes k columns from its PINNED cursor —
-                # live rows from idx, ride-along rows from park (their
-                # garbage stays beyond their real fill)
-                start = jnp.where(jnp.asarray(live, bool),
-                                  f32c["block_0"]["idx"],
-                                  jnp.asarray(park, jnp.int32))
-                toks, last, keys, f32c = decode_k_apply(
-                    self.dm, params, f32c, tokens, keys, temps, top_ks,
-                    eos_ids, remaining, live, park, _k)
-                return toks, last, keys, repack_cache(cache, f32c,
-                                                      start, _k)
+                return self._decode_k_program(
+                    params, cache, tokens, keys, temps, top_ks, eos_ids,
+                    remaining, live, park, _k)
 
             kw = {}
             if self._mesh is not None:
                 repl, cache_sh = self._shardings(self._mesh, self._axis)
                 kw = dict(
                     in_shardings=(repl, cache_sh) + (repl,) * 8,
-                    out_shardings=(repl, repl, repl, cache_sh))
+                    out_shardings=(repl, repl, repl, cache_sh)
+                    + (repl,) * self._decode_k_extra)
             self._decode_k_jits[kk] = jax.jit(
                 _decode_k, donate_argnums=self._donate, **kw)
-        toks, last, keys, self.cache = self._decode_k_jits[kk](
+        toks, last, keys, self.cache, *extra = self._decode_k_jits[kk](
             self.params, self.cache, jnp.asarray(tokens, jnp.int32),
             keys, jnp.asarray(temps, jnp.float32),
             jnp.asarray(top_ks, jnp.int32),
@@ -638,6 +700,8 @@ class ServingStep:
             jnp.asarray(remaining, jnp.int32),
             jnp.asarray(live, bool), jnp.asarray(park, jnp.int32))
         self.last_decode_logits = last
+        if extra:
+            self.last_decode_stats = extra[0]
         return toks, keys
 
     def prefill_sampled(self, tokens, lengths, slot_ids, keys, temps,
@@ -657,11 +721,8 @@ class ServingStep:
                     temps, top_ks, _key=key):
                 self.prefill_traces[_key] = (
                     self.prefill_traces.get(_key, 0) + 1)
-                f32c = unpack_cache(cache)
-                last, f32c = prefill_apply(self.dm, params, f32c,
-                                           tokens, lengths, slot_ids)
-                start, count = self._scatter_window(slot_ids, 0, _key[1])
-                cache = repack_cache(cache, f32c, start, count)
+                last, cache = self._prefill_program(
+                    params, cache, tokens, lengths, slot_ids)
                 sid = jnp.asarray(slot_ids, jnp.int32)
                 gid = jnp.clip(sid, 0, self.n_slots - 1)
                 tok, newk = sample_tokens(last, keys[gid], temps[gid],
@@ -765,6 +826,9 @@ class ServingStep:
         # Export IS the host pull: handoff serialization runs once per
         # migration, outside the per-token decode loop, and the payload
         # must be host bytes by contract.
+        return self._export_rows(slot, fill)
+
+    def _export_rows(self, slot, fill):
         if self.kv_dtype == "int8-block":
             return {  # dlint: disable=DL121 — sanctioned migration pull
                 name: {leaf: np.asarray(page[leaf][slot, :fill])
@@ -796,6 +860,9 @@ class ServingStep:
             raise ValueError(
                 "handoff pages do not match this model's cache layout: "
                 f"got {sorted(pages)}, want {sorted(self.cache)}")
+        self._import_rows(slot, pages, cursor)
+
+    def _import_rows(self, slot, pages, cursor):
         resident = "k_q" in next(iter(pages.values()))
         blk = page_block(self.model)
         new_cache = {}

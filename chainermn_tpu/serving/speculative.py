@@ -84,6 +84,7 @@ from chainermn_tpu.serving.kv_cache import (
     unpack_cache,
 )
 from chainermn_tpu.serving.sampling import draft_shadow_keys, sample_tokens
+from chainermn_tpu.serving.state_cache import refuse_recurrent
 
 __all__ = ["DraftStep", "SpeculativeEngine", "propose_apply",
            "verify_apply"]
@@ -343,6 +344,8 @@ class SpeculativeEngine(Engine):
                  weights_version: Optional[str] = None):
         if spec_k < 1:
             raise ValueError("spec_k must be >= 1")
+        for m in (model, draft_model):
+            refuse_recurrent(m, "speculative decoding (rewind on reject)")
         super().__init__(model, params, config, report=report,
                          time_fn=time_fn, weights_version=weights_version)
         if draft_model.vocab != model.vocab:

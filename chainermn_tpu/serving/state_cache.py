@@ -1,0 +1,226 @@
+"""Pages taken from what the model declares: the serving programs for models
+whose per-sequence state is not K/V rows.
+
+``kv_cache.py`` sizes its pages from ``cache_spec`` — ``n_kv_heads · d_head``
+keys and values per token and layer. A model that sets ``declares_cache``
+(``models/hybrid.py``) holds other kinds of state side by side: a fixed-size
+recurrent state and a convolution tail per linear-attention layer, a latent
+page per latent-attention layer, no K/V at all. For such a model the page
+tree IS the flax ``cache`` collection ``model.clone(decode=True,
+max_len=capacity)`` declares at batch ``n_slots``: every leaf is slot-major
+(``[n_slots, ...]``), the root leaf ``idx`` is the per-slot cursor, and
+install, evict, reset, export and import work leaf by leaf on axis 0 with no
+knowledge of what a leaf means.
+
+What the cursor cannot do here: a recurrent state is the sum of its history
+and cannot be rewound, re-windowed or wrapped by moving a cursor. So
+
+* a row that is not live must not be stepped at all — the model takes the
+  ``live`` mask and leaves such a row's state exactly as it was (``β = 0``,
+  ``α = 1``), where the K/V path lets it write garbage past its cursor;
+* a right-padded prefill row must stop at its true length — the model takes
+  ``lengths`` and installs the state after the row's last real token;
+* speculative decoding (rewind on reject), ``int8-block`` pages (per-column
+  requantisation) and ring wrap (overwrite the oldest column) are refused
+  for these models with a ``ValueError`` that says "recurrent state".
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from chainermn_tpu.serving.kv_cache import ServingStep
+from chainermn_tpu.serving.sampling import sample_tokens
+
+__all__ = ["StateServingStep", "serving_step", "declares_cache",
+           "init_state_cache", "state_decode_apply", "state_prefill_apply",
+           "state_decode_k_apply", "refuse_recurrent"]
+
+
+def declares_cache(model) -> bool:
+    return bool(getattr(model, "declares_cache", False))
+
+
+def refuse_recurrent(model, what: str) -> None:
+    """Raise for a feature that moves K/V rows by cursor."""
+    if declares_cache(model):
+        raise ValueError(
+            f"{what} is not available for {type(model).__name__}: its "
+            "layers carry a recurrent state, which is the sum of its "
+            "history and cannot be rewound, requantised by column or "
+            "wrapped by moving a cursor")
+
+
+def init_state_cache(model, n_slots: int, capacity: int):
+    """Zeroed pages: the declared ``cache`` collection at batch ``n_slots``
+    and page length ``capacity``, each leaf in the dtype the model gives it
+    (recurrent state f32, pages in the compute dtype)."""
+    dm = model.clone(decode=True, max_len=capacity)
+    shapes = jax.eval_shape(
+        lambda: dm.init(jax.random.PRNGKey(0),
+                        jnp.zeros((n_slots, 1), jnp.int32))["cache"])
+    return jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), shapes)
+
+
+def _apply(dm, params, cache, tokens, lengths, live):
+    """One call of the model on its declared cache: (logits, cache, what the
+    model counted — the ``stats`` collection's leaves, {} where it counts
+    nothing)."""
+    logits, upd = dm.apply(
+        {"params": params, "cache": cache}, tokens, lengths=lengths,
+        live=live, mutable=["cache", "stats"])
+    return logits, upd["cache"], dict(upd.get("stats", {}))
+
+
+def state_decode_apply(dm, params, cache, tokens, live=None):
+    """PURE one-token step: tokens ``[n]`` → (logits ``[n, vocab]``, cache,
+    the model's counts). Rows that are not ``live`` keep their state and
+    cursor."""
+    n = tokens.shape[0]
+    live = jnp.ones((n,), bool) if live is None else live
+    logits, cache, stats = _apply(
+        dm, params, cache, tokens[:, None], jnp.ones((n,), jnp.int32), live)
+    return logits[:, 0], cache, stats
+
+
+def state_prefill_apply(dm, params, cache, tokens, lengths, slot_ids):
+    """PURE cohort prefill: the model runs the right-padded ``[S, L]``
+    cohort on a fresh ``S``-row copy of the declared state and stops each
+    row at its true length; every leaf's rows are then installed at
+    ``slot_ids`` on axis 0 (sentinel ``n_slots`` rows drop), cursor
+    included. Returns (last-real-position logits ``[S, vocab]``, cache)."""
+    s, l = tokens.shape
+    n_slots = cache["idx"].shape[0]
+    sid = jnp.asarray(slot_ids, jnp.int32)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    slab = jax.tree_util.tree_map(
+        lambda page: jnp.zeros((s,) + page.shape[1:], page.dtype), cache)
+    logits, slab, _ = _apply(dm, params, slab, tokens, lengths,
+                             sid < n_slots)
+    last = jnp.take_along_axis(
+        logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+    cache = jax.tree_util.tree_map(
+        lambda page, rows: page.at[sid].set(rows, mode="drop"), cache, slab)
+    return last, cache
+
+
+def state_decode_k_apply(dm, params, cache, tokens, keys, temps, top_ks,
+                         eos_ids, remaining, live, park, k):
+    """``kv_cache.decode_k_apply`` for declared state: ``k`` steps under one
+    scan with on-device sampling and stop masks. A row is stepped only
+    while it is alive, so a slot that is held, mid-prefill, free or
+    finished inside the dispatch keeps the state of its last real token.
+    Also returns the model's counts summed over the steps."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    live = jnp.asarray(live, bool)
+    remaining = jnp.asarray(remaining, jnp.int32)
+    eos_ids = jnp.asarray(eos_ids, jnp.int32)
+    temps = jnp.asarray(temps, jnp.float32)
+    top_ks = jnp.asarray(top_ks, jnp.int32)
+    cache = {**cache, "idx": jnp.where(live, cache["idx"],
+                                       jnp.asarray(park, jnp.int32))}
+    zeros = jnp.zeros((tokens.shape[0], dm.vocab), jnp.float32)
+
+    def body(carry, _):
+        cache, tok, keys, rem, alive, _last = carry
+        logits, cache, stats = state_decode_apply(dm, params, cache, tok,
+                                                  alive)
+        nxt, keys2 = sample_tokens(logits, keys, temps, top_ks)
+        keys = jnp.where(alive[:, None], keys2, keys)
+        valid = alive
+        rem = rem - valid.astype(jnp.int32)
+        hit_eos = (nxt == eos_ids) & (eos_ids >= 0)
+        alive = alive & ~hit_eos & (rem > 0)
+        tok = jnp.where(valid, nxt, tok)
+        out = jnp.where(valid, nxt, jnp.int32(-1))
+        return (cache, tok, keys, rem, alive, logits), (out, stats)
+
+    (cache, _, keys, _, _, last), (toks, stats) = jax.lax.scan(
+        body, (cache, tokens, keys, remaining, live, zeros), None, length=k)
+    stats = jax.tree_util.tree_map(lambda a: a.sum(0), stats)
+    return toks.T, last, keys, cache, stats
+
+
+class StateServingStep(ServingStep):
+    """:class:`ServingStep` for a model that declares its cache: the same
+    entry points, jit caches, trace counters, donation and placement; the
+    pages, the three programs, the shardings and the slot export are the
+    ones above, leaf-wise on axis 0."""
+
+    no_wrap = "a recurrent state forbids ring wrap"
+    _decode_k_extra = 1     # the model's counts, summed over the steps
+
+    def __init__(self, model, params, n_slots, capacity, *, kv_dtype=None,
+                 **kw):
+        if kv_dtype not in (None, "f32"):
+            refuse_recurrent(model, f"kv_dtype={kv_dtype!r}")
+        super().__init__(model, params, n_slots, capacity, **kw)
+
+    def _init_pages(self, model, params, cache_dtype):
+        # each leaf has the dtype the model declares: no ``cache_dtype``
+        self.model = model
+        self.dm = model.clone(decode=True, max_len=self.capacity)
+        self.dm_chunk = None
+        self.cache = init_state_cache(model, self.n_slots, self.capacity)
+        return params
+
+    def _decode_program(self, params, cache, tokens):
+        return state_decode_apply(self.dm, params, cache, tokens)[:2]
+
+    def _prefill_program(self, params, cache, tokens, lengths, slot_ids):
+        return state_prefill_apply(self.dm, params, cache, tokens, lengths,
+                                   slot_ids)
+
+    def _decode_k_program(self, params, cache, *args):
+        return state_decode_k_apply(self.dm, params, cache, *args)
+
+    def _shardings(self, mesh, axis):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        repl = NamedSharding(mesh, P())     # every leaf whole on every device
+        return repl, jax.tree_util.tree_map(lambda _: repl, self.cache)
+
+    def cache_bytes(self) -> int:
+        return self.n_slots * self.slot_bytes
+
+    def cursors(self):
+        return jax.device_get(self.cache["idx"])
+
+    def prefill_chunk(self, *args, **kw):
+        refuse_recurrent(self.model, "chunked prefill")
+
+    def _export_rows(self, slot, fill):
+        # whatever a leaf means: a recurrent state has no rows to cut at
+        # ``fill``; a page is taken whole
+        return jax.tree_util.tree_map(
+            lambda page: np.asarray(  # dlint: disable=DL121 — sanctioned migration pull
+                page[slot]), self.cache)
+
+    def _import_rows(self, slot, pages, cursor):
+        def put(page, row):
+            row = jnp.asarray(row, page.dtype)
+            if row.shape != page.shape[1:]:
+                raise ValueError(f"handoff leaf has shape {row.shape}, "
+                                 f"want {page.shape[1:]}")
+            return page.at[slot].set(row)
+
+        cache = jax.tree_util.tree_map(put, self.cache, pages)
+        cache["idx"] = cache["idx"].at[slot].set(jnp.int32(cursor))
+        self.cache = cache
+
+    def load_params(self, params):
+        self.params = self.place(params)
+
+    def reset(self):
+        self.cache = self.place(init_state_cache(
+            self.model, self.n_slots, self.capacity), pages=True)
+
+
+def serving_step(model, *args, **kw) -> ServingStep:
+    """The step for ``model``, picked once: :class:`StateServingStep` where
+    the model declares its cache, :class:`ServingStep` (K/V pages) else."""
+    cls = StateServingStep if declares_cache(model) else ServingStep
+    return cls(model, *args, **kw)

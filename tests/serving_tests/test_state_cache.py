@@ -1,0 +1,252 @@
+"""A model that declares its own per-slot state (models/hybrid.py: recurrent
+state, convolution tail, latent page — no K/V) through ``Engine.submit`` /
+``step``: the served tokens against the plain reference, the trace counts,
+slot export/import leaf by leaf, what is refused and why, and the route
+counts on the decode span."""
+import functools
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from chainermn_tpu import tracing
+from chainermn_tpu.models.hybrid import HybridLM, layer_pattern
+from chainermn_tpu.models.transformer import TransformerLM
+from chainermn_tpu.serving import (Engine, EngineConfig, ServingStep,
+                                   SpeculativeEngine)
+from chainermn_tpu.serving.kv_cache import slot_bytes
+from chainermn_tpu.serving.state_cache import StateServingStep, serving_step
+
+from tests.models_tests.test_hybrid import SIZES, reference_logits
+
+LENS = [(20, 9), (33, 12), (50, 7), (10, 15), (60, 11), (31, 5)]
+
+
+@functools.lru_cache(maxsize=None)
+def setup():
+    model = HybridLM(pattern=layer_pattern(4, 3, 1), **SIZES)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+def engine(**over):
+    model, params = setup()
+    cfg = dict(n_slots=4, capacity=128, buckets=(32, 64, 128), decode_k=4,
+               prefill_cohort=2)
+    return Engine(model, params, EngineConfig(**dict(cfg, **over)))
+
+
+@functools.lru_cache(maxsize=None)
+def served():
+    eng = engine()
+    rs = np.random.RandomState(0)
+    reqs = [eng.submit(rs.randint(0, 256, (n,)), max_new_tokens=m,
+                       **({} if i % 2 == 0 else
+                          dict(temperature=0.8, top_k=20, seed=i)))
+            for i, (n, m) in enumerate(LENS)]
+    eng.run_until_drained()
+    return eng, reqs
+
+
+def test_one_decode_k_trace_and_one_prefill_trace_a_bucket():
+    eng, reqs = served()
+    assert all(r.state == "done" and len(r.tokens) == m
+               for r, (_, m) in zip(reqs, LENS))
+    assert eng.steps.decode_k_traces == 1
+    assert eng.steps.prefill_traces == {(2, 32): 1, (2, 64): 1}
+
+
+def test_served_greedy_tokens_are_the_references_best():
+    """Logits, not tokens: every greedy served token's reference logit is
+    the reference's largest at that position to within float32 noise."""
+    eng, reqs = served()
+    model, params = setup()
+    for r in reqs[::2]:
+        seq = np.concatenate([r.prompt, r.tokens[:-1]])
+        want = np.asarray(reference_logits(model, params,
+                                           jnp.asarray(seq[None])))[0]
+        rows = want[len(r.prompt) - 1:]
+        gap = rows.max(-1) - rows[np.arange(len(r.tokens)), r.tokens]
+        assert gap.max() < 1e-3, gap
+
+
+def test_last_decode_logits_match_the_reference_row():
+    """What the benchmark's ``state_logit_rms`` compares: after prefill and
+    decode through the cache, a live slot's logits on the device equal the
+    reference's at that position."""
+    model, params = setup()
+    eng = engine()
+    rs = np.random.RandomState(1)
+    req = eng.submit(rs.randint(0, 256, (40,)), max_new_tokens=30)
+    for _ in range(4):
+        # step() syncs internally: one [n_slots, k] int32 pull
+        eng.step()  # dlint: disable=DL104
+    assert req.state == "running" and len(req.tokens) == 17
+    seq = np.concatenate([req.prompt, req.tokens[:-1]])
+    want = np.asarray(reference_logits(model, params,
+                                       jnp.asarray(seq[None])))[0, -1]
+    np.testing.assert_allclose(eng.last_logits[req.slot], want, atol=5e-4)
+
+
+def test_pages_are_the_declared_leaves_slot_major():
+    eng, _ = served()
+    steps = eng.steps
+    leaves = jax.tree_util.tree_leaves(steps.cache)
+    assert all(a.shape[0] == 4 for a in leaves)
+    kda = steps.cache["block_0"]["kda"]
+    assert kda["state"].shape == (4, 4, 16, 16)
+    assert kda["state"].dtype == jnp.float32
+    assert kda["conv"].shape == (4, 3, 3 * 64)
+    assert steps.cache["block_2"]["mla"]["ckv"].shape == (4, 128, 32 + 8)
+    assert steps.cache["idx"].shape == (4,)
+    per_slot = 3 * (4 * 16 * 16 * 4 + 3 * 192 * 4) + 128 * 40 * 4 + 4
+    assert steps.slot_bytes == slot_bytes(steps.cache) == per_slot
+    assert steps.cache_bytes() == 4 * per_slot
+
+
+def test_export_import_round_trip_of_every_leaf_kind():
+    """A frozen decoding session moves to another engine leaf by leaf —
+    recurrent state, convolution tail, latent page, cursor — and continues
+    the exact stream."""
+    model, params = setup()
+    rs = np.random.RandomState(2)
+    prompt = rs.randint(0, 256, (25,))
+    oracle = engine()
+    want = oracle.submit(prompt, max_new_tokens=20)
+    oracle.run_until_drained()
+
+    src, dst = engine(), engine()
+    req = src.submit(prompt, max_new_tokens=20)
+    src.step()
+    src.step()
+    session = src.export_session(req)
+    pages = session["pages"]
+    assert set(pages) == set(src.steps.cache)
+    assert pages["block_0"]["kda"]["state"].shape == (4, 16, 16)
+    assert pages["block_0"]["kda"]["conv"].shape == (3, 192)
+    assert pages["block_2"]["mla"]["ckv"].shape == (128, 40)
+    dst.submit(rs.randint(0, 256, (9,)), max_new_tokens=3)   # takes slot 0
+    dst.step()
+    moved = dst.import_session(session, prompt)
+    for name in ("block_0", "block_1", "block_3"):
+        for leaf in ("state", "conv"):
+            assert np.array_equal(
+                np.asarray(dst.steps.cache[name]["kda"][leaf][moved.slot]),
+                pages[name]["kda"][leaf])
+    assert int(dst.steps.cursors()[moved.slot]) == session["cursor"]
+    src.release_held(req)
+    dst.run_until_drained()
+    assert moved.tokens == want.tokens
+
+
+def test_a_held_slot_rides_along_unchanged():
+    """A frozen session's slot stays in the grid while others decode: its
+    recurrent state must not take the ride-along steps."""
+    eng = engine()
+    rs = np.random.RandomState(3)
+    a = eng.submit(rs.randint(0, 256, (20,)), max_new_tokens=40)
+    b = eng.submit(rs.randint(0, 256, (22,)), max_new_tokens=40)
+    eng.step()
+    eng.export_session(a)
+    before = np.asarray(eng.steps.cache["block_0"]["kda"]["state"][a.slot])
+    other = np.asarray(eng.steps.cache["block_0"]["kda"]["state"][b.slot])
+    eng.step()
+    state = eng.steps.cache["block_0"]["kda"]["state"]
+    assert np.array_equal(np.asarray(state[a.slot]), before)
+    assert not np.array_equal(np.asarray(state[b.slot]), other)
+    eng.resume_session(a)
+    eng.run_until_drained()
+    oracle = engine()
+    want = oracle.submit(a.prompt, max_new_tokens=40)
+    oracle.run_until_drained()
+    assert a.tokens == want.tokens
+
+
+def test_reset_zeroes_every_leaf():
+    eng = engine()
+    eng.submit(np.arange(12), max_new_tokens=3)
+    eng.run_until_drained()
+    assert any(np.asarray(a).any()
+               for a in jax.tree_util.tree_leaves(eng.steps.cache))
+    eng.steps.reset()
+    assert not any(np.asarray(a).any()
+                   for a in jax.tree_util.tree_leaves(eng.steps.cache))
+
+
+@pytest.mark.parametrize("what", ["speculative", "int8-block", "ring wrap",
+                                  "chunked prefill"])
+def test_what_moves_rows_by_cursor_refuses_a_recurrent_state(what):
+    model, params = setup()
+    cfg = EngineConfig(n_slots=2, capacity=64, buckets=(32, 64))
+    with pytest.raises(ValueError, match="recurrent state"):
+        if what == "speculative":
+            SpeculativeEngine(model, params, model, params, cfg, spec_k=2)
+        elif what == "int8-block":
+            serving_step(model, params, 2, 64, kv_dtype="int8-block")
+        elif what == "ring wrap":
+            Engine(model, params, cfg).submit(np.arange(40),
+                                              max_new_tokens=30)
+        else:
+            Engine(model, params, EngineConfig(
+                n_slots=2, capacity=64, buckets=(32, 64), prefill_chunk=8))
+
+
+def test_the_step_is_picked_once_and_a_plain_one_refuses_declared_state():
+    model, params = setup()
+    assert type(serving_step(model, params, 2, 64)) is StateServingStep
+    assert type(engine().steps) is StateServingStep
+    with pytest.raises(ValueError, match="StateServingStep"):
+        ServingStep(model, params, 2, 64)
+    dense = TransformerLM(vocab=43, d_model=32, n_heads=4, n_layers=1,
+                          d_ff=48, max_len=64)
+    dense_params = dense.init(jax.random.PRNGKey(0),
+                              jnp.zeros((1, 8), jnp.int32))["params"]
+    assert type(serving_step(dense, dense_params, 2, 16)) is ServingStep
+
+
+def test_the_switch_training_layer_is_still_refused_and_says_what_serves():
+    moe = TransformerLM(vocab=43, d_model=32, n_heads=4, n_layers=1, d_ff=48,
+                        max_len=64, moe_experts_per_device=2)
+    with pytest.raises(ValueError, match="HybridLM"):
+        ServingStep(moe, {}, 2, 16)
+
+
+def test_route_counts_ride_on_the_decode_span(tmp_path):
+    """Under a profiler session the ``engine.decode.enqueue`` span carries
+    the counts the step computed on the device, and ``engine.admit`` the
+    bytes of state it installed."""
+    eng = engine()
+    rs = np.random.RandomState(4)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        tracing.clear()
+        for n in (20, 30, 12):
+            eng.submit(rs.randint(0, 256, (n,)), max_new_tokens=9)
+        eng.run_until_drained()
+        rows = tracing.rows()
+    finally:
+        jax.profiler.stop_trace()
+    enq = [r for r in rows if r.name == "engine.decode.enqueue"]
+    assert enq and all(
+        {"experts_touched", "pairs_held", "pairs_routed", "expert_load_max",
+         "expert_load_mean", "live"} <= set(r.attrs) for r in enq)
+    for r in enq:
+        a = r.attrs
+        # 3 expert layers, decode_k 4, top-4 routing, 8 held experts
+        assert a["pairs_routed"] <= a["live"] * 4 * 3 * 4
+        assert a["pairs_routed"] % 4 == 0
+        assert a["pairs_held"] <= a["pairs_routed"]
+        assert a["experts_touched"] <= 8 * 3 * 4
+        assert a["expert_load_max"] * 8 >= a["pairs_held"]
+        assert a["expert_load_mean"] == pytest.approx(a["pairs_held"] / 8)
+    assert sum(r.attrs["pairs_routed"] for r in enq) > 0
+    admits = [r for r in rows if r.name == "engine.admit"]
+    assert admits and all(
+        r.attrs["state_bytes"] == r.attrs["admitted"] * eng.steps.slot_bytes
+        for r in admits)
