@@ -43,6 +43,7 @@ from chainermn_tpu.parallel.tensor_parallel import (
     vocab_parallel_cross_entropy,
 )
 from chainermn_tpu.parallel.ulysses import ulysses_attention
+from chainermn_tpu.ops.page_write import write_rows
 from chainermn_tpu.ops.rotary import apply_rope, apply_rope_bhld
 
 __all__ = ["TransformerLM", "TransformerBlock", "generate",
@@ -234,14 +235,14 @@ class TransformerBlock(nn.Module):
                 cv.value = cv.value.at[bidx, safe].set(
                     v.astype(self.dtype), mode="drop")
             elif per_slot:
-                ck.value = jax.vmap(
-                    lambda c, u, s0: jax.lax.dynamic_update_slice(
-                        c, u, (s0, 0, 0)))(
-                    ck.value, k.astype(self.dtype), start)
-                cv.value = jax.vmap(
-                    lambda c, u, s0: jax.lax.dynamic_update_slice(
-                        c, u, (s0, 0, 0)))(
-                    cv.value, v.astype(self.dtype), start)
+                # l == 1 is every serving engine's decode step: one row
+                # per slot at its own cursor, one in-place kernel for K
+                # and V where ops/page_write.py can serve; else, and for
+                # an l > 1 slab, vmap(dynamic_update_slice). Same bytes.
+                with jax.named_scope("cache_write"):
+                    ck.value, cv.value = write_rows(
+                        ck.value, cv.value, k.astype(self.dtype),
+                        v.astype(self.dtype), start)
             else:
                 ck.value = jax.lax.dynamic_update_slice(
                     ck.value, k.astype(self.dtype), (0, start, 0, 0))

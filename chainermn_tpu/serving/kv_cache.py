@@ -92,6 +92,7 @@ import numpy as np
 
 from chainermn_tpu.collectives.quantized import QUANT_BLOCK
 from chainermn_tpu.models.transformer import bhld_to_blhd_params
+from chainermn_tpu.ops.page_write import partitioned_pages
 from chainermn_tpu.serving.sampling import sample_tokens
 
 __all__ = ["init_cache", "cache_bytes", "cache_spec", "slot_bytes", "decode_apply",
@@ -509,7 +510,8 @@ class ServingStep:
 
         def _decode(params, cache, tokens):
             self.decode_traces += 1      # trace-time only: counts compiles
-            return self._decode_program(params, cache, tokens)
+            with self._page_write():
+                return self._decode_program(params, cache, tokens)
 
         kw = {}
         if mesh is not None:
@@ -572,6 +574,14 @@ class ServingStep:
             self.dm, params, f32c, tokens, keys, temps, top_ks, eos_ids,
             remaining, live, park, k)
         return toks, last, keys, repack_cache(cache, f32c, start, k)
+
+    def _page_write(self):
+        """Trace-time scope of the one-token programs: pages split over
+        several devices keep the decode write in its partitionable form
+        (a kernel is one custom call, which the partitioner cannot split;
+        ops/page_write.py). One device, mesh or not, takes the kernel."""
+        return partitioned_pages(
+            self._mesh is not None and self._mesh.size > 1)
 
     def place(self, tree, pages: bool = False):
         """Commit parameters, pages or per-slot state to this step's
@@ -679,9 +689,10 @@ class ServingStep:
             def _decode_k(params, cache, tokens, keys, temps, top_ks,
                           eos_ids, remaining, live, park, _k=kk):
                 self.decode_k_traces += 1   # trace-time only
-                return self._decode_k_program(
-                    params, cache, tokens, keys, temps, top_ks, eos_ids,
-                    remaining, live, park, _k)
+                with self._page_write():
+                    return self._decode_k_program(
+                        params, cache, tokens, keys, temps, top_ks,
+                        eos_ids, remaining, live, park, _k)
 
             kw = {}
             if self._mesh is not None:
