@@ -904,7 +904,8 @@ def check_decode_step_recompile(tree, src, path) -> List[Finding]:
       whose *slice extent* is the loop counter — ``step(toks[:, :t])``
       changes shape every iteration, and shape-polymorphic dispatch
       means one compile per sequence length (the full-recompute decode
-      that ``tools/bench_serve.py`` exists to measure against).
+      ``tests/serving_tests/test_serving_contracts.py`` counts against
+      the cached one).
 
     Fix: hoist the ``jit`` out of the loop and decode from a
     fixed-capacity cache (``serving/kv_cache.py``) so every step sees
@@ -1142,7 +1143,7 @@ def check_per_token_host_sync(tree, src, path) -> List[Finding]:
                 f"'{_callee_name(n)}' materializes decode output on the "
                 "host inside a token loop — the [n_slots, vocab] f32 "
                 "logits cross PCIe once per generated token (vocab × 4 "
-                "bytes/token; bench.py gates the decode path at ≤ 8). "
+                "bytes/token; the decode path is held to ≤ 8). "
                 "Sample on device (serving/sampling.py) and pull int32 "
                 "ids via ServingStep.decode_k, or at least reduce "
                 "on device first — np.asarray(jnp.argmax(...)) moves "

@@ -10,9 +10,9 @@ composing with ``scatter_dataset``/``SubDataset``, the iterators, and the
 trainer exactly like any other dataset.
 
 Decode throughput note: JPEG decode is host-CPU work. On a many-core host
-it hides behind the device step via the prefetch loader; this repo's
-1-core environment decodes ~10^2 img/s, so the PERF benches keep their
-on-device synthetic feed (bench.py) and this path carries the
+it hides behind the device step via the prefetch loader; on a one-core
+host it cannot keep a chip fed, so the benchmark's cells make their
+batches from a seed (``benchmark/``) and this path carries the
 correctness/parity story — the same split the reference makes between
 its benchmark harness and its example scripts.
 """

@@ -43,7 +43,6 @@ from chainermn_tpu.fleet.router import EngineReplica, Router
 from chainermn_tpu.fleet.transport import (Arrival, InProcessTransport,
                                            LoopbackPlane,
                                            ObjectPlaneTransport,
-                                           PairedTransport,
                                            TransportError)
 
 __all__ = [
@@ -57,5 +56,5 @@ __all__ = [
     "EngineReplica", "Router",
     "RolloutController", "RolloutError", "DEFAULT_CHUNK_BYTES",
     "TransportError", "Arrival", "InProcessTransport",
-    "ObjectPlaneTransport", "LoopbackPlane", "PairedTransport",
+    "ObjectPlaneTransport", "LoopbackPlane",
 ]

@@ -224,7 +224,7 @@ def encode_handoff(handoff: dict,
 
 def handoff_payload_bytes(manifest: dict) -> int:
     """Exact wire bytes of the encoded handoff (the blob length the
-    manifest vouches for — what the bench gate prices)."""
+    manifest vouches for)."""
     return int(manifest["bytes"])
 
 
@@ -403,7 +403,7 @@ def encode_handoff_streamed(
 def streamed_wire_bytes(closing_manifest: dict) -> int:
     """Exact wire bytes of the whole streamed handoff: the closing blob
     plus every chunk the closing table commits to (the streamed sibling
-    of :func:`handoff_payload_bytes`, same bench-gate pricing role)."""
+    of :func:`handoff_payload_bytes`)."""
     return int(closing_manifest["bytes"]) + sum(
         int(c["bytes"]) for c in closing_manifest["chunks"])
 

@@ -245,7 +245,7 @@ class DisaggregatedFleet:
     ``wire_format`` picks the handoff codec (``"f32"`` raw/bitwise,
     ``"int8-block"`` quantized at ~0.254× the wire bytes); ``report``
     accumulates the fleet counters (handoffs, wire bytes by format,
-    fallbacks) that ``bench.py``'s fleet gate reads; ``transport``
+    fallbacks); ``transport``
     defaults to an :class:`~chainermn_tpu.fleet.transport.
     InProcessTransport` (pass one with ``wire_delay_ms`` to model DCN
     latency, or wire the pools across processes via

@@ -19,7 +19,7 @@ The fleet-level counters (admission rejections, re-queues after a
 replica death, handoffs by wire format and their exact wire bytes,
 handoff fallbacks) live here because no single engine can see them —
 they are properties of the routing layer. ``summary()`` emits the JSON
-block ``tools/fleet_lm.py`` and the ``bench.py`` fleet gate read.
+block ``tools/fleet_lm.py`` reads.
 """
 
 from __future__ import annotations

@@ -57,7 +57,7 @@ from chainermn_tpu.resilience import chaos
 from chainermn_tpu.resilience.policy import RpcPolicy, policy
 
 __all__ = ["TransportError", "Arrival", "InProcessTransport",
-           "ObjectPlaneTransport", "LoopbackPlane", "PairedTransport",
+           "ObjectPlaneTransport", "LoopbackPlane",
            "HANDOFF_DATA_TAG", "HANDOFF_ACK_TAG"]
 
 #: object-plane tags for the two handoff channels (data and acks ride
@@ -481,50 +481,6 @@ class ObjectPlaneTransport:
     @property
     def receiver_stats(self) -> dict:
         return dict(self._recv.stats)
-
-    def close(self) -> None:
-        pass
-
-
-class PairedTransport:
-    """Two :class:`ObjectPlaneTransport` endpoints glued into the
-    single-object transport interface ``DisaggregatedFleet`` expects.
-
-    A real object plane has one process per end, so the sender face
-    and the receiver face of a channel live in different transports.
-    When one process holds BOTH ends — the bench's localhost-socket
-    drill, the tier-1 socket harness — this adapter routes ``send``
-    to the sender-side transport and ``poll``/``resolve`` to the
-    receiver-side one, while forwarding the stats surfaces
-    (``stats``, ``receiver_stats``, ``last_send_defects``, ``plane``)
-    the fleet's wire-health accounting reads."""
-
-    def __init__(self, sender: ObjectPlaneTransport,
-                 receiver: ObjectPlaneTransport):
-        self.sender = sender
-        self.receiver = receiver
-        self.plane = sender.plane
-
-    def send(self, stream_id: int, manifest: dict, blob: bytes) -> str:
-        return self.sender.send(stream_id, manifest, blob)
-
-    def poll(self, timeout_ms: int = 0) -> List[Arrival]:
-        return self.receiver.poll(timeout_ms=timeout_ms)
-
-    def resolve(self, stream_id: int) -> None:
-        self.receiver.resolve(stream_id)
-
-    @property
-    def stats(self) -> dict:
-        return self.sender.stats
-
-    @property
-    def receiver_stats(self) -> dict:
-        return self.receiver.receiver_stats
-
-    @property
-    def last_send_defects(self):
-        return self.sender.last_send_defects
 
     def close(self) -> None:
         pass

@@ -85,7 +85,6 @@ class ResNet(nn.Module):
     comm: Any = None
     dtype: Any = jnp.float32
     small_inputs: bool = False   # CIFAR stem: 3x3 conv, no maxpool
-    space_to_depth: bool = False  # MXU-friendly stem (see __call__)
 
     @nn.compact
     def __call__(self, x, train: bool = True):
@@ -107,22 +106,6 @@ class ResNet(nn.Module):
         x = x.astype(self.dtype)
         if self.small_inputs:
             x = conv(self.num_filters, (3, 3), name="conv_init")(x)
-        elif self.space_to_depth:
-            # A 7x7/s2 conv on 3 channels feeds the 128-lane MXU 3 lanes at
-            # a time. Space-to-depth(2) reshapes [H,W,3] -> [H/2,W/2,12] and
-            # a 4x4/s1 conv over it covers an 8x8/s2 input window — a
-            # superset of the 7x7/s2 receptive field at 4x the MXU packing.
-            b, h, w, c = x.shape
-            if h % 2 or w % 2:
-                raise ValueError(
-                    f"space_to_depth stem needs even H and W, got {(h, w)}; "
-                    "pad/resize the input or set space_to_depth=False"
-                )
-            x = x.reshape(b, h // 2, 2, w // 2, 2, c)
-            x = x.transpose(0, 1, 3, 2, 4, 5).reshape(b, h // 2, w // 2,
-                                                      4 * c)
-            x = conv(self.num_filters, (4, 4), padding=[(1, 2), (1, 2)],
-                     name="conv_init")(x)
         else:
             x = conv(self.num_filters, (7, 7), (2, 2),
                      padding=[(3, 3), (3, 3)], name="conv_init")(x)

@@ -27,13 +27,13 @@ Reference analog: none (upstream seq2seq computes full softmax CE);
 this is the TPU-native counterpart of the vocab-parallel CE idea applied
 to the single-chip memory axis instead of the model-parallel axis.
 
-MEASURED (v5e, 2026-07-31, bench_lm config): throughput-NEUTRAL —
-105.4k tok/s fused vs 104.7k unfused at L=2048/b=8; 56.8k vs 56.7k at
-L=8192/b=2. XLA's own CE fusion already avoids most of the naive
-round-trips, so the win is MEMORY, not time: the [B·L, V] f32 buffer
-(2 GB at the bench shape) disappears from the activation footprint.
-Use it when logits memory is the binding constraint (big vocab, long L,
-grad accumulation); the default losses stay unfused.
+Measured on this tree: the three kernels run at 93.7% of their compute
+roofline in both training cells (``train_fused_ce_roofline``,
+PERF_LEDGER.jsonl); fused against unfused throughput: not measured on
+this tree. What it buys is MEMORY: the [B·L, V] f32 logits buffer
+disappears from the activation footprint. Use it when logits memory is
+the binding constraint (big vocab, long L, grad accumulation); the
+default losses stay unfused.
 """
 
 from __future__ import annotations

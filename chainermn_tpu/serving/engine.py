@@ -420,7 +420,8 @@ class Engine:
         and cursor, restore the PRNG key and sampling rows, and resume
         decoding from the handed-off last token. The resumed stream is
         bitwise-identical to the exporting engine continuing (raw wire
-        format) — the disaggregation contract bench.py gates."""
+        format) — the disaggregation contract
+        (``tests/fleet_tests/test_handoff.py``)."""
         if not self.free_slots:
             raise RuntimeError("no free slot to import a handoff into")
         hv = handoff.get("weights_version")

@@ -62,11 +62,13 @@ Every compiled program dequantizes ONCE at dispatch entry
 (:func:`unpack_cache`) and re-quantizes only the columns the dispatch
 actually wrote (:func:`repack_cache`): requantization is not provably
 idempotent (the re-derived scale can differ by 1 ulp), so untouched
-columns must keep their exact resident bytes. ~3.5–4× more slots per
-chip at equal cache memory; accuracy is held to a calibrated
-logit-error gate (bench.py ``specdec_gate_ok``) rather than bitwise
-parity, and peak transient memory during a dispatch is the f32
-working copy — the win is the RESIDENT footprint between dispatches.
+columns must keep their exact resident bytes. By ``cache_bytes``'
+count a slot's resident pages are under 1/3.5 of float32 pages'
+(``tests/serving_tests/test_serving_contracts.py``; device memory: not
+measured); accuracy is held to a calibrated logit-error bound
+(``tests/serving_tests/test_kv_cache.py``) rather than bitwise parity, and
+peak transient memory during a dispatch is the f32 working copy — the
+win is the RESIDENT footprint between dispatches.
 No mesh sharding and no ring wrap in this mode — the engine enforces
 ``prompt + max_new ≤ capacity`` at submit.
 
@@ -472,7 +474,7 @@ class ServingStep:
     replicated. ``prefill()`` compiles one program per (cohort, bucket)
     shape — bucket lengths are the engine's admission policy; the
     per-shape jit cache plus the trace counters below make recompiles
-    observable (``tools/bench_serve.py`` asserts decode traces == 1).
+    observable (``test_serving_contracts.py`` asserts decode traces == 1).
     """
 
     def __init__(self, model, params, n_slots: int, capacity: int, *,

@@ -7,8 +7,8 @@ occupancy) that tell an operator whether the fleet is sized right.
 
 Everything is recorded as plain floats against an injectable clock
 (``time_fn``) so tests drive it deterministically; ``summary()`` folds
-the raw samples into the JSON block ``tools/bench_serve.py`` and the
-``bench.py`` serving section emit. Field reference: docs/serving.md.
+the raw samples into the JSON block ``tools/serve_lm.py --report``
+emits. Field reference: docs/serving.md.
 """
 
 from __future__ import annotations
@@ -138,8 +138,9 @@ class ServingReport:
         this per dispatch with the pulled array's ``nbytes``). With
         on-device sampling this is int32 token ids only — the
         ``host_bytes_per_token`` summary key is the observable DL110
-        exists to keep small (bench.py gates decode traffic at
-        ≤ 8 bytes/token; the old full-logits pull was ``vocab × 4``)."""
+        exists to keep small (``test_serving_contracts.py`` holds decode
+        traffic to ≤ 8 bytes/token; the old full-logits pull was
+        ``vocab × 4``)."""
         self.host_bytes += int(nbytes)
 
     def record_spec_round(self, proposed: int, accepted: int,

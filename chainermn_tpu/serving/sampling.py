@@ -8,8 +8,8 @@ over PCIe once per generated token. Here sampling compiles INTO the
 decode program: :func:`sample_tokens` is pure jax, takes the per-slot
 PRNG keys/temperatures/top-k the engine threads as state, and returns
 int32 token ids — so a ``decode_k`` dispatch transfers ``O(n_slots)``
-ids instead of ``O(n_slots × vocab)`` floats (gated ≤ 8 bytes/token in
-bench.py).
+ids instead of ``O(n_slots × vocab)`` floats (held to ≤ 8 bytes/token by
+``tests/serving_tests/test_serving_contracts.py``).
 
 Encoding conventions (the engine's ``None`` → array mapping):
 

@@ -58,7 +58,8 @@ own pages from wrapping too.
 
 Host-transfer honesty: a round moves ``4 · (spec_k + 1)`` bytes per
 slot for 1..``spec_k + 1`` emitted tokens, so the ≤ 8 bytes/token
-decode gate (DL110/bench.py) holds only at healthy acceptance rates;
+bound of the plain decode path (DL110) holds only at healthy acceptance
+rates;
 ``ServingReport.acceptance_rate`` / ``tokens_per_dispatch`` are the
 observability for exactly that (reports.py).
 """

@@ -43,7 +43,7 @@ def use_compile_cache() -> str:
     it itself, so nothing is set here. Otherwise the cache lives in
     ``<checkout>/.jax_cache`` (gitignored): the path is part of the cache
     key's lookup, so it must not move between runs. Entry points
-    (chip_smoke.py, bench.py, tools/) call this once before compiling."""
+    (chip_smoke.py, tools/) call this once before compiling."""
     import jax
 
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")

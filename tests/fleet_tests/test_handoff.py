@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from chainermn_tpu.collectives.quantized import (QUANT_BLOCK,
                                                  block_quantize)
+from chainermn_tpu.fleet import DisaggregatedFleet
 from chainermn_tpu.fleet.handoff import (HandoffError, decode_handoff,
                                          encode_handoff,
                                          handoff_payload_bytes)
@@ -126,7 +127,7 @@ def test_int8_block_logit_error_calibrated():
 def test_wire_bytes_exact_and_quantized_ratio():
     """manifest["bytes"] is the exact blob length; with one-block
     leaves the int8-block wire is (256 + 4)/1024 of raw + the shared
-    key tail — comfortably under the 0.27 bench gate."""
+    key tail — comfortably under 0.27 of raw."""
     handoff, _prompt = _handoff()
     m_raw, b_raw = encode_handoff(handoff, "f32")
     m_q, b_q = encode_handoff(handoff, "int8-block")
@@ -420,3 +421,32 @@ def test_f32_source_wire_is_unchanged_by_resident_support():
     assert manifest["codec"]["block"] == QUANT_BLOCK
     out = decode_handoff(manifest, blob)
     assert "pages_q8" not in out
+
+
+# ---------------------------------------------------------------------------
+# the codec under the fleet: prefill on one engine, decode on another
+# ---------------------------------------------------------------------------
+
+def test_raw_disagg_streams_bitwise_vs_single_engine():
+    """The disaggregation contract on real engines: prefill on engine A,
+    f32 handoff, decode on engine B — every stream is the single
+    engine's, token for token, with no fallback. (Chunked prefill and
+    sampled streams: the slow ``test_pools.py``.)"""
+    model, params = _setup()
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, VOCAB, (PROMPT_LEN,)).astype(np.int32)
+               for _ in range(4)]
+    fleet = DisaggregatedFleet(Engine(model, params, _cfg()),
+                               Engine(model, params, _cfg()))
+    streams = [fleet.submit(p, max_new_tokens=6) for p in prompts]
+    fleet.run_until_drained()
+
+    single = Engine(model, params, _cfg())
+    reqs = [single.submit(p, max_new_tokens=6) for p in prompts]
+    single.run_until_drained()
+
+    assert [list(s.tokens) for s in streams] == [list(r.tokens)
+                                                 for r in reqs]
+    assert all(s.finished and not s.fell_back for s in streams)
+    assert fleet.report.handoffs == len(prompts)
+    assert fleet.report.handoff_fallbacks == 0

@@ -52,15 +52,15 @@ def _prompts(seed=0, lens=(3, 4, 4, 3)):
     return [rng.randint(0, VOCAB, (l,)).astype(np.int32) for l in lens]
 
 
-@pytest.mark.parametrize("chunk", [None, 3, 5])
+@pytest.mark.parametrize("chunk", [3, 5])
 def test_raw_disagg_streams_bitwise_vs_single_engine(chunk):
-    """The acceptance bitwise gate: prefill on engine A (chunked or
-    monolithic), decode on engine B, stream == single-engine Engine ==
-    generate(), token for token."""
+    """The acceptance bitwise gate with a chunked prefill on engine A,
+    decode on engine B: stream == single-engine Engine == generate(),
+    token for token. (Monolithic prefill: tier-1,
+    ``test_handoff.py::test_raw_disagg_streams_bitwise_vs_single_engine``.)"""
     model, params = _setup()
     prompts = _prompts()
-    pre_cfg = (_cfg(prefill_chunk=chunk, buckets=None) if chunk
-               else _cfg())
+    pre_cfg = _cfg(prefill_chunk=chunk, buckets=None)
     fleet = DisaggregatedFleet(Engine(model, params, pre_cfg),
                                Engine(model, params, _cfg()))
     streams = [fleet.submit(p, max_new_tokens=N_NEW) for p in prompts]

@@ -60,7 +60,7 @@ def test_abort_and_scheduler_samples():
     assert s["requests"]["aborted"] == 1
     assert s["queue_depth"]["max"] == 1
     assert abs(s["slot_occupancy"]["mean"] - 0.75) < 1e-9
-    # the JSON face round-trips (bench_serve consumes it)
+    # the JSON face round-trips (tools/serve_lm.py --report writes it)
     assert json.loads(rep.json())["requests"]["submitted"] == 2
 
 

@@ -318,7 +318,7 @@ def test_greedy_engine_ignores_seed():
 def test_host_bytes_per_token_is_4():
     """The report's observable for DL110: with on-device sampling the
     emit path moves exactly one int32 per token — padding rows included
-    still lands ≤ 8 bytes/token (the bench.py gate)."""
+    still lands ≤ 8 bytes/token."""
     model, params = _setup()
     prompts = _prompts(10, [4, 4])
     cfg = EngineConfig(n_slots=2, capacity=32, max_new_tokens=6,

@@ -112,7 +112,7 @@ def test_tune_accepts_a_plan_object_directly(comm):
 
 def test_untuned_optimizer_has_no_plan(comm):
     # legacy contract: no reducer + no tune -> plain optax transform;
-    # consumers probe the plan with getattr (see tools/bench_lm.py)
+    # consumers probe the plan with getattr
     opt = chainermn_tpu.create_multi_node_optimizer(optax.sgd(1.0), comm)
     assert getattr(opt, "plan", None) is None
     with_reducer = chainermn_tpu.create_multi_node_optimizer(

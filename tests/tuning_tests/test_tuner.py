@@ -126,6 +126,21 @@ def test_tuner_exploits_an_outer_tier():
     assert res.plan.buckets  # per-bucket algorithm record is filled
 
 
+def test_synth_beats_every_fixed_reducer_on_the_canned_fixture():
+    """The PR's acceptance bar: on at least one canned fixture the
+    winner is a SYNTHESIZED program whose DL201 overlap fraction is
+    strictly above the best any fixed strategy achieves (the staged
+    scatter pipeline issues its first collective one emission earlier)."""
+    res = tune_canned(two_tier(4, 2), GRAD_BYTES)
+    assert res.plan.strategy == "synth"
+    assert res.plan.program is not None
+    assert res.plan.buckets[0][0].startswith("synth:")
+    best_fixed = max(r["overlap_fraction"] for r in res.rows
+                     if r["candidate"]["strategy"] != "synth")
+    assert res.plan.overlap_fraction > best_fixed
+    assert res.improves_overlap
+
+
 def test_candidate_grid_respects_opt_ins():
     flat_only = default_candidates(single_tier(8))
     assert {c.strategy for c in flat_only} == {"flat"}
