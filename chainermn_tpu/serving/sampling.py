@@ -53,35 +53,76 @@ def split_keys(keys):
     return both[:, 0], both[:, 1]
 
 
+def _ordered_bits(x):
+    """uint32 keys whose unsigned order is the float order of ``x`` (f32,
+    no NaN): a negative float has every bit flipped, any other its sign
+    bit. ``-0.0`` and ``+0.0`` get ONE key, because ``>=`` holds them
+    equal."""
+    b = jax.lax.bitcast_convert_type(x, jnp.uint32)
+    flip = jnp.where(b >> 31 == 1, jnp.uint32(0xFFFFFFFF),
+                     jnp.uint32(0x80000000))
+    return jnp.where(x == 0, jnp.uint32(0x80000000), b ^ flip)
+
+
+def _kth_largest(keys, k):
+    """Per row the ``k``-th largest of ``keys`` (``[n, v]`` uint32; ``k``
+    ``[n]`` int32 in ``1..v``), EXACTLY and without sorting: the largest
+    ``t`` with ``count(keys >= t) >= k``, found by bisecting its 32 bits
+    from the top — each pass is one compare-and-count reduction over the
+    row, and keeps the bit it tried iff ``k`` entries still reach it."""
+    def one_bit(i, t):
+        cand = t | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+        cnt = jnp.sum(keys >= cand[:, None], axis=-1, dtype=jnp.int32)
+        return jnp.where(cnt >= k, cand, t)
+
+    return jax.lax.fori_loop(0, 32, one_bit,
+                             jnp.zeros(keys.shape[:1], jnp.uint32))
+
+
+def _top_k_mask(scaled, top_k):
+    """Where ``scaled`` is at or above its row's ``top_k``-th largest
+    value (``top_k`` clipped to ``1..vocab``): ties AT that value all
+    count, so a row can keep more than ``top_k`` entries."""
+    keys = _ordered_bits(scaled)
+    kth = _kth_largest(keys, jnp.clip(top_k, 1, scaled.shape[-1]))
+    return keys >= kth[:, None]
+
+
 @jax.named_scope("sample")   # the one sampling site of every program
 def sample_tokens(logits, keys, temperature, top_k):
     """One sampled token per row, entirely on device.
 
-    logits ``[n, vocab]`` f32; keys ``[n, 2]`` uint32; temperature
-    ``[n]`` f32 (``<= 0`` → greedy); top_k ``[n]`` int32 (``<= 0`` → full
-    vocab). Returns ``(tokens [n] int32, new_keys [n, 2])``.
+    logits ``[n, vocab]`` f32 (no NaN); keys ``[n, 2]`` uint32;
+    temperature ``[n]`` f32 (``<= 0`` → greedy); top_k ``[n]`` int32
+    (``<= 0`` → full vocab). Returns ``(tokens [n] int32, new_keys
+    [n, 2])``.
 
     Every row consumes exactly one split — greedy rows too — so the key
     stream position depends only on how many tokens a slot has sampled,
     never on its neighbours' sampling modes. Callers freeze keys for
     rows that didn't really sample (dead/pad rows) with a ``where`` on
     the returned keys.
+
+    Top-k keeps every logit at or above the row's k-th largest value:
+    ties AT that value all stay, ``top_k >= vocab`` keeps everything.
+    The value is found by selection (:func:`_top_k_mask`: 32 counting
+    passes over the row), not by sorting the vocabulary; the kept set,
+    and with it the tokens and keys, are bitwise what a full descending
+    sort and a gather of its k-th entry give
+    (tests/serving_tests/test_sampling.py keeps that form as the oracle).
     """
     logits = logits.astype(jnp.float32)
-    n, v = logits.shape
     temperature = jnp.asarray(temperature, jnp.float32)
     top_k = jnp.asarray(top_k, jnp.int32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
     new_keys, sub = split_keys(keys)
     scaled = logits / jnp.maximum(temperature, 1e-6)[:, None]
-    # top-k truncation with a TRACED k: sort descending, gather the
-    # k-th value per row, mask everything strictly below it (same
-    # >=-kth tie rule as generate()'s static-k lax.top_k path)
-    kth_idx = jnp.clip(top_k - 1, 0, v - 1)
-    srt = -jnp.sort(-scaled, axis=-1)
-    kth = jnp.take_along_axis(srt, kth_idx[:, None], axis=-1)
-    truncated = jnp.where(scaled >= kth, scaled, -jnp.inf)
+    # top-k truncation with a TRACED k: find the k-th largest value per
+    # row by selection on order-preserving integer keys, mask everything
+    # strictly below it (same >=-kth tie rule as generate()'s static-k
+    # lax.top_k path)
+    truncated = jnp.where(_top_k_mask(scaled, top_k), scaled, -jnp.inf)
     scaled = jnp.where((top_k > 0)[:, None], truncated, scaled)
     sampled = jax.vmap(jax.random.categorical)(sub, scaled).astype(jnp.int32)
 

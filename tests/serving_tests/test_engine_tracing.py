@@ -168,4 +168,7 @@ def test_the_serving_programs_name_their_attention_and_their_sampling(
         debug_info=True)
     scoped = [l for l in text.splitlines() if "attend_cache/" in l]
     assert any("dot_general" in l for l in scoped), scoped[:5]
-    assert any("/sample/" in l and "sort" in l for l in text.splitlines())
+    # the selection of the top-k threshold: its counting loop, and no sort
+    sampling = [l for l in text.splitlines() if "/sample/" in l]
+    assert any("/sample/while" in l for l in sampling), sampling[:5]
+    assert not any("sort" in l for l in sampling)
