@@ -13,10 +13,26 @@ and per layer one MIXER of
   (:func:`kda_step`); both are f32.
 * ``"mla"`` — multi-head latent attention (DeepSeek-V2): the cache holds a
   512-value latent and one shared rotary key per token; prefill expands
-  them to per-head keys and values, decode uses the absorbed form.
+  them to per-head keys and values, decode uses the absorbed form. The
+  query is full-rank or low-rank with its own norm (``q_rank``), the output
+  gate is optional (``mla_gate``), the rotary frequencies plain or YaRN's
+  (``rope_scaling``). With ``mla_block`` set the page is read in blocks of
+  columns under an online softmax (:func:`latent_chunk_attention`,
+  :func:`latent_decode_attention`): a chunk of queries at any cursor
+  attends the filled page plus itself with no score array over the page,
+  and a decode step reads the columns the live rows have filled, not
+  ``max_len`` — what 8k-32k prompts need.
 
 and one FEED-FORWARD of ``"dense"`` (SwiGLU) or ``"moe"`` (this chip's share
 of a routed expert layer, ``parallel/expert_share.py``, plus a shared expert).
+
+The residual path is the plain sum (``hc_mult`` 1) or manifold-constrained
+hyper-connections (mHC, arXiv:2512.24880; :class:`HyperConnection`): the
+state is ``n = hc_mult`` streams a token, ``X ∈ R^{n×d}``, and around each
+sub-layer ``F`` three maps computed from the state itself — ``H_pre`` mixes
+the streams into ``F``'s input, ``H_post`` spreads ``F``'s output over them,
+``H_res`` (doubly stochastic, by Sinkhorn rounds) mixes the streams among
+themselves: ``X' = H_res X + H_postᵀ ⊗ F(norm(H_pre X))``.
 
 Serving contract (``serving/state_cache.py`` reads ``declares_cache``): with
 ``decode=True`` the ``cache`` collection holds, slot-major, whatever the
@@ -26,14 +42,26 @@ latent page per MLA layer — and one cursor vector ``idx`` at the root.
 ``lengths[b]`` tokens (right-padded rows: a recurrence integrates padding
 unless told not to, so positions at or past the length get ``β = 0``,
 ``α = 1`` and leave the state exactly as it was) and leaves rows that are
-not ``live`` untouched apart from page columns past their cursor.
+not ``live`` untouched apart from page columns past their cursor. With
+``pos_offset`` and ``slots`` (blocked latent pages only) the call's rows are
+rows ``slots`` of a cache that holds more rows than the call has: a chunk
+cohort against the whole grid's pages, where they lie.
+
+What a leaf of that collection may do: a POSITIONAL leaf
+(``HybridLM.positional_leaves``: the latent page ``ckv``, axis 1 the
+position) is addressed by the cursor like K/V rows, so a call may start at
+any cursor (``pos``) and a prompt may arrive in chunks; a RECURRENT leaf
+(every other one but ``idx``: KDA's ``state`` and ``conv``) is the sum of
+its history, starts from what the slot holds and cannot be rewound,
+re-windowed or written at a column.
 """
 
 from __future__ import annotations
 
 import collections
 import dataclasses
-from typing import Any, Tuple
+import math
+from typing import Any, Mapping, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -41,12 +69,18 @@ import jax.numpy as jnp
 
 from chainermn_tpu.parallel.expert_share import HeldExperts, RouteStats
 
-__all__ = ["HybridLM", "HybridBlock", "KDAMixer", "MLAMixer", "RMSNorm",
-           "SwiGLU", "kda_chunk", "kda_step", "layer_pattern"]
+__all__ = ["HybridLM", "HybridBlock", "HyperConnection", "KDAMixer",
+           "MLAMixer", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
+           "latent_chunk_attention", "latent_decode_attention",
+           "layer_pattern", "sinkhorn", "yarn_inv_freq", "yarn_mscale"]
 
 _HI = jax.lax.Precision.HIGHEST
 KDA_CHUNK = 64
 _SUB = 16        # sub-block inside a chunk: keeps every exponent <= 0
+#: page columns of one row a blocked decode step reads per loop iteration: one
+#: query a row makes a block cheap to score, so it is wide to keep the loop
+#: short
+DECODE_BLOCK = 4096
 
 
 class RMSNorm(nn.Module):
@@ -265,19 +299,196 @@ class KDAMixer(nn.Module):
 # MLA
 # ---------------------------------------------------------------------------
 
-def rope_interleaved(x, positions, theta):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1·mscale·ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(d: int, theta: float, scaling: Mapping[str, Any]):
+    """The ``d/2`` rotary frequencies under YaRN (arXiv:2309.00071, as
+    DeepSeek-V2 publishes it): pair ``i`` turns by ``theta^(-2i/d)`` where
+    it makes more than ``beta_fast`` rotations over the original context,
+    by that over ``factor`` where it makes fewer than ``beta_slow``, and by
+    a linear blend of the two between (the ramp runs over pair indices)."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(rotations):
+        return (d * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(scaling.get("beta_fast", 32))), 0)
+    high = min(math.ceil(correction_dim(scaling.get("beta_slow", 1))), d - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(d // 2, dtype=jnp.float32)
+    extra = theta ** (-2.0 * i / d)
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return extra / factor * ramp + extra * (1.0 - ramp)
+
+
+def rope_interleaved(x, positions, theta, inv_freq=None, mscale=1.0):
     """Rotate adjacent pairs ``(x_{2i}, x_{2i+1})`` of the last axis by
-    ``positions · theta^{-2i/d}``. x ``[B, L, ..., d]``, positions
-    ``[B, L]``."""
+    ``positions · theta^{-2i/d}`` (or by ``inv_freq``, with cos and sin
+    scaled by ``mscale``). x ``[B, L, ..., d]``, positions ``[B, L]``."""
     d = x.shape[-1]
-    freqs = theta ** (-jnp.arange(d // 2, dtype=jnp.float32) * 2.0 / d)
+    freqs = (theta ** (-jnp.arange(d // 2, dtype=jnp.float32) * 2.0 / d)
+             if inv_freq is None else inv_freq)
     ang = positions.astype(jnp.float32)[..., None] * freqs     # [B, L, d/2]
     ang = ang.reshape(ang.shape[:2] + (1,) * (x.ndim - 3) + (d // 2,))
     cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., 0::2], x32[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.reshape(x.shape)
+
+
+def _write_window(page, chunk, pos, n, slots):
+    """``page [N, T, w]`` with ``chunk[b, :n[b]]`` written into row
+    ``slots[b]`` at columns ``pos[b] ..`` and everything else as it was
+    (``chunk [B, C, w]``, ``C <= T``; a slot past ``N`` writes nothing):
+    one window a chunk row, read, blended and put back where the page
+    lies."""
+    rows, t, w = page.shape
+    c = chunk.shape[1]
+    chunk = chunk.astype(page.dtype)
+    for b in range(chunk.shape[0]):
+        s0 = jnp.clip(pos[b], 0, t - c)     # the window stays inside the page
+        off = pos[b] - s0
+        j = jnp.arange(c)
+        keep = (j >= off) & (j - off < n[b]) & (slots[b] < rows)
+        at = (jnp.minimum(slots[b], rows - 1), s0, 0)
+        new = jnp.where(keep[:, None], jnp.roll(chunk[b], off, axis=0),
+                        jax.lax.dynamic_slice(page, at, (1, c, w))[0])
+        page = jax.lax.dynamic_update_slice(page, new[None], at)
+    return page
+
+
+def _blocks(page, top, block, slots):
+    """(block width, number of blocks that cover columns ``< top``, a
+    function ``(page, j) -> (block j of the rows ``slots`` [B, block, w], its
+    column ids, which of them are block j's own)``). The last block of a
+    page that is no multiple of the width starts early, inside the page, and
+    disowns the columns the block before it has."""
+    rows, t, w = page.shape
+    block = min(block, t)
+
+    def take(page, j):
+        s0 = jnp.minimum(j * block, t - block)
+        col = s0 + jnp.arange(block)
+        blk = jnp.concatenate([jax.lax.dynamic_slice(
+            page, (jnp.minimum(slots[b], rows - 1), s0, 0), (1, block, w))
+            for b in range(slots.shape[0])])
+        return blk, col, col >= j * block
+
+    return block, (top + block - 1) // block, take
+
+
+def latent_chunk_attention(q_nope, q_rope, page, w_kvb, pos, scale, block,
+                           slots=None):
+    """Causal attention of a chunk of queries over a latent page, EXPANDED
+    block by block. ``q_nope [B, C, H, dn]``, ``q_rope [B, C, H, dr]``: the
+    queries at positions ``pos[b] + 0..C-1``; row ``slots[b]`` (``b``
+    itself without ``slots``) of ``page [N, T, >= r + dr]`` holds ``[c |
+    k_r]`` (and maybe padding) for every column up to those positions (the
+    chunk's own included); ``w_kvb [r, H, dn + dv]``. Each block of
+    ``block`` columns is re-expanded to per-head keys and values (640 flop
+    a query-key pair and head against the absorbed form's 2,176) and folded
+    into a running softmax, so the largest score array is ``[B, H, C,
+    block]`` whatever the page's length, and the loop stops at the last
+    column a query sees. Returns ``([B, C, H, dv]`` float32, the page``)``:
+    the page rides the loop's carry and the caller keeps what comes out, so
+    that a page just written is read where it lies (one that the loop only
+    closed over stayed live beside it, and the compiler copied all of it a
+    call)."""
+    b, c, h, dn = q_nope.shape
+    r = w_kvb.shape[0]
+    dv = w_kvb.shape[-1] - dn
+    qpos = pos[:, None] + jnp.arange(c)[None]                   # [B, C]
+    block, n_blocks, take = _blocks(
+        page, jnp.max(pos) + c, block,
+        jnp.arange(b) if slots is None else slots)
+
+    def body(j, carry):
+        page, m, l, acc = carry
+        blk, col, own = take(page, j)
+        kv = jnp.einsum("btr,rhe->bthe", blk[..., :r], w_kvb)
+        s = (jnp.einsum("bqhe,bkhe->bhqk", q_nope, kv[..., :dn],
+                        preferred_element_type=jnp.float32)
+             + jnp.einsum("bqhe,bke->bhqk", q_rope,
+                          blk[..., r:r + q_rope.shape[-1]],
+                          preferred_element_type=jnp.float32)) * scale
+        seen = (col[None, None] <= qpos[:, :, None]) & own       # [B, C, blk]
+        s = jnp.where(seen[:, None], s, -jnp.inf)
+        m_new = jnp.maximum(m, s.max(-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = alpha * l + p.sum(-1)
+        acc = alpha[..., None] * acc + jnp.einsum(
+            "bhqk,bkhe->bhqe", p.astype(kv.dtype), kv[..., dn:],
+            preferred_element_type=jnp.float32)
+        return page, m_new, l, acc
+
+    # column 0 is seen by every query, so ``m`` is finite after block 0
+    init = (page, jnp.full((b, h, c), -jnp.inf, jnp.float32),
+            jnp.zeros((b, h, c), jnp.float32),
+            jnp.zeros((b, h, c, dv), jnp.float32))
+    page, _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    return jnp.moveaxis(acc / l[..., None], 1, 2), page
+
+
+def latent_decode_attention(q_cat, page, pos, live, scale, r,
+                            block=DECODE_BLOCK):
+    """One query a row over its latent page, ABSORBED, block by block.
+    ``q_cat [B, H, r + dr]`` (the no-rope query already through
+    ``W_kvb``'s key half, beside the rotary query); row ``b`` sees columns
+    ``<= pos[b]``. ONE loop over the (row, block) pairs that hold a column a
+    LIVE row has filled, each a plain product of one row's block ``[block,
+    r + dr]`` with that row's heads: a step reads what is cached, row by
+    row, not the page's capacity (a row that is not live is not visited and
+    gets zeros; nobody reads it). The block is the left operand of the
+    scores and the right one of the values, so it is read as it lies. (A
+    product batched over the rows, ``bhc,btc->bht``, made the compiler
+    re-lay the whole page with its columns minor around the loop: a copy of
+    every page a step.) Returns ``(``the attended latents ``[B, H, r]``
+    float32, the page``)``, the page through the loop's carry as in
+    :func:`latent_chunk_attention`."""
+    b, h, w = q_cat.shape
+    t = page.shape[1]
+    block = min(block, t)
+    n_of = jnp.where(live, pos // block + 1, 0)     # blocks a row reads
+    ends = jnp.cumsum(n_of)
+
+    def body(i, carry):
+        page, m, l, acc = carry
+        row = jnp.minimum(jnp.searchsorted(ends, i, side="right"), b - 1)
+        j = i - (ends[row] - n_of[row])
+        s0 = jnp.minimum(j * block, t - block)
+        blk = jax.lax.dynamic_slice(page, (row, s0, 0), (1, block, w))[0]
+        at = lambda a: jax.lax.dynamic_index_in_dim(a, row, 0, False)
+        s = jnp.dot(blk, at(q_cat).T,
+                    preferred_element_type=jnp.float32) * scale   # [blk, H]
+        col = s0 + jnp.arange(block)
+        seen = (col <= at(pos)) & (col >= j * block)
+        s = jnp.where(seen[:, None], s, -jnp.inf)
+        m_new = jnp.maximum(at(m), s.max(0))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(at(m) - m_new)
+        put = lambda a, v: jax.lax.dynamic_update_index_in_dim(a, v, row, 0)
+        return (page, put(m, m_new), put(l, alpha * at(l) + p.sum(0)),
+                put(acc, alpha[:, None] * at(acc) + jnp.dot(
+                    p.T.astype(blk.dtype), blk[:, :r],
+                    preferred_element_type=jnp.float32)))
+
+    # a row's block 0 holds column 0, which the row sees: ``m`` is finite
+    # from its first visit on
+    init = (page, jnp.full((b, h), -jnp.inf, jnp.float32),
+            jnp.zeros((b, h), jnp.float32),
+            jnp.zeros((b, h, r), jnp.float32))
+    page, _, l, acc = jax.lax.fori_loop(0, ends[-1], body, init)
+    return jnp.where(l[..., None] > 0, acc / l[..., None], 0.0), page
 
 
 class MLAMixer(nn.Module):
@@ -291,33 +502,87 @@ class MLAMixer(nn.Module):
     eps: float = 1e-6
     dtype: Any = jnp.float32
     decode: bool = False
+    q_rank: Optional[int] = None     # None: one full-rank query projection
+    gate: bool = True                # the head-wise output gate
+    rope_scaling: Optional[Mapping[str, Any]] = None    # YaRN's keys
+    block: int = 0                   # 0: score the whole page in one piece
 
     @nn.compact
-    def __call__(self, x, pos):
+    def __call__(self, x, pos, lengths=None, live=None, slots=None):
+        """``slots [B]`` (blocked mode only): the cache's rows this call's
+        rows live in, where the cache holds more rows than the call has (a
+        chunk cohort against the whole grid's pages)."""
         b, l, d = x.shape
         h, dn, dr, dv, r = (self.n_heads, self.d_nope, self.d_rope,
                             self.d_v, self.kv_rank)
         dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
                                          param_dtype=self.dtype, name=name)
         positions = pos[:, None] + jnp.arange(l)[None]
-        q = dense(h * (dn + dr), "q_proj")(x).reshape(b, l, h, dn + dr)
-        q_nope = q[..., :dn]
-        q_rope = rope_interleaved(q[..., dn:], positions,
-                                  self.rope_theta).astype(self.dtype)
+        scale = (dn + dr) ** -0.5
+        inv_freq, mscale = None, 1.0
+        if self.rope_scaling is not None:
+            rs = dict(self.rope_scaling)
+            if rs.get("type", "yarn") != "yarn":
+                raise ValueError(f"rope_scaling type {rs['type']!r}: only "
+                                 "'yarn' is implemented")
+            inv_freq = yarn_inv_freq(dr, self.rope_theta, rs)
+            all_dim = rs.get("mscale_all_dim", 0)
+            mscale = (yarn_mscale(rs["factor"], rs.get("mscale", 1))
+                      / yarn_mscale(rs["factor"], all_dim))
+            if all_dim:
+                scale *= yarn_mscale(rs["factor"], all_dim) ** 2
+        rope = lambda a: rope_interleaved(
+            a, positions, self.rope_theta, inv_freq, mscale).astype(
+                self.dtype)
+        if self.q_rank is None:
+            q = dense(h * (dn + dr), "q_proj")(x)
+        else:
+            q = dense(h * (dn + dr), "qb_proj")(RMSNorm(
+                self.eps, self.dtype, name="q_norm")(
+                    dense(self.q_rank, "qa_proj")(x)))
+        q = q.reshape(b, l, h, dn + dr)
+        q_nope, q_rope = q[..., :dn], rope(q[..., dn:])
         kva = dense(r + dr, "kva_proj")(x)
         c = RMSNorm(self.eps, self.dtype, name="c_norm")(kva[..., :r])
-        k_r = rope_interleaved(kva[..., r:], positions,
-                               self.rope_theta).astype(self.dtype)
+        k_r = rope(kva[..., r:])
         ckv = jnp.concatenate([c, k_r], -1)                    # [B, L, r+dr]
         w_kvb = self.param("kvb_proj", nn.initializers.lecun_normal(),
                            (r, h * (dn + dv)), self.dtype)
         w_kvb = w_kvb.reshape(r, h, dn + dv)
-        gate = jax.nn.sigmoid(dense(h, "g_proj")(x).astype(jnp.float32))
-        scale = (dn + dr) ** -0.5
+        if self.gate:
+            gate = jax.nn.sigmoid(dense(h, "g_proj")(x).astype(jnp.float32))
         if self.decode:
+            # read in blocks, the page keeps its rows whole lane tiles wide
+            # (576 -> 640): a last axis that is no multiple of 128 the chip
+            # stores with the COLUMNS minor, and every row written into such
+            # a page re-lays all of it, twice (found compiling for the chip)
+            width = -(-(r + dr) // 128) * 128 if self.block else r + dr
             page_v = self.variable("cache", "ckv", jnp.zeros,
-                                   (b, self.max_len, r + dr), self.dtype)
-        if l > 1:
+                                   (b, self.max_len, width), self.dtype)
+            if width > r + dr:
+                ckv = jnp.pad(ckv, ((0, 0), (0, 0), (0, width - r - dr)))
+        if slots is not None and not (l > 1 and self.block and self.decode):
+            raise ValueError("slots address the pages of a blocked chunk "
+                             "call (mla_block > 0, decode=True, L > 1)")
+        if l > 1 and self.block:
+            # a chunk at any cursor: its latents go into the page at
+            # [pos, pos + length), then the chunk attends the page
+            with jax.named_scope("mla_chunk"):
+                page = ckv
+                if self.decode:
+                    n = jnp.full((b,), l, jnp.int32) if lengths is None \
+                        else lengths
+                    if live is not None:
+                        n = jnp.where(live, n, 0)
+                    page = _write_window(
+                        page_v.value, ckv, pos, n,
+                        jnp.arange(b) if slots is None else slots)
+                o, page = latent_chunk_attention(
+                    q_nope, q_rope, page, w_kvb, pos, scale, self.block,
+                    slots)
+                if self.decode:
+                    page_v.value = page
+        elif l > 1:
             # prefill of a fresh slot (pos 0): the expanded form
             kv = jnp.einsum("blr,rhe->blhe", c, w_kvb)
             k_nope, v = kv[..., :dn], kv[..., dn:]
@@ -339,22 +604,88 @@ class MLAMixer(nn.Module):
                 page = page_v.value
                 page = page.at[jnp.arange(b), pos].set(
                     ckv[:, 0].astype(page.dtype), mode="drop")
-                page_v.value = page
                 q_lat = jnp.einsum("bhe,rhe->bhr", q_nope[:, 0],
                                    w_kvb[..., :dn]).astype(self.dtype)
                 q_cat = jnp.concatenate([q_lat, q_rope[:, 0]], -1)
-                s = jnp.einsum("bhc,btc->bht", q_cat, page,
-                               preferred_element_type=jnp.float32) * scale
-                seen = jnp.arange(page.shape[1])[None] <= pos[:, None]
-                s = jnp.where(seen[:, None], s, -jnp.inf)
-                p = jax.nn.softmax(s, -1).astype(self.dtype)
-                o_lat = jnp.einsum("bht,btr->bhr", p, page[..., :r],
-                                   preferred_element_type=jnp.float32)
+                if self.block:
+                    q_cat = jnp.pad(q_cat, ((0, 0), (0, 0),
+                                            (0, page.shape[-1] - r - dr)))
+                    o_lat, page = latent_decode_attention(
+                        q_cat, page, pos,
+                        jnp.ones((b,), bool) if live is None else live,
+                        scale, r)
+                    page_v.value = page
+                else:
+                    page_v.value = page
+                    s = jnp.einsum("bhc,btc->bht", q_cat, page,
+                                   preferred_element_type=jnp.float32) * scale
+                    seen = jnp.arange(page.shape[1])[None] <= pos[:, None]
+                    s = jnp.where(seen[:, None], s, -jnp.inf)
+                    p = jax.nn.softmax(s, -1).astype(self.dtype)
+                    o_lat = jnp.einsum("bht,btr->bhr", p, page[..., :r],
+                                       preferred_element_type=jnp.float32)
                 o = jnp.einsum("bhr,rhe->bhe", o_lat.astype(self.dtype),
                                w_kvb[..., dn:],
                                preferred_element_type=jnp.float32)[:, None]
-        o = (o * gate[..., None]).astype(self.dtype).reshape(b, l, h * dv)
+        if self.gate:
+            o = o * gate[..., None]
+        o = o.astype(self.dtype).reshape(b, l, h * dv)
         return dense(d, "o_proj")(o)
+
+
+# ---------------------------------------------------------------------------
+# hyper-connections
+# ---------------------------------------------------------------------------
+
+def sinkhorn(logits, iters: int, eps: float):
+    """``iters`` rounds of row-then-column normalisation of ``exp(logits)``
+    (``[..., n, n]``), ``eps`` in each divisor: towards the doubly
+    stochastic matrices, whose products keep a signal's mean."""
+    m = jnp.exp(logits)
+
+    def one(m, _):
+        m = m / (m.sum(-1, keepdims=True) + eps)
+        return m / (m.sum(-2, keepdims=True) + eps), None
+
+    return jax.lax.scan(one, m, None, length=iters)[0]
+
+
+class HyperConnection(nn.Module):
+    """The three maps of one sub-layer, from the residual state ``X [B, L,
+    n, d]`` itself, in float32: ``x̃ = RMSNorm(vec(X))`` (no learned
+    scale), ``H̃ = α·(x̃ Φ) + b`` for each of pre ``[n]``, post ``[n]`` and
+    res ``[n, n]`` (one ``nd × (2n + n²)`` matrix), ``H_pre = σ(H̃_pre)``,
+    ``H_post = 2σ(H̃_post)``, ``H_res = sinkhorn(clamp(H̃_res))``."""
+    n: int
+    iters: int = 20
+    eps: float = 1e-6
+    clamp: float = 30.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        n = self.n
+        b, l, _, d = x.shape
+        phi = self.param("phi", nn.initializers.lecun_normal(),
+                         (n * d, 2 * n + n * n), self.dtype)
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                           jnp.float32)
+        b_pre = self.param("b_pre", nn.initializers.zeros, (n,), jnp.float32)
+        b_post = self.param("b_post", nn.initializers.zeros, (n,),
+                            jnp.float32)
+        b_res = self.param("b_res", lambda *_: 4.0 * jnp.eye(n), (n, n),
+                           jnp.float32)
+        x32 = x.reshape(b, l, n * d).astype(jnp.float32)
+        xt = x32 * jax.lax.rsqrt(
+            jnp.mean(jnp.square(x32), -1, keepdims=True) + self.norm_eps)
+        t = jnp.matmul(xt, phi.astype(jnp.float32), precision=_HI)
+        h_pre = jax.nn.sigmoid(alpha[0] * t[..., :n] + b_pre)
+        h_post = 2.0 * jax.nn.sigmoid(alpha[1] * t[..., n:2 * n] + b_post)
+        h_res = alpha[2] * t[..., 2 * n:].reshape(b, l, n, n) + b_res
+        h_res = sinkhorn(jnp.clip(h_res, -self.clamp, self.clamp),
+                         self.iters, self.eps)
+        return h_pre, h_post, h_res
 
 
 # ---------------------------------------------------------------------------
@@ -377,24 +708,29 @@ class HybridBlock(nn.Module):
     cfg: Any                     # HybridLM.dims(): the sizes, as a tuple
     decode: bool = False
 
-    @nn.compact
-    def __call__(self, x, pos, lengths, live):
+    def _mix(self, y, pos, lengths, live, slots):
         c = self.cfg
-        y = RMSNorm(c.norm_eps, c.dtype, name="norm_mix")(x)
+        y = RMSNorm(c.norm_eps, c.dtype, name="norm_mix")(y)
         if self.mixer == "kda":
-            y = KDAMixer(c.n_heads, c.d_head, c.d_head, conv=c.conv_kernel,
-                         lower_bound=c.kda_lower_bound, eps=c.norm_eps,
-                         dtype=c.dtype, decode=self.decode,
-                         name="kda")(y, lengths, live)
-        else:
-            y = MLAMixer(c.n_heads, c.d_nope, c.d_rope, c.d_head, c.kv_rank,
-                         c.rope_theta, c.max_len, eps=c.norm_eps,
-                         dtype=c.dtype, decode=self.decode,
-                         name="mla")(y, pos)
-        x = x + y
-        y = RMSNorm(c.norm_eps, c.dtype, name="norm_ffn")(x)
+            if slots is not None:
+                raise ValueError("a recurrent state is not addressed by "
+                                 "slot: it has no chunk call")
+            return KDAMixer(c.n_heads, c.d_head, c.d_head, conv=c.conv_kernel,
+                            lower_bound=c.kda_lower_bound, eps=c.norm_eps,
+                            dtype=c.dtype, decode=self.decode,
+                            name="kda")(y, lengths, live)
+        return MLAMixer(c.n_heads, c.d_nope, c.d_rope, c.d_head, c.kv_rank,
+                        c.rope_theta, c.max_len, eps=c.norm_eps,
+                        dtype=c.dtype, decode=self.decode, q_rank=c.q_rank,
+                        gate=c.mla_gate, rope_scaling=c.rope_scaling,
+                        block=c.mla_block, name="mla")(y, pos, lengths, live,
+                                                       slots)
+
+    def _feed(self, y, lengths, live):
+        c = self.cfg
+        y = RMSNorm(c.norm_eps, c.dtype, name="norm_ffn")(y)
         if self.ffn == "dense":
-            return x + SwiGLU(c.d_ff, dtype=c.dtype, name="ffn")(y), None
+            return SwiGLU(c.d_ff, dtype=c.dtype, name="ffn")(y), None
         b, l, d = y.shape
         routes = (live[:, None]
                   & (jnp.arange(l)[None] < lengths[:, None])).reshape(-1)
@@ -404,7 +740,34 @@ class HybridBlock(nn.Module):
             c.n_group, c.topk_group, c.routed_scale, c.norm_topk_prob,
             dtype=c.dtype, name="moe")(flat, routes)
         shared = SwiGLU(c.d_shared, dtype=c.dtype, name="shared")(flat)
-        return x + (routed + shared).reshape(b, l, d), stats
+        return (routed + shared).reshape(b, l, d), stats
+
+    def _around(self, x, name, f):
+        """One sub-layer ``f`` on the residual state: the plain sum, or the
+        hyper-connection's three maps around it."""
+        c = self.cfg
+        if c.hc_mult == 1:
+            y, stats = f(x)
+            return x + y, stats
+        with jax.named_scope("mhc_mix"):
+            h_pre, h_post, h_res = HyperConnection(
+                c.hc_mult, c.hc_sinkhorn_iters, c.hc_eps, c.hc_clamp,
+                c.norm_eps, c.dtype, name=name)(x)
+            x32 = x.astype(jnp.float32)
+            u = jnp.einsum("bln,blnd->bld", h_pre, x32).astype(c.dtype)
+        y, stats = f(u)
+        with jax.named_scope("mhc_mix"):
+            x = (jnp.einsum("blij,bljd->blid", h_res, x32)
+                 + h_post[..., None] * y.astype(jnp.float32)[:, :, None])
+        return x.astype(c.dtype), stats
+
+    @nn.compact
+    def __call__(self, x, pos, lengths, live, slots=None):
+        x, _ = self._around(
+            x, "hc_mix",
+            lambda y: (self._mix(y, pos, lengths, live, slots), None))
+        return self._around(x, "hc_ffn",
+                            lambda y: self._feed(y, lengths, live))
 
 
 class HybridLM(nn.Module):
@@ -435,12 +798,24 @@ class HybridLM(nn.Module):
     routed_scale: float = 1.0
     norm_topk_prob: bool = True
     norm_eps: float = 1e-6
+    hc_mult: int = 1                 # residual streams; 1: the plain sum
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: float = 30.0
+    q_rank: Optional[int] = None     # MLA's low-rank query; None: full rank
+    mla_gate: bool = True
+    rope_scaling: Optional[Mapping[str, Any]] = None     # YaRN's keys
+    mla_block: int = 0               # page columns a block; 0: one piece
     dtype: Any = jnp.float32
     decode: bool = False
 
     #: serving/kv_cache.py: the pages are what the ``cache`` collection
     #: declares (recurrent state, convolution tail, latent page), not K/V
     declares_cache = True
+    #: serving/state_cache.py: the declared leaves a cursor addresses like
+    #: K/V rows (axis 1 is the position); any other but ``idx`` is a
+    #: recurrence
+    positional_leaves = ("ckv",)
 
     @property
     def n_layers(self) -> int:
@@ -455,12 +830,21 @@ class HybridLM(nn.Module):
             *(getattr(self, n) for n in names))
 
     @nn.compact
-    def __call__(self, tokens, pos_offset=None, lengths=None, live=None):
+    def __call__(self, tokens, pos_offset=None, lengths=None, live=None,
+                 slots=None):
+        """``slots [B]`` (with ``pos_offset``, models of blocked latent
+        pages only): row ``b`` of the call is row ``slots[b]`` of a cache
+        that holds more rows than the call has — a chunk cohort run against
+        the whole grid's pages where they lie, no copy of a page in or out.
+        A slot past the cache's rows is a padding row: it writes nothing."""
         b, l = tokens.shape
         if lengths is None:
             lengths = jnp.full((b,), l, jnp.int32)
         if live is None:
             live = jnp.ones((b,), bool)
+        if slots is not None and (pos_offset is None or not self.decode):
+            raise ValueError("slots need decode=True and the rows' cursors "
+                             "as pos_offset")
         if self.decode:
             idx = self.variable("cache", "idx", jnp.zeros, (b,), jnp.int32)
             pos = idx.value if pos_offset is None else jnp.broadcast_to(
@@ -469,21 +853,29 @@ class HybridLM(nn.Module):
             pos = jnp.zeros((b,), jnp.int32)
         x = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
                      param_dtype=self.dtype, name="tok_emb")(tokens)
+        if self.hc_mult > 1:        # every stream starts as the embedding
+            x = jnp.broadcast_to(x[:, :, None],
+                                 (b, l, self.hc_mult, self.d_model))
         stats = RouteStats.zero()
         dims = self.dims()
         for i, (mixer, ffn) in enumerate(self.pattern):
             x, st = HybridBlock(mixer, ffn, dims, decode=self.decode,
-                                name=f"block_{i}")(x, pos, lengths, live)
+                                name=f"block_{i}")(x, pos, lengths, live,
+                                                   slots)
             if st is not None:
                 stats = stats + st
         if self.decode:
-            idx.value = pos + jnp.where(live, lengths, 0).astype(jnp.int32)
+            moved = pos + jnp.where(live, lengths, 0).astype(jnp.int32)
+            idx.value = moved if slots is None else idx.value.at[slots].set(
+                moved, mode="drop")
             if self.n_experts:
                 # the serving step returns the collection with the tokens,
                 # and the engine's decode span carries it under these names
                 for name, v in stats._asdict().items():
                     self.sow("stats", name, v, reduce_fn=lambda a, b: a + b,
                              init_fn=lambda v=v: jnp.zeros((), v.dtype))
+        if self.hc_mult > 1:        # and the streams are summed at the end
+            x = x.astype(jnp.float32).sum(2).astype(self.dtype)
         x = RMSNorm(self.norm_eps, self.dtype, name="norm_f")(x)
         return nn.Dense(self.vocab, use_bias=False, dtype=self.dtype,
                         param_dtype=self.dtype, name="lm_head")(x).astype(

@@ -158,7 +158,7 @@ class Engine:
         if config.prefill_chunk is not None and config.prefill_chunk < 1:
             raise ValueError("prefill_chunk must be >= 1")
         if config.prefill_chunk is not None:
-            refuse_recurrent(model, "chunked prefill")
+            refuse_recurrent(model, "chunked prefill", positional_too=False)
         self.steps = serving_step(
             model, params, config.n_slots, config.capacity,
             cache_dtype=config.cache_dtype, mesh=mesh, axis=axis,
@@ -662,9 +662,18 @@ class Engine:
                     spent += len(cohort) * c
                     tok, valid, final = self._dispatch_chunk(cohort)
                     if sp:
-                        filled = int(valid[:len(cohort)].sum())
+                        v = valid[:len(cohort)].astype(np.int64)
+                        at = np.array([r.prefill_pos for _, r in cohort],
+                                      np.int64)
+                        filled = int(v.sum())
+                        # start_tokens: columns already in the cohort's
+                        # pages; attended_pairs: the query-column pairs a
+                        # causal attention over page + chunk computes
                         sp.set(admitted=fresh, rows=s, prompt_tokens=filled,
-                               padded_tokens=s * c - filled)
+                               padded_tokens=s * c - filled,
+                               start_tokens=int(at.sum()),
+                               attended_pairs=int(
+                                   (v * at + v * (v + 1) // 2).sum()))
             if not cohort:
                 break
             self._finish_chunk(cohort, tok, valid, final)
@@ -724,6 +733,10 @@ class Engine:
         n = cfg.n_slots
         with tracing.span("engine.decode.enqueue",
                           live=len(self.active)) as enq:
+            if enq:     # page columns the live slots hold at this dispatch
+                enq.set(filled_columns=sum(
+                    r.prompt.size + len(r.tokens) - 1
+                    for r in self.active.values()))
             live = np.zeros(n, bool)
             remaining = np.ones(n, np.int32)
             for slot, req in self.active.items():

@@ -561,6 +561,14 @@ class ServingStep:
         start, count = self._scatter_window(slot_ids, 0, tokens.shape[1])
         return last, repack_cache(cache, f32c, start, count)
 
+    def _prefill_chunk_program(self, params, cache, tokens, starts, valid,
+                               slot_ids):
+        f32c = unpack_cache(cache)
+        last, f32c = prefill_chunk_apply(
+            self.dm_chunk, params, f32c, tokens, starts, valid, slot_ids)
+        w_start, w_count = self._scatter_window(slot_ids, starts, valid)
+        return last, repack_cache(cache, f32c, w_start, w_count)
+
     #: outputs of ``_decode_k_program`` after the cache (replicated)
     _decode_k_extra = 0
 
@@ -775,13 +783,8 @@ class ServingStep:
                     final, keys, temps, top_ks, _key=key):
                 self.prefill_chunk_traces[_key] = (
                     self.prefill_chunk_traces.get(_key, 0) + 1)
-                f32c = unpack_cache(cache)
-                last, f32c = prefill_chunk_apply(
-                    self.dm_chunk, params, f32c, tokens, starts, valid,
-                    slot_ids)
-                w_start, w_count = self._scatter_window(
-                    slot_ids, starts, valid)
-                cache = repack_cache(cache, f32c, w_start, w_count)
+                last, cache = self._prefill_chunk_program(
+                    params, cache, tokens, starts, valid, slot_ids)
                 sid = jnp.asarray(slot_ids, jnp.int32)
                 gid = jnp.clip(sid, 0, self.n_slots - 1)
                 tok, newk = sample_tokens(last, keys[gid], temps[gid],

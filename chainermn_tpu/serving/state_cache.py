@@ -12,17 +12,29 @@ max_len=capacity)`` declares at batch ``n_slots``: every leaf is slot-major
 install, evict, reset, export and import work leaf by leaf on axis 0 with no
 knowledge of what a leaf means.
 
-What the cursor cannot do here: a recurrent state is the sum of its history
-and cannot be rewound, re-windowed or wrapped by moving a cursor. So
+What the cursor can and cannot do here goes by the KIND of each declared
+leaf, which the model names (``positional_leaves``):
 
-* a row that is not live must not be stepped at all — the model takes the
-  ``live`` mask and leaves such a row's state exactly as it was (``β = 0``,
-  ``α = 1``), where the K/V path lets it write garbage past its cursor;
-* a right-padded prefill row must stop at its true length — the model takes
-  ``lengths`` and installs the state after the row's last real token;
-* speculative decoding (rewind on reject), ``int8-block`` pages (per-column
-  requantisation) and ring wrap (overwrite the oldest column) are refused
-  for these models with a ``ValueError`` that says "recurrent state".
+* a POSITIONAL leaf (a latent page: axis 1 is the position) is addressed by
+  the cursor like K/V rows. A prompt may arrive in chunks
+  (:func:`state_prefill_chunk_apply`, ``EngineConfig.prefill_chunk``): the
+  model runs ``[S, C]`` at the rows' cursors against the cohort's rows of
+  the pages, writes ``[start, start + valid)`` and attends what is filled;
+* a RECURRENT leaf (every other one but ``idx``) is the sum of its history
+  and cannot be rewound, re-windowed or wrapped by moving a cursor. A model
+  with one is refused chunked prefill with a ``ValueError`` that names the
+  leaf and says "recurrent state".
+
+For every model here: a row that is not live must not be stepped at all — the
+model takes the ``live`` mask and leaves such a row's state exactly as it was
+(``β = 0``, ``α = 1``), where the K/V path lets it write garbage past its
+cursor; a right-padded prefill row must stop at its true length — the model
+takes ``lengths`` and installs the state after the row's last real token.
+Speculative decoding (rewind on reject), ``int8-block`` pages (per-column
+requantisation) and ring wrap (overwrite the oldest column) are refused for
+all of them: a recurrent leaf forbids them by nature ("recurrent state"), and
+for a model of positional leaves alone these programs are not written (the
+message says so).
 """
 
 from __future__ import annotations
@@ -36,21 +48,49 @@ from chainermn_tpu.serving.sampling import sample_tokens
 
 __all__ = ["StateServingStep", "serving_step", "declares_cache",
            "init_state_cache", "state_decode_apply", "state_prefill_apply",
-           "state_decode_k_apply", "refuse_recurrent"]
+           "state_decode_k_apply", "state_prefill_chunk_apply",
+           "recurrent_leaves", "refuse_recurrent"]
 
 
 def declares_cache(model) -> bool:
     return bool(getattr(model, "declares_cache", False))
 
 
-def refuse_recurrent(model, what: str) -> None:
-    """Raise for a feature that moves K/V rows by cursor."""
-    if declares_cache(model):
+def recurrent_leaves(model):
+    """Paths (``block_0/kda/state``) of the declared leaves that are a
+    recurrence: every leaf of the ``cache`` collection but the cursor and
+    the ones the model names in ``positional_leaves``."""
+    shapes = jax.eval_shape(lambda: init_state_cache(model, 1, 8))
+    positional = tuple(getattr(model, "positional_leaves", ()))
+    flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
+    paths = ["/".join(str(getattr(k, "key", k)) for k in path)
+             for path, _ in flat]
+    return [p for p in paths
+            if p != "idx" and p.rsplit("/", 1)[-1] not in positional]
+
+
+def refuse_recurrent(model, what: str, *, positional_too: bool = True
+                     ) -> None:
+    """Raise for a feature that moves K/V rows by cursor. A model with a
+    recurrent leaf is always refused, by that leaf's name; one whose
+    declared leaves are all positional only where the feature has no
+    program for declared pages (``positional_too``)."""
+    if not declares_cache(model):
+        return
+    name = type(model).__name__
+    leaves = recurrent_leaves(model)
+    if leaves:
+        more = f" (and {len(leaves) - 1} more)" if len(leaves) > 1 else ""
         raise ValueError(
-            f"{what} is not available for {type(model).__name__}: its "
-            "layers carry a recurrent state, which is the sum of its "
-            "history and cannot be rewound, requantised by column or "
-            "wrapped by moving a cursor")
+            f"{what} is not available for {name}: its leaf "
+            f"{leaves[0]!r}{more} is a recurrent state, which is the sum "
+            "of its history and cannot be rewound, requantised by column "
+            "or wrapped by moving a cursor")
+    if positional_too:
+        raise ValueError(
+            f"{what} is not available for {name}: its declared pages are "
+            "positional and could take it, but serving/state_cache.py has "
+            "no such program for declared pages yet")
 
 
 def init_state_cache(model, n_slots: int, capacity: int):
@@ -65,13 +105,13 @@ def init_state_cache(model, n_slots: int, capacity: int):
         lambda a: jnp.zeros(a.shape, a.dtype), shapes)
 
 
-def _apply(dm, params, cache, tokens, lengths, live):
+def _apply(dm, params, cache, tokens, lengths, live, **at):
     """One call of the model on its declared cache: (logits, cache, what the
     model counted — the ``stats`` collection's leaves, {} where it counts
     nothing)."""
     logits, upd = dm.apply(
         {"params": params, "cache": cache}, tokens, lengths=lengths,
-        live=live, mutable=["cache", "stats"])
+        live=live, mutable=["cache", "stats"], **at)
     return logits, upd["cache"], dict(upd.get("stats", {}))
 
 
@@ -104,6 +144,29 @@ def state_prefill_apply(dm, params, cache, tokens, lengths, slot_ids):
         logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
     cache = jax.tree_util.tree_map(
         lambda page, rows: page.at[sid].set(rows, mode="drop"), cache, slab)
+    return last, cache
+
+
+def state_prefill_chunk_apply(dm, params, cache, tokens, starts, valid,
+                              slot_ids):
+    """``kv_cache.prefill_chunk_apply`` for declared POSITIONAL leaves: the
+    model runs the ``[S, C]`` cohort at ``starts`` against the whole grid's
+    pages where they lie, row ``s`` of the cohort in row ``slot_ids[s]`` of
+    every page (no copy of a page in or out: at 12 x 33,024 columns a row of
+    the 7 pages is 0.3 GB), and stops each row at ``valid`` — it writes the
+    row's latents at ``[start, start + valid)`` and attends the filled page
+    plus the chunk; a sentinel ``n_slots`` row writes nothing. Cursors go to
+    ``start + valid``. Returns (last-real-position logits ``[S, vocab]``,
+    cache)."""
+    n_slots = cache["idx"].shape[0]
+    sid = jnp.asarray(slot_ids, jnp.int32)
+    valid = jnp.asarray(valid, jnp.int32)
+    logits, cache, _ = _apply(
+        dm, params, cache, tokens, valid, sid < n_slots,
+        pos_offset=jnp.asarray(starts, jnp.int32), slots=sid)
+    last = jnp.take_along_axis(
+        logits, jnp.clip(valid - 1, 0, tokens.shape[1] - 1)[:, None, None],
+        axis=1)[:, 0]
     return last, cache
 
 
@@ -150,7 +213,6 @@ class StateServingStep(ServingStep):
     pages, the three programs, the shardings and the slot export are the
     ones above, leaf-wise on axis 0."""
 
-    no_wrap = "a recurrent state forbids ring wrap"
     _decode_k_extra = 1     # the model's counts, summed over the steps
 
     def __init__(self, model, params, n_slots, capacity, *, kv_dtype=None,
@@ -159,12 +221,20 @@ class StateServingStep(ServingStep):
             refuse_recurrent(model, f"kv_dtype={kv_dtype!r}")
         super().__init__(model, params, n_slots, capacity, **kw)
 
+    @property
+    def no_wrap(self):
+        return ("a recurrent state forbids ring wrap" if self._recurrent else
+                "a declared page has no ring wrap (the model's decode write "
+                "drops past the capacity)")
+
     def _init_pages(self, model, params, cache_dtype):
         # each leaf has the dtype the model declares: no ``cache_dtype``
         self.model = model
         self.dm = model.clone(decode=True, max_len=self.capacity)
         self.dm_chunk = None
         self.cache = init_state_cache(model, self.n_slots, self.capacity)
+        # read off the declared tree once: a trace of the model's init
+        self._recurrent = recurrent_leaves(model)
         return params
 
     def _decode_program(self, params, cache, tokens):
@@ -189,8 +259,15 @@ class StateServingStep(ServingStep):
     def cursors(self):
         return jax.device_get(self.cache["idx"])
 
+    def _prefill_chunk_program(self, params, cache, tokens, starts, valid,
+                               slot_ids):
+        return state_prefill_chunk_apply(self.dm, params, cache, tokens,
+                                         starts, valid, slot_ids)
+
     def prefill_chunk(self, *args, **kw):
-        refuse_recurrent(self.model, "chunked prefill")
+        if self._recurrent:
+            refuse_recurrent(self.model, "chunked prefill")
+        return super().prefill_chunk(*args, **kw)
 
     def _export_rows(self, slot, fill):
         # whatever a leaf means: a recurrent state has no rows to cut at

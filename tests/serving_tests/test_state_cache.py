@@ -250,3 +250,120 @@ def test_route_counts_ride_on_the_decode_span(tmp_path):
     assert admits and all(
         r.attrs["state_bytes"] == r.attrs["admitted"] * eng.steps.slot_bytes
         for r in admits)
+
+
+# -- positional leaves: what a cursor may do to a latent page -----------------
+
+LATENT = dict(vocab=256, d_model=64, n_heads=4, d_head=16, d_ff=128,
+              max_len=160, d_nope=16, d_rope=8, kv_rank=32, n_experts=16,
+              held_lo=0, held_hi=16, d_expert=32, d_shared=32, top_k=4,
+              n_group=1, topk_group=1, routed_scale=2.0, hc_mult=2,
+              q_rank=24, mla_gate=False, mla_block=16)
+
+
+@functools.lru_cache(maxsize=None)
+def latent_setup():
+    model = HybridLM(pattern=(("mla", "dense"), ("mla", "moe")), **LATENT)
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return model, params
+
+
+def latent_engine(**over):
+    model, params = latent_setup()
+    cfg = dict(n_slots=3, capacity=144, buckets=(32, 64, 144), decode_k=4,
+               prefill_cohort=2)
+    return Engine(model, params, EngineConfig(**dict(cfg, **over)))
+
+
+def serve_latent(eng):
+    rs = np.random.RandomState(0)
+    reqs = [eng.submit(rs.randint(0, 256, (n,)), max_new_tokens=m,
+                       **({} if i % 2 == 0 else
+                          dict(temperature=0.8, top_k=20, seed=i)))
+            for i, (n, m) in enumerate([(37, 9), (90, 12), (21, 7), (64, 11),
+                                        (130, 6)])]
+    eng.run_until_drained()
+    assert all(r.state == "done" for r in reqs)
+    return [r.tokens for r in reqs]
+
+
+@pytest.mark.parametrize("chunk,cohort", [(16, 1), (16, 2), (24, 2)])
+def test_chunked_prefill_on_positional_leaves_serves_the_same_tokens(
+        chunk, cohort):
+    """A model whose declared leaves are all latent pages takes
+    ``prefill_chunk``: one chunk program whatever the prompt lengths, one
+    decode program, and the streams of the one-piece engine (greedy and
+    sampled: a chunk that is not final consumes no key)."""
+    want = serve_latent(latent_engine())
+    eng = latent_engine(prefill_chunk=chunk, prefill_cohort=cohort)
+    assert serve_latent(eng) == want
+    assert eng.steps.prefill_chunk_traces == {(cohort, chunk): 1}
+    assert eng.steps.decode_k_traces == 1 and not eng.steps.prefill_traces
+    assert eng.idle() and sorted(eng.free_slots) == [0, 1, 2]
+
+
+def test_chunks_stream_in_beside_decoding_slots_and_a_parked_page_is_kept():
+    """A long prompt arrives in chunks while another slot decodes: the
+    decoding slot's dispatches ride over the prefilling slot (parked at its
+    cursor) without touching what its chunks have written."""
+    model, params = latent_setup()
+    rs = np.random.RandomState(1)
+    short, long_ = rs.randint(0, 256, (20,)), rs.randint(0, 256, (120,))
+    eng = latent_engine(prefill_chunk=16, prefill_cohort=1)
+    a = eng.submit(short, max_new_tokens=40)
+    b = eng.submit(long_, max_new_tokens=5)
+    saw_both = 0
+    while not eng.idle():
+        eng.step()  # dlint: disable=DL104 — syncs via np.asarray
+        saw_both += bool(eng.active) and bool(eng.prefilling)
+    assert saw_both >= 5        # decode dispatches rode along the prefill
+    alone = latent_engine()
+    b2 = alone.submit(long_, max_new_tokens=5)
+    alone.run_until_drained()
+    assert b.tokens == b2.tokens
+    a2 = alone.submit(short, max_new_tokens=40)
+    alone.run_until_drained()
+    assert a.tokens == a2.tokens
+
+
+@pytest.mark.parametrize("what", ["speculative", "int8-block", "ring wrap"])
+def test_a_positional_page_still_refuses_what_has_no_program(what):
+    """Rewind, per-column requantisation and wrap could be done to a latent
+    page by cursor; the programs are not written, and the refusal says so
+    (it does not blame a recurrent state the model has not got)."""
+    model, params = latent_setup()
+    cfg = EngineConfig(n_slots=2, capacity=64, buckets=(32, 64))
+    with pytest.raises(ValueError) as err:
+        if what == "speculative":
+            SpeculativeEngine(model, params, model, params, cfg, spec_k=2)
+        elif what == "int8-block":
+            serving_step(model, params, 2, 64, kv_dtype="int8-block")
+        else:
+            Engine(model, params, cfg).submit(np.arange(40),
+                                              max_new_tokens=30)
+    assert "recurrent state" not in str(err.value)
+    assert ("no such program" in str(err.value)
+            or "no ring wrap" in str(err.value))
+
+
+def test_the_refusal_names_the_leaf_that_is_a_recurrence():
+    from chainermn_tpu.serving.state_cache import (recurrent_leaves,
+                                                   refuse_recurrent)
+
+    model, params = setup()             # kda/dense, kda/moe, mla/moe, kda/moe
+    leaves = recurrent_leaves(model)
+    assert "block_0/kda/state" in leaves and "block_0/kda/conv" in leaves
+    assert not any("ckv" in p or p == "idx" for p in leaves)
+    assert recurrent_leaves(latent_setup()[0]) == []
+    with pytest.raises(ValueError, match="recurrent state") as err:
+        refuse_recurrent(model, "chunked prefill")
+    assert "block_0/kda/conv" in str(err.value) or (
+        "block_0/kda/state" in str(err.value))
+    assert "HybridLM" in str(err.value)
+    # the step of a model with a recurrent leaf refuses a chunk by itself too
+    with pytest.raises(ValueError, match="recurrent state"):
+        engine().steps.prefill_chunk(None, None, None, None, None, None,
+                                     None, None)
+    refuse_recurrent(latent_setup()[0], "chunked prefill",
+                     positional_too=False)      # no leaf stands in the way
