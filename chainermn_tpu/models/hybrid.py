@@ -67,6 +67,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.ops import latent_attention
 from chainermn_tpu.parallel.expert_share import HeldExperts, RouteStats
 
 __all__ = ["HybridLM", "HybridBlock", "HyperConnection", "KDAMixer",
@@ -387,25 +388,43 @@ def _blocks(page, top, block, slots):
 
 
 def latent_chunk_attention(q_nope, q_rope, page, w_kvb, pos, scale, block,
-                           slots=None):
+                           slots=None, valid=None):
     """Causal attention of a chunk of queries over a latent page, EXPANDED
     block by block. ``q_nope [B, C, H, dn]``, ``q_rope [B, C, H, dr]``: the
-    queries at positions ``pos[b] + 0..C-1``; row ``slots[b]`` (``b``
+    queries at positions ``pos[b] + 0..C-1``, the first ``valid[b]`` of
+    them real (all of them without ``valid``); row ``slots[b]`` (``b``
     itself without ``slots``) of ``page [N, T, >= r + dr]`` holds ``[c |
     k_r]`` (and maybe padding) for every column up to those positions (the
-    chunk's own included); ``w_kvb [r, H, dn + dv]``. Each block of
-    ``block`` columns is re-expanded to per-head keys and values (640 flop
-    a query-key pair and head against the absorbed form's 2,176) and folded
-    into a running softmax, so the largest score array is ``[B, H, C,
-    block]`` whatever the page's length, and the loop stops at the last
-    column a query sees. Returns ``([B, C, H, dv]`` float32, the page``)``:
-    the page rides the loop's carry and the caller keeps what comes out, so
-    that a page just written is read where it lies (one that the loop only
-    closed over stayed live beside it, and the compiler copied all of it a
-    call)."""
+    chunk's own included); ``w_kvb [r, H, dn + dv]``. Each block of columns
+    is re-expanded to per-head keys and values (640 flop a query-key pair
+    and head against the absorbed form's 2,176) and folded into a running
+    softmax. Returns ``([B, C, H, dv]`` float32, the page``)``.
+
+    Two forms, chosen here at trace time by what the call shows
+    (``ops/latent_attention.py::chunk_kernel_refusal``; the choice is noted
+    for whoever traces the program, ``record_paths``): on a TPU, with
+    lane-aligned widths and a page that one device holds, ONE Pallas kernel
+    a call, which keeps a block's keys, values, scores and probabilities in
+    VMEM, stops a row at ``pos + valid``, skips what lies above a query
+    tile's diagonal and leaves the query tiles past ``valid`` zero; else
+    the ``jax.numpy`` loop below over blocks of ``block`` columns, whose
+    largest score array is ``[B, H, C, block]`` whatever the page's length
+    and which stops at the last column a query sees. In the loop the page
+    rides the carry and the caller keeps what comes out, so that a page
+    just written is read where it lies (one that the loop only closed over
+    stayed live beside it, and the compiler copied all of it a call)."""
     b, c, h, dn = q_nope.shape
     r = w_kvb.shape[0]
     dv = w_kvb.shape[-1] - dn
+    refusal = latent_attention.chunk_kernel_refusal(q_nope, q_rope, page,
+                                                    w_kvb)
+    latent_attention.note_path(
+        "kernel" if refusal is None else f"loop:{refusal}")
+    if refusal is None:
+        return latent_attention.latent_chunk_fwd(
+            q_nope, q_rope, page, w_kvb, pos,
+            jnp.full((b,), c, jnp.int32) if valid is None else valid,
+            jnp.arange(b) if slots is None else slots, scale), page
     qpos = pos[:, None] + jnp.arange(c)[None]                   # [B, C]
     block, n_blocks, take = _blocks(
         page, jnp.max(pos) + c, block,
@@ -579,7 +598,7 @@ class MLAMixer(nn.Module):
                         jnp.arange(b) if slots is None else slots)
                 o, page = latent_chunk_attention(
                     q_nope, q_rope, page, w_kvb, pos, scale, self.block,
-                    slots)
+                    slots, n if self.decode else None)
                 if self.decode:
                     page_v.value = page
         elif l > 1:

@@ -42,7 +42,8 @@ from jax.experimental.pallas import tpu as pltpu
 from chainermn_tpu.utils import on_tpu
 
 __all__ = ["page_write_rows", "vmap_write_rows", "write_rows",
-           "partitioned_pages", "rows_are_whole_tiles"]
+           "partitioned_pages", "pages_are_partitioned",
+           "rows_are_whole_tiles"]
 
 _trace = threading.local()
 
@@ -57,6 +58,13 @@ def partitioned_pages(partitioned: bool = True):
         yield
     finally:
         _trace.partitioned = before
+
+
+def pages_are_partitioned() -> bool:
+    """Whether the program being traced runs under
+    :func:`partitioned_pages`: what a kernel over a page asks before it
+    takes the page as one device's."""
+    return getattr(_trace, "partitioned", False)
 
 
 def rows_are_whole_tiles(h_kv: int, d_head: int, dtype) -> bool:
@@ -151,7 +159,7 @@ def write_rows(k_page, v_page, k_new, v_new, start):
     """The per-slot write of ``TransformerBlock``'s decode branch: the
     kernel, or the ``vmap`` form where the module's docstring says the
     kernel cannot serve."""
-    if (k_new.shape[1] != 1 or getattr(_trace, "partitioned", False)
+    if (k_new.shape[1] != 1 or pages_are_partitioned()
             or not rows_are_whole_tiles(*k_page.shape[2:], k_page.dtype)):
         return vmap_write_rows(k_page, v_page, k_new, v_new, start)
     return page_write_rows(k_page, v_page, k_new, v_new, start)

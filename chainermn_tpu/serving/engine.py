@@ -674,6 +674,8 @@ class Engine:
                                start_tokens=int(at.sum()),
                                attended_pairs=int(
                                    (v * at + v * (v + 1) // 2).sum()))
+                        if self.steps.chunk_attention:
+                            sp.set(chunk_attention=self.steps.chunk_attention)
             if not cohort:
                 break
             self._finish_chunk(cohort, tok, valid, final)

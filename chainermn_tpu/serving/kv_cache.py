@@ -94,6 +94,7 @@ import numpy as np
 
 from chainermn_tpu.collectives.quantized import QUANT_BLOCK
 from chainermn_tpu.models.transformer import bhld_to_blhd_params
+from chainermn_tpu.ops.latent_attention import record_paths
 from chainermn_tpu.ops.page_write import partitioned_pages
 from chainermn_tpu.serving.sampling import sample_tokens
 
@@ -497,6 +498,11 @@ class ServingStep:
         self.decode_k_traces = 0
         self.prefill_traces: Dict[tuple, int] = {}
         self.prefill_chunk_traces: Dict[tuple, int] = {}
+        #: which form the chunk program's latent attention took when it was
+        #: traced ("kernel" or "loop:<reason>", models/hybrid.py::
+        #: latent_chunk_attention); None before a chunk program exists and
+        #: for a model that has no such call
+        self.chunk_attention: Optional[str] = None
         self._prefill_jits: Dict[tuple, Any] = {}
         self._prefill_sampled_jits: Dict[tuple, Any] = {}
         self._prefill_chunk_jits: Dict[tuple, Any] = {}
@@ -586,10 +592,12 @@ class ServingStep:
         return toks, last, keys, repack_cache(cache, f32c, start, k)
 
     def _page_write(self):
-        """Trace-time scope of the one-token programs: pages split over
-        several devices keep the decode write in its partitionable form
-        (a kernel is one custom call, which the partitioner cannot split;
-        ops/page_write.py). One device, mesh or not, takes the kernel."""
+        """Trace-time scope of the programs that may hold a kernel over a
+        page (the one-token programs' write, ops/page_write.py; the chunk
+        program's latent attention, ops/latent_attention.py): pages split
+        over several devices keep the partitionable form (a kernel is one
+        custom call, which the partitioner cannot split). One device, mesh
+        or not, takes the kernel."""
         return partitioned_pages(
             self._mesh is not None and self._mesh.size > 1)
 
@@ -783,8 +791,10 @@ class ServingStep:
                     final, keys, temps, top_ks, _key=key):
                 self.prefill_chunk_traces[_key] = (
                     self.prefill_chunk_traces.get(_key, 0) + 1)
-                last, cache = self._prefill_chunk_program(
-                    params, cache, tokens, starts, valid, slot_ids)
+                with self._page_write(), record_paths() as paths:
+                    last, cache = self._prefill_chunk_program(
+                        params, cache, tokens, starts, valid, slot_ids)
+                self.chunk_attention = ",".join(sorted(set(paths))) or None
                 sid = jnp.asarray(slot_ids, jnp.int32)
                 gid = jnp.clip(sid, 0, self.n_slots - 1)
                 tok, newk = sample_tokens(last, keys[gid], temps[gid],

@@ -3,9 +3,12 @@ the low-rank query, ``latent_chunk_attention``, ``latent_decode_attention``)
 at toy size on the CPU, against the benchmark's plain reference
 (benchmark/references/xing_mhc.py): YaRN's frequencies and softmax scale
 against hand-computed values, prefill in chunks against prefill in one piece
-and against the reference's full forward, a decode step through the page at
-cursors 0, mid-block and capacity - 1, and that the chunk program holds no
-score array of chunk x capacity a head."""
+and against the reference's full forward — through the ``jax.numpy`` loop and,
+at lane-aligned widths, through the kernel (ops/latent_attention.py, in the
+Pallas interpreter) —, a decode step through the page at cursors 0, mid-block
+and capacity - 1, and that the chunk program holds no score array of chunk x
+capacity a head in either form."""
+import contextlib
 import math
 import re
 
@@ -19,6 +22,7 @@ from benchmark.references import xing_mhc as ref
 from chainermn_tpu.models.hybrid import (HybridLM, MLAMixer,
                                          latent_decode_attention,
                                          yarn_inv_freq, yarn_mscale)
+from chainermn_tpu.ops import latent_attention
 from chainermn_tpu.serving.state_cache import (init_state_cache,
                                                state_decode_apply,
                                                state_prefill_apply,
@@ -105,6 +109,24 @@ def test_low_rank_query_and_blocked_prefill_match_the_reference():
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-5)
 
 
+#: widths the kernel serves (whole lane tiles); everything else as SIZES
+ALIGNED = dict(n_heads=2, d_head=128, d_nope=128, d_rope=64, kv_rank=128)
+
+
+@contextlib.contextmanager
+def kernel_path():
+    """Inside, ``latent_chunk_attention`` takes the kernel as it does on the
+    chip, and the kernel runs in the Pallas TPU interpreter. Yields the list
+    of paths the calls traced inside took."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pytest.MonkeyPatch.context() as mp, \
+            pltpu.force_tpu_interpret_mode(), \
+            latent_attention.record_paths() as paths:
+        mp.setattr(latent_attention, "on_tpu", lambda: True)
+        yield paths
+
+
 def prefill(model, params, tokens, chunks):
     """One slot of a 2-slot cache: the prompt in the given chunk sizes (a
     single size: the one-piece program). Returns (logits after the last
@@ -118,12 +140,13 @@ def prefill(model, params, tokens, chunks):
                                    jnp.asarray([tokens.size]),
                                    jnp.asarray([1]))
     at, c = 0, max(chunks)
+    chunk = jax.jit(lambda *a: state_prefill_chunk_apply(dm, *a))
     for n in chunks:
         toks = np.zeros((2, c), np.int32)
         toks[0, :n] = tokens[at:at + n]
         # row 1 is a sentinel row, as the engine pads a cohort
-        logits, cache = state_prefill_chunk_apply(
-            dm, params, cache, jnp.asarray(toks), jnp.asarray([at, 0]),
+        logits, cache = chunk(
+            params, cache, jnp.asarray(toks), jnp.asarray([at, 0]),
             jnp.asarray([n, 1]), jnp.asarray([1, 2]))
         at += n
     return logits[:1], cache
@@ -152,6 +175,46 @@ def test_chunked_prefill_equals_one_piece_and_the_reference(chunks):
     padded[0, :73] = tokens
     want = reference_logits(model, params, padded)[0, 72]
     np.testing.assert_allclose(got[0], want, rtol=5e-4, atol=5e-4)
+
+
+@pytest.mark.parametrize("chunks", [(32, 32, 9), (24, 24, 24, 1)])
+def test_chunked_prefill_through_the_kernel_equals_the_loop_and_one_piece(
+        chunks):
+    """The same 73 tokens at widths the kernel serves: every chunk call of
+    the three layers takes the kernel (a short last chunk, a sentinel row
+    beside the real one, the row in slot 1 of 2), and the pages, the cursor
+    and the logits are those of the loop over one piece of 80."""
+    model, params = setup(**ALIGNED)
+    tokens = np.random.RandomState(5).randint(0, 256, (73,))
+    with kernel_path() as paths:
+        got, cache = prefill(model, params, tokens, chunks)
+    assert paths == ["kernel"] * 3      # one trace serves every chunk
+    one, cache1 = prefill(model, params, tokens, (80,))
+    assert cache["idx"].tolist() == cache1["idx"].tolist() == [0, 73]
+    for i in range(3):
+        a = np.asarray(cache[f"block_{i}"]["mla"]["ckv"])
+        b = np.asarray(cache1[f"block_{i}"]["mla"]["ckv"])
+        assert a.shape[-1] == 256       # [c 128 | k_r 64 | 64 zeros]
+        assert not a[0].any() and not a[1, 73:].any()
+        np.testing.assert_allclose(a[1, :73], b[1, :73], rtol=1e-4,
+                                   atol=1e-5)
+    np.testing.assert_allclose(got, one, rtol=1e-4, atol=1e-4)
+    padded = np.zeros((1, 80), np.int64)
+    padded[0, :73] = tokens
+    want = reference_logits(model, params, padded)[0, 72]
+    np.testing.assert_allclose(got[0], want, rtol=5e-4, atol=5e-4)
+
+
+def test_the_same_chunks_through_the_loop_off_the_chip():
+    """The control of the test above: off the chip the same model's chunk
+    calls take the loop, name why, and give the same logits."""
+    model, params = setup(**ALIGNED)
+    tokens = np.random.RandomState(5).randint(0, 256, (73,))
+    with latent_attention.record_paths() as paths:
+        got, _ = prefill(model, params, tokens, (32, 32, 9))
+    assert paths == ["loop:not on a TPU"] * 3
+    one, _ = prefill(model, params, tokens, (80,))
+    np.testing.assert_allclose(got, one, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("cursor", [0, 21, 32, CAP - 1])
@@ -202,24 +265,36 @@ def test_decode_reads_the_blocks_the_live_rows_have_filled():
         np.testing.assert_allclose(got[row], want, rtol=1e-5, atol=1e-6)
 
 
-def test_chunk_program_holds_no_score_array_over_the_page():
-    """A mid size: 8 heads, a chunk of 256 queries, a page of 4,096 columns
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_chunk_program_holds_no_score_array_over_the_page(form):
+    """A mid size: 8 heads, a chunk of 512 queries, a page of 8,192 columns
     in blocks of 128. A float32 score array of chunk x capacity a head
-    would be 8 x 256 x 4,096 x 4 B = 32 MB; the compiled chunk program's
+    would be 8 x 512 x 8,192 x 4 B = 128 MB; the compiled chunk program's
     temporaries stay under a quarter of that, and no array in its HLO has
-    both a chunk and a capacity axis beside the heads."""
-    cap, c, h = 4096, 256, 8
-    model = HybridLM(**dict(SIZES, n_heads=h, max_len=cap, mla_block=128),
-                     pattern=PATTERN[:2])
+    both a chunk and a capacity axis beside the heads. The loop's largest
+    score array is a chunk by a block a head; the kernel's program (its
+    body interpreted here: tests/ops_tests/test_grouped_swiglu_compile.py
+    compiles it for the chip) holds no float32 array with the heads, the
+    chunk and a block of columns at all: a step scores one head."""
+    cap, c, h = 8192, 512, 8
+    over = dict(SIZES, n_heads=h, max_len=cap, mla_block=128)
+    if form == "kernel":
+        over.update(ALIGNED, n_heads=h)
+    model = HybridLM(**over, pattern=PATTERN[:2])
     shapes = jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
     dm = model.clone(decode=True, max_len=cap)
     cache = jax.eval_shape(lambda: init_state_cache(model, 2, cap))
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    compiled = jax.jit(
-        lambda p, ca, t, s, v, ids: state_prefill_chunk_apply(
-            dm, p, ca, t, s, v, ids), donate_argnums=(1,)).lower(
-        shapes, cache, i32(1, c), i32(1), i32(1), i32(1)).compile()
+    with kernel_path() if form == "kernel" else \
+            latent_attention.record_paths() as paths:
+        compiled = jax.jit(
+            lambda p, ca, t, s, v, ids: state_prefill_chunk_apply(
+                dm, p, ca, t, s, v, ids), donate_argnums=(1,)).lower(
+            shapes, cache, i32(1, c), i32(1), i32(1), i32(1)).compile()
+    assert len(paths) == 2 and all(
+        p == "kernel" if form == "kernel" else p.startswith("loop:")
+        for p in paths)
     whole = h * c * cap * 4
     assert compiled.memory_analysis().temp_size_in_bytes < whole // 4
     text = compiled.as_text()
@@ -227,4 +302,9 @@ def test_chunk_program_holds_no_score_array_over_the_page():
                    for dims in re.findall(r"f32\[([\d,]+)\]", text)}
     assert shapes_seen, "no float32 array in the program's text"
     assert not [s for s in shapes_seen if c in s and cap in s]
-    assert [s for s in shapes_seen if c in s and 128 in s]   # the blocks are
+    if form == "loop":
+        assert [s for s in shapes_seen if c in s and 128 in s]  # the blocks
+    else:
+        tile = min(latent_attention.COLUMN_TILE, cap)
+        assert not [s for s in shapes_seen
+                    if h in s and c in s and tile in s]

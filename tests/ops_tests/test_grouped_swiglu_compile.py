@@ -9,13 +9,23 @@ library, and a second file could go to a worker that cannot.
 - The per-slot cache write (ops/page_write.py) at StarCoder2-3B's page and
   over the row shapes ``rows_are_whole_tiles`` admits: it compiles, under
   its own name, with the pages aliased and no copy of a page; and what the
-  rule refuses is refused for cause."""
+  rule refuses is refused for cause.
+- The chunk's latent attention (ops/latent_attention.py) at
+  ``xing4-serve-longdoc``'s shapes, behind the page write as the mixer calls
+  it: one custom call under its own name, the donated page updated in place
+  and read where it lies (no copy of it), and no float32 score array among
+  the program's HBM temporaries."""
+import re
+
+import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
+from chainermn_tpu.models import hybrid
 from chainermn_tpu.ops import grouped_swiglu as gs
+from chainermn_tpu.ops import latent_attention as la
 from chainermn_tpu.ops import page_write as pw
 
 
@@ -100,3 +110,43 @@ def test_rows_the_rule_refuses_cannot_be_written_in_place(
         return
     page_bytes = n * cap * h * d * jnp.dtype(dtype).itemsize
     assert compiled.memory_analysis().temp_size_in_bytes >= page_bytes
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+def test_latent_chunk_attention_compiles_in_place_for_v5e(one_chip,
+                                                          monkeypatch, dtype):
+    """A chunk of 2,048 queries, 32 heads, rank 512, a page of 12 x 33,024
+    columns of 640 values (the last block of 1,024 columns is partial): the
+    chunk's latents written into the donated page, then the chunk attends
+    it, as ``MLAMixer`` does under ``mla_chunk``."""
+    monkeypatch.setattr(la, "on_tpu", lambda: True)     # Mosaic, not interpret
+    b, c, h, n, t, w = 1, 2048, 32, 12, 33024, 640
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(q_nope, q_rope, page, ckv, w_kvb, pos, valid, slots):
+        page = hybrid._write_window(page, ckv, pos, valid, slots)
+        return hybrid.latent_chunk_attention(
+            q_nope, q_rope, page, w_kvb, pos, 0.1447, 512, slots, valid)
+
+    with la.record_paths() as paths:
+        compiled = jax.jit(chunk, donate_argnums=(2,)).lower(
+            sds((b, c, h, 128), dtype), sds((b, c, h, 64), dtype),
+            sds((n, t, w), dtype), sds((b, c, w), dtype),
+            sds((512, h, 256), dtype), *[sds((b,), jnp.int32)] * 3).compile()
+    assert paths == ["kernel"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_chunk_fwd" in text
+    mem = compiled.memory_analysis()
+    page_bytes = n * t * w * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= page_bytes        # the page in place
+    assert mem.temp_size_in_bytes < page_bytes // 16    # and no copy of it
+    # what a score array would be: float32 with the chunk and a block of
+    # columns (the loop's 512, the kernel's tile) as axes
+    f32 = {tuple(int(d) for d in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    assert not [s for s in f32 if c in s and (
+        512 in s or la.COLUMN_TILE in s or t in s)]
+    # beside the page, no float32 array is larger than the result
+    assert max(int(np.prod(s)) for s in f32
+               if s != (n, t, w)) == b * c * h * 128

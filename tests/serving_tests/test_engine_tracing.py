@@ -120,6 +120,15 @@ def test_admission_is_counted_where_it_happens(traced):
             (8, 2, 8), (8, 1, 12), (32, 1, 52), (8, 2, 6), (8, 1, 13)]
 
 
+def test_a_program_without_latent_attention_names_none(traced):
+    """``chunk_attention`` is the chunk program's latent attention as it
+    was traced (tests/serving_tests/test_state_cache.py): a dense model has
+    no such call, chunked or not, so its spans carry no such attribute."""
+    admits = [r for r in traced["rows"] if r.name == "engine.admit"]
+    assert admits and not any("chunk_attention" in a.attrs for a in admits)
+    assert traced["engine"].steps.chunk_attention is None
+
+
 def test_the_step_span_sees_the_queue_it_started_with(traced):
     its = _iterations(traced["rows"])
     first, last = its[0][0], its[-1][0]

@@ -327,6 +327,30 @@ def test_chunks_stream_in_beside_decoding_slots_and_a_parked_page_is_kept():
     assert a.tokens == a2.tokens
 
 
+@pytest.mark.parametrize("cohort", [1, 2])
+def test_the_chunk_span_names_the_attention_its_program_traced(
+        cohort, profiler_session):
+    """Every ``engine.admit`` span of a chunk dispatch says which form the
+    chunk program's latent attention took when it was traced — here, off
+    the chip and at widths that are no lane tiles, the ``jax.numpy`` loop
+    and why — beside the pairs that attention computed; the step holds the
+    same string from the trace on."""
+    eng = latent_engine(prefill_chunk=16, prefill_cohort=cohort)
+    assert eng.steps.chunk_attention is None        # nothing traced yet
+    tracing.clear()
+    with profiler_session():
+        serve_latent(eng)
+    rows = tracing.rows()
+    tracing.clear()
+    admits = [r for r in rows if r.name == "engine.admit"]
+    assert admits and all(r.attrs["chunk"] == 16 for r in admits)
+    how = eng.steps.chunk_attention
+    assert how.startswith("loop:") and "multiples of 128" in how
+    assert {r.attrs["chunk_attention"] for r in admits} == {how}
+    assert all(r.attrs["attended_pairs"] > 0 for r in admits)
+    assert eng.steps.prefill_chunk_traces == {(cohort, 16): 1}
+
+
 @pytest.mark.parametrize("what", ["speculative", "int8-block", "ring wrap"])
 def test_a_positional_page_still_refuses_what_has_no_program(what):
     """Rewind, per-column requantisation and wrap could be done to a latent
