@@ -47,13 +47,25 @@ not ``live`` untouched apart from page columns past their cursor. With
 rows ``slots`` of a cache that holds more rows than the call has: a chunk
 cohort against the whole grid's pages, where they lie.
 
+With ``n_mtp`` 1 the model carries one multi-token-prediction module
+(:class:`MTPModule`, DeepSeek-V3 report §2.2) beside its layers: its own
+norms, a ``2d → d`` projection of ``[norm(h_i) ; norm(Emb(t_{i+1}))]``, one
+block of the expert kind over its OWN latent page, the main model's
+embedding and head. It is run by a call of its own (``hidden=``), never
+inside the main call, and the ``cache`` collection then also holds, per
+slot, the ``draft`` a self-drafting engine keeps between rounds
+(``serving/state_cache.py``). With ``absorbed=True`` a call's ``L`` tokens
+are decode positions: every row writes ``L`` latents at its cursor and the
+``L`` queries take the absorbed form against one read of the page.
+
 What a leaf of that collection may do: a POSITIONAL leaf
 (``HybridLM.positional_leaves``: the latent page ``ckv``, axis 1 the
 position) is addressed by the cursor like K/V rows, so a call may start at
 any cursor (``pos``) and a prompt may arrive in chunks; a RECURRENT leaf
 (every other one but ``idx``: KDA's ``state`` and ``conv``) is the sum of
 its history, starts from what the slot holds and cannot be rewound,
-re-windowed or written at a column.
+re-windowed or written at a column. ``idx`` and ``draft`` are per-slot
+scalars, neither page nor state.
 """
 
 from __future__ import annotations
@@ -71,7 +83,7 @@ from chainermn_tpu.ops import latent_attention
 from chainermn_tpu.parallel.expert_share import HeldExperts, RouteStats
 
 __all__ = ["HybridLM", "HybridBlock", "HyperConnection", "KDAMixer",
-           "MLAMixer", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
+           "MLAMixer", "MTPModule", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
            "latent_chunk_attention", "latent_decode_attention",
            "layer_pattern", "sinkhorn", "yarn_inv_freq", "yarn_mscale"]
 
@@ -459,7 +471,7 @@ def latent_chunk_attention(q_nope, q_rope, page, w_kvb, pos, scale, block,
 
 
 def latent_decode_attention(q_cat, page, pos, live, scale, r,
-                            block=DECODE_BLOCK):
+                            block=DECODE_BLOCK, offs=None):
     """One query a row over its latent page, ABSORBED, block by block.
     ``q_cat [B, H, r + dr]`` (the no-rope query already through
     ``W_kvb``'s key half, beside the rotary query); row ``b`` sees columns
@@ -473,11 +485,21 @@ def latent_decode_attention(q_cat, page, pos, live, scale, r,
     re-lay the whole page with its columns minor around the loop: a copy of
     every page a step.) Returns ``(``the attended latents ``[B, H, r]``
     float32, the page``)``, the page through the loop's carry as in
-    :func:`latent_chunk_attention`."""
+    :func:`latent_chunk_attention`.
+
+    With ``offs [H]`` int32 (a tuple of Python ints) the ``H`` rows of
+    ``q_cat`` are several queries' heads side by side, query-major, and
+    "head" ``i`` belongs to the query at position ``pos[b] + offs[i]``: a
+    few queries at consecutive positions against ONE read of the row's
+    blocks (the self-drafting round's two, ``serving/state_cache.py``)."""
     b, h, w = q_cat.shape
     t = page.shape[1]
     block = min(block, t)
-    n_of = jnp.where(live, pos // block + 1, 0)     # blocks a row reads
+    if offs is None:
+        n_of = jnp.where(live, pos // block + 1, 0)     # blocks a row reads
+    else:
+        n_of = jnp.where(live, (pos + max(offs)) // block + 1, 0)
+        offs = jnp.asarray(offs, jnp.int32)
     ends = jnp.cumsum(n_of)
 
     def body(i, carry):
@@ -490,8 +512,12 @@ def latent_decode_attention(q_cat, page, pos, live, scale, r,
         s = jnp.dot(blk, at(q_cat).T,
                     preferred_element_type=jnp.float32) * scale   # [blk, H]
         col = s0 + jnp.arange(block)
-        seen = (col <= at(pos)) & (col >= j * block)
-        s = jnp.where(seen[:, None], s, -jnp.inf)
+        if offs is None:
+            seen = ((col <= at(pos)) & (col >= j * block))[:, None]
+        else:
+            seen = ((col[:, None] <= at(pos) + offs[None])
+                    & (col >= j * block)[:, None])
+        s = jnp.where(seen, s, -jnp.inf)
         m_new = jnp.maximum(at(m), s.max(0))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(at(m) - m_new)
@@ -527,10 +553,15 @@ class MLAMixer(nn.Module):
     block: int = 0                   # 0: score the whole page in one piece
 
     @nn.compact
-    def __call__(self, x, pos, lengths=None, live=None, slots=None):
+    def __call__(self, x, pos, lengths=None, live=None, slots=None,
+                 absorbed=False):
         """``slots [B]`` (blocked mode only): the cache's rows this call's
         rows live in, where the cache holds more rows than the call has (a
-        chunk cohort against the whole grid's pages)."""
+        chunk cohort against the whole grid's pages). ``absorbed`` (blocked
+        pages, ``decode=True``): the ``L`` tokens of a row are a few decode
+        positions, not a chunk of a prompt — every row writes its ``L``
+        latents at ``pos ..`` and the queries take the absorbed form
+        against one read of the page."""
         b, l, d = x.shape
         h, dn, dr, dv, r = (self.n_heads, self.d_nope, self.d_rope,
                             self.d_v, self.kv_rank)
@@ -583,7 +614,33 @@ class MLAMixer(nn.Module):
         if slots is not None and not (l > 1 and self.block and self.decode):
             raise ValueError("slots address the pages of a blocked chunk "
                              "call (mla_block > 0, decode=True, L > 1)")
-        if l > 1 and self.block:
+        if absorbed and l > 1:
+            if not (self.block and self.decode) or slots is not None:
+                raise ValueError("several absorbed queries a row need "
+                                 "blocked pages (mla_block > 0), decode=True "
+                                 "and no slots")
+            with jax.named_scope("mla_absorbed"):
+                # rows pos .. pos+L-1 of every slot in one scatter: what a
+                # row does not keep (a rejected draft's latent, a row that
+                # is not live) lies at or beyond its fill
+                page = page_v.value.at[
+                    jnp.arange(b)[:, None], positions].set(
+                        ckv.astype(page_v.value.dtype), mode="drop")
+                q_lat = jnp.einsum("blhe,rhe->blhr", q_nope,
+                                   w_kvb[..., :dn]).astype(self.dtype)
+                q_cat = jnp.concatenate([q_lat, q_rope], -1)
+                q_cat = jnp.pad(q_cat, ((0, 0), (0, 0), (0, 0),
+                                        (0, page.shape[-1] - r - dr)))
+                o_lat, page = latent_decode_attention(
+                    q_cat.reshape(b, l * h, -1), page, pos,
+                    jnp.ones((b,), bool) if live is None else live, scale, r,
+                    offs=tuple(j for j in range(l) for _ in range(h)))
+                page_v.value = page
+                o = jnp.einsum("blhr,rhe->blhe",
+                               o_lat.reshape(b, l, h, r).astype(self.dtype),
+                               w_kvb[..., dn:],
+                               preferred_element_type=jnp.float32)
+        elif l > 1 and self.block:
             # a chunk at any cursor: its latents go into the page at
             # [pos, pos + length), then the chunk attends the page
             with jax.named_scope("mla_chunk"):
@@ -727,13 +784,14 @@ class HybridBlock(nn.Module):
     cfg: Any                     # HybridLM.dims(): the sizes, as a tuple
     decode: bool = False
 
-    def _mix(self, y, pos, lengths, live, slots):
+    def _mix(self, y, pos, lengths, live, slots, absorbed):
         c = self.cfg
         y = RMSNorm(c.norm_eps, c.dtype, name="norm_mix")(y)
         if self.mixer == "kda":
-            if slots is not None:
+            if slots is not None or absorbed:
                 raise ValueError("a recurrent state is not addressed by "
-                                 "slot: it has no chunk call")
+                                 "slot or by position: it has no chunk call "
+                                 "and no several-position decode call")
             return KDAMixer(c.n_heads, c.d_head, c.d_head, conv=c.conv_kernel,
                             lower_bound=c.kda_lower_bound, eps=c.norm_eps,
                             dtype=c.dtype, decode=self.decode,
@@ -743,7 +801,7 @@ class HybridBlock(nn.Module):
                         dtype=c.dtype, decode=self.decode, q_rank=c.q_rank,
                         gate=c.mla_gate, rope_scaling=c.rope_scaling,
                         block=c.mla_block, name="mla")(y, pos, lengths, live,
-                                                       slots)
+                                                       slots, absorbed)
 
     def _feed(self, y, lengths, live):
         c = self.cfg
@@ -781,18 +839,57 @@ class HybridBlock(nn.Module):
         return x.astype(c.dtype), stats
 
     @nn.compact
-    def __call__(self, x, pos, lengths, live, slots=None):
+    def __call__(self, x, pos, lengths, live, slots=None, absorbed=False):
         x, _ = self._around(
             x, "hc_mix",
-            lambda y: (self._mix(y, pos, lengths, live, slots), None))
+            lambda y: (self._mix(y, pos, lengths, live, slots, absorbed),
+                       None))
         return self._around(x, "hc_ffn",
                             lambda y: self._feed(y, lengths, live))
+
+
+class MTPModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3 report §2.2), without
+    the embedding and the head, which are the main model's: for the main
+    hidden state ``h_i`` (the last layer's output BEFORE the final norm) and
+    the embedding of the token ``t_{i+1}`` that follows,
+
+    ``u = W_eh [RMSNorm_h(h_i) ; RMSNorm_e(Emb(t_{i+1}))]``, ``h'_i =
+    Block(u)`` at position ``i`` — one block of the expert kind: latent
+    attention over the module's OWN page, this chip's share of its own
+    routed experts, the shared expert — and ``RMSNorm_out(h'_i)`` is what the
+    main head turns into the logits of ``t_{i+2}``."""
+    cfg: Any                     # HybridLM.dims()
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, hidden, emb_next, pos, lengths, live, absorbed=False):
+        c = self.cfg
+        norm = lambda name: RMSNorm(c.norm_eps, c.dtype, name=name)
+        u = nn.Dense(c.d_model, use_bias=False, dtype=c.dtype,
+                     param_dtype=c.dtype, name="eh_proj")(
+            jnp.concatenate([norm("norm_h")(hidden),
+                             norm("norm_e")(emb_next)], -1))
+        x, stats = HybridBlock("mla", "moe", c._replace(hc_mult=1),
+                               decode=self.decode, name="block")(
+            u, pos, lengths, live, None, absorbed)
+        return norm("norm_out")(x), stats
 
 
 class HybridLM(nn.Module):
     """See the module docstring. ``pattern`` is one ``(mixer, ffn)`` pair a
     layer (:func:`layer_pattern`); the expert fields describe the routed
-    layers: ``n_experts`` routed over, ``held_lo:held_hi`` held here."""
+    layers: ``n_experts`` routed over, ``held_lo:held_hi`` held here.
+
+    ``n_mtp`` 1 adds one :class:`MTPModule` (``mtp_0``; 0 creates no
+    parameter and no page). The module is run by a call of its own:
+    ``__call__(next_tokens, pos_offset=positions, hidden=h, ...)`` returns
+    the DRAFT logits — of the token two places on — for the main hidden
+    states ``h`` (what a main call hands back beside its logits under
+    ``return_hidden``) and the tokens that follow them, reading and writing
+    the module's own latent page at those positions and nothing else of the
+    cache. ``tok_emb`` and ``lm_head`` are the main model's in both calls:
+    one leaf each."""
     vocab: int
     d_model: int
     n_heads: int
@@ -825,6 +922,7 @@ class HybridLM(nn.Module):
     mla_gate: bool = True
     rope_scaling: Optional[Mapping[str, Any]] = None     # YaRN's keys
     mla_block: int = 0               # page columns a block; 0: one piece
+    n_mtp: int = 0                   # multi-token-prediction modules (0, 1)
     dtype: Any = jnp.float32
     decode: bool = False
 
@@ -832,8 +930,8 @@ class HybridLM(nn.Module):
     #: declares (recurrent state, convolution tail, latent page), not K/V
     declares_cache = True
     #: serving/state_cache.py: the declared leaves a cursor addresses like
-    #: K/V rows (axis 1 is the position); any other but ``idx`` is a
-    #: recurrence
+    #: K/V rows (axis 1 is the position); any other but ``idx`` and
+    #: ``draft`` is a recurrence
     positional_leaves = ("ckv",)
 
     @property
@@ -848,14 +946,42 @@ class HybridLM(nn.Module):
         return collections.namedtuple("HybridDims", names)(
             *(getattr(self, n) for n in names))
 
+    def _sow(self, stats, prefix=""):
+        # the serving step returns the collection with the tokens, and the
+        # engine's decode span carries it under these names
+        for name, v in stats._asdict().items():
+            self.sow("stats", prefix + name, v, reduce_fn=lambda a, b: a + b,
+                     init_fn=lambda v=v: jnp.zeros((), v.dtype))
+
+    def _draft(self, emb, head, hidden, tokens, pos, lengths, live, absorbed,
+               at):
+        """The module's call: draft logits ``[B, L, vocab]`` (``[B, vocab]``
+        at the positions ``at [B]``)."""
+        with jax.named_scope("mtp_draft"):
+            x, stats = MTPModule(self.dims(), decode=self.decode,
+                                 name="mtp_0")(hidden, emb(tokens), pos,
+                                               lengths, live, absorbed)
+            if self.decode:
+                self._sow(stats, "mtp_")
+            if at is not None:
+                x = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
+            return head(x).astype(jnp.float32)
+
     @nn.compact
     def __call__(self, tokens, pos_offset=None, lengths=None, live=None,
-                 slots=None):
+                 slots=None, *, hidden=None, absorbed=False,
+                 return_hidden=False, at=None):
         """``slots [B]`` (with ``pos_offset``, models of blocked latent
         pages only): row ``b`` of the call is row ``slots[b]`` of a cache
         that holds more rows than the call has — a chunk cohort run against
         the whole grid's pages where they lie, no copy of a page in or out.
-        A slot past the cache's rows is a padding row: it writes nothing."""
+        A slot past the cache's rows is a padding row: it writes nothing.
+
+        ``absorbed``: the ``L`` tokens are decode positions (``MLAMixer``);
+        ``return_hidden``: ``(logits, the last layer's output before the
+        final norm)``; ``at [B]``: logits of those positions alone, ``[B,
+        vocab]``; ``hidden [B, L, d]``: the call is the MTP module's (class
+        docstring) and ``tokens`` are the tokens that follow."""
         b, l = tokens.shape
         if lengths is None:
             lengths = jnp.full((b,), l, jnp.int32)
@@ -864,14 +990,36 @@ class HybridLM(nn.Module):
         if slots is not None and (pos_offset is None or not self.decode):
             raise ValueError("slots need decode=True and the rows' cursors "
                              "as pos_offset")
+        if self.n_mtp not in (0, 1):
+            raise ValueError("one multi-token-prediction module at most "
+                             f"(n_mtp {self.n_mtp}): deeper drafting is not "
+                             "built")
+        emb = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
+                       param_dtype=self.dtype, name="tok_emb")
+        head = nn.Dense(self.vocab, use_bias=False, dtype=self.dtype,
+                        param_dtype=self.dtype, name="lm_head")
+        if hidden is not None:
+            if not self.n_mtp or slots is not None:
+                raise ValueError("the module's call needs n_mtp 1 and takes "
+                                 "no slots")
+            if self.decode and pos_offset is None:
+                raise ValueError("the module's call takes its positions as "
+                                 "pos_offset: it does not move the cursor")
+            pos = (jnp.zeros((b,), jnp.int32) if pos_offset is None else
+                   jnp.broadcast_to(jnp.asarray(pos_offset, jnp.int32), (b,)))
+            return self._draft(emb, head, hidden, tokens, pos, lengths, live,
+                               absorbed, at)
         if self.decode:
             idx = self.variable("cache", "idx", jnp.zeros, (b,), jnp.int32)
             pos = idx.value if pos_offset is None else jnp.broadcast_to(
                 jnp.asarray(pos_offset, jnp.int32), (b,))
+            if self.n_mtp:
+                # the draft a slot holds between rounds: the serving
+                # program's to read and write, the model's to declare
+                self.variable("cache", "draft", jnp.zeros, (b,), jnp.int32)
         else:
             pos = jnp.zeros((b,), jnp.int32)
-        x = nn.Embed(self.vocab, self.d_model, dtype=self.dtype,
-                     param_dtype=self.dtype, name="tok_emb")(tokens)
+        x = emb(tokens)
         if self.hc_mult > 1:        # every stream starts as the embedding
             x = jnp.broadcast_to(x[:, :, None],
                                  (b, l, self.hc_mult, self.d_model))
@@ -880,7 +1028,7 @@ class HybridLM(nn.Module):
         for i, (mixer, ffn) in enumerate(self.pattern):
             x, st = HybridBlock(mixer, ffn, dims, decode=self.decode,
                                 name=f"block_{i}")(x, pos, lengths, live,
-                                                   slots)
+                                                   slots, absorbed)
             if st is not None:
                 stats = stats + st
         if self.decode:
@@ -888,14 +1036,27 @@ class HybridLM(nn.Module):
             idx.value = moved if slots is None else idx.value.at[slots].set(
                 moved, mode="drop")
             if self.n_experts:
-                # the serving step returns the collection with the tokens,
-                # and the engine's decode span carries it under these names
-                for name, v in stats._asdict().items():
-                    self.sow("stats", name, v, reduce_fn=lambda a, b: a + b,
-                             init_fn=lambda v=v: jnp.zeros((), v.dtype))
+                self._sow(stats)
         if self.hc_mult > 1:        # and the streams are summed at the end
             x = x.astype(jnp.float32).sum(2).astype(self.dtype)
+        if self.n_mtp and self.is_initializing():
+            # the module's leaves and its page exist from the model's init
+            self._draft(emb, head, x, tokens, pos, lengths, live, False,
+                        None)
+        h = x
+        if at is not None:
+            x = jnp.take_along_axis(x, at[:, None, None], axis=1)[:, 0]
         x = RMSNorm(self.norm_eps, self.dtype, name="norm_f")(x)
-        return nn.Dense(self.vocab, use_bias=False, dtype=self.dtype,
-                        param_dtype=self.dtype, name="lm_head")(x).astype(
-                            jnp.float32)
+        logits = head(x).astype(jnp.float32)
+        return (logits, h) if return_hidden else logits
+
+    def self_draft_refusal(self) -> Optional[str]:
+        """Why a serving step cannot run this model's self-drafted rounds
+        (``serving/state_cache.py``), or None where it can: the model, not
+        the step, knows what its module and its pages take."""
+        if not self.n_mtp:
+            return "it carries no multi-token-prediction module (n_mtp 1)"
+        if not self.mla_block:
+            return ("two positions a slot against one read of the latent "
+                    "page need blocked pages (mla_block > 0)")
+        return None

@@ -22,7 +22,11 @@ TOKEN BUDGET (``EngineConfig.token_budget``; ``None`` → unbounded):
    by per-slot PRNG state the engine threads), EOS/budget stop masks
    are evaluated in the compiled scan, and the host pulls a single
    ``[n_slots, k]`` int32 array — 4 bytes/token instead of
-   ``vocab × 4`` (dlint DL110 polices the old full-logits pull).
+   ``vocab × 4`` (dlint DL110 polices the old full-logits pull). With
+   ``EngineConfig.self_draft`` (a model that carries a multi-token-
+   prediction module, ``state_cache.py``) the dispatch is ``k``
+   self-drafted ROUNDS of one or two tokens a slot, the pull ``[n_slots,
+   2k]``, and every reservation below counts ``2k``.
 4. **Retirement** — slots whose request emitted ``eos_id`` or reached
    its token budget are freed for the next admission.
 
@@ -102,6 +106,11 @@ class EngineConfig:
     #                                   pages forbid ring wrap, so submit
     #                                   enforces prompt + max_new ≤
     #                                   capacity)
+    self_draft: bool = False          # decode_k runs self-drafted rounds
+    #                                   (the model's own MTP module drafts,
+    #                                   state_cache.py): 1 or 2 tokens a
+    #                                   slot a round; submit enforces
+    #                                   prompt + max_new + 1 ≤ capacity
 
     def bucket_table(self) -> Tuple[int, ...]:
         return (tuple(sorted(self.buckets)) if self.buckets
@@ -135,6 +144,8 @@ class Request:
     #                                   for export_handoff; fleet pools)
     t_submit: float = dataclasses.field(   # time.perf_counter at creation:
         default_factory=time.perf_counter)  # the queue's age in engine.step
+    drafts_proposed: int = 0          # rounds whose first token left this
+    drafts_accepted: int = 0          # stream alive; those that gave two
 
     @property
     def finished(self) -> bool:
@@ -159,10 +170,15 @@ class Engine:
             raise ValueError("prefill_chunk must be >= 1")
         if config.prefill_chunk is not None:
             refuse_recurrent(model, "chunked prefill", positional_too=False)
+        if config.self_draft and config.prefill_chunk is not None:
+            raise ValueError(
+                "self_draft serves bucketed prefill only: the module runs "
+                "over the prompt there (prefill_chunk must be None)")
         self.steps = serving_step(
             model, params, config.n_slots, config.capacity,
             cache_dtype=config.cache_dtype, mesh=mesh, axis=axis,
-            kv_dtype=config.kv_dtype)
+            kv_dtype=config.kv_dtype,
+            **({"self_draft": True} if config.self_draft else {}))
         self.report = report or (ServingReport(time_fn) if time_fn
                                  else ServingReport())
         self.queue: deque[Request] = deque()
@@ -223,6 +239,13 @@ class Engine:
             raise ValueError(
                 f"{self.steps.no_wrap}: prompt ({prompt.size})"
                 f" + max_new_tokens ({budget}) exceeds the page capacity "
+                f"({self.config.capacity})")
+        if (self.config.self_draft
+                and prompt.size + budget + 1 > self.config.capacity):
+            raise ValueError(
+                "a self-drafted round writes the draft's row beside the "
+                f"current token's: prompt ({prompt.size}) + max_new_tokens "
+                f"({budget}) + 1 exceeds the page capacity "
                 f"({self.config.capacity})")
         req = Request(request_id=next(self._ids), prompt=prompt,
                       max_new_tokens=budget,
@@ -544,9 +567,10 @@ class Engine:
     def _max_decode_advance(self) -> int:
         """Cache columns one decode iteration may write per slot — the
         wrap guard's and token budget's reservation unit. The base
-        engine advances ``decode_k``; ``speculative.SpeculativeEngine``
-        overrides this with its verify width (``spec_k + 1``)."""
-        return self.config.decode_k
+        engine advances ``decode_k`` (``2 · decode_k`` when its rounds are
+        self-drafted); ``speculative.SpeculativeEngine`` overrides this
+        with its verify width (``spec_k + 1``)."""
+        return self.config.decode_k * (2 if self.config.self_draft else 1)
 
     def _on_prefill(self, tokens, lengths, slot_ids) -> None:
         """Subclass hook, fired after every monolithic prefill dispatch
@@ -730,7 +754,10 @@ class Engine:
     def _decode(self) -> int:
         """One ``decode_k`` dispatch for the whole grid; the host pulls
         a single ``[n_slots, k]`` int32 array (validity in-band as -1)
-        and replays the device's EOS/budget retirement decisions."""
+        and replays the device's EOS/budget retirement decisions. A
+        self-drafted dispatch pulls ``[n_slots, 2k]``, round-major: a
+        round's second place is -1 where the draft was rejected, both
+        past the row's stop."""
         cfg = self.config
         n = cfg.n_slots
         with tracing.span("engine.decode.enqueue",
@@ -766,9 +793,39 @@ class Engine:
         self.report.record_host_bytes(toks.nbytes)
         with tracing.span("engine.emit") as sp:
             emitted = retired = 0
+            if cfg.self_draft:
+                # [slot, round, place]: a round ran where its first place
+                # holds a token, and its draft was accepted where the second
+                # does
+                got = toks.reshape(cfg.n_slots, cfg.decode_k, 2) >= 0
+                ran = got[..., 0].sum(1)
+                took = got[..., 1].sum(1).tolist()
+                # whether the last round a row ran gave its second token
+                whole = got[np.arange(cfg.n_slots), np.maximum(ran - 1, 0),
+                            1].tolist()
+                ran = ran.tolist()
+                rounds = proposed = accepted = 0
             for slot, req in list(self.active.items()):
-                emitted += self._replay(req, toks[slot])
+                row = toks[slot]
+                if cfg.self_draft:
+                    row = row[row >= 0]
+                emitted += self._replay(req, row)
                 retired += req.finished
+                if cfg.self_draft:
+                    # a draft was verified where the round's first token
+                    # left the row alive (the device's ``drafts_verified``):
+                    # a stream that ended on a first place did not verify
+                    # that round's
+                    verified = ran[slot] - bool(
+                        req.finished and ran[slot] and not whole[slot])
+                    req.drafts_proposed += verified
+                    req.drafts_accepted += took[slot]
+                    rounds += ran[slot]
+                    proposed += verified
+                    accepted += took[slot]
+            if cfg.self_draft:
+                self.report.record_spec_round(proposed, accepted, emitted,
+                                              rounds=rounds)
             if sp:
                 sp.set(tokens=emitted, retired=retired)
         return emitted

@@ -575,6 +575,18 @@ class ServingStep:
         w_start, w_count = self._scatter_window(slot_ids, starts, valid)
         return last, repack_cache(cache, f32c, w_start, w_count)
 
+    def _prefill_sampled_program(self, params, cache, tokens, lengths,
+                                 slot_ids, keys, temps, top_ks):
+        last, cache = self._prefill_program(params, cache, tokens, lengths,
+                                            slot_ids)
+        sid = jnp.asarray(slot_ids, jnp.int32)
+        gid = jnp.clip(sid, 0, self.n_slots - 1)
+        tok, newk = sample_tokens(last, keys[gid], temps[gid], top_ks[gid])
+        # sentinel rows (sid == n_slots) drop out of the key scatter —
+        # their splits never touch a live slot's stream
+        keys = keys.at[sid].set(newk, mode="drop")
+        return tok, keys, cache
+
     #: outputs of ``_decode_k_program`` after the cache (replicated)
     _decode_k_extra = 0
 
@@ -729,9 +741,13 @@ class ServingStep:
             jnp.asarray(remaining, jnp.int32),
             jnp.asarray(live, bool), jnp.asarray(park, jnp.int32))
         self.last_decode_logits = last
-        if extra:
-            self.last_decode_stats = extra[0]
+        self._keep_decode_extra(*extra)
         return toks, keys
+
+    def _keep_decode_extra(self, stats=None):
+        """What ``_decode_k_program`` returned after the cache."""
+        if stats is not None:
+            self.last_decode_stats = stats
 
     def prefill_sampled(self, tokens, lengths, slot_ids, keys, temps,
                         top_ks):
@@ -750,16 +766,9 @@ class ServingStep:
                     temps, top_ks, _key=key):
                 self.prefill_traces[_key] = (
                     self.prefill_traces.get(_key, 0) + 1)
-                last, cache = self._prefill_program(
-                    params, cache, tokens, lengths, slot_ids)
-                sid = jnp.asarray(slot_ids, jnp.int32)
-                gid = jnp.clip(sid, 0, self.n_slots - 1)
-                tok, newk = sample_tokens(last, keys[gid], temps[gid],
-                                          top_ks[gid])
-                # sentinel rows (sid == n_slots) drop out of the key
-                # scatter — their splits never touch a live slot's stream
-                keys = keys.at[sid].set(newk, mode="drop")
-                return tok, keys, cache
+                return self._prefill_sampled_program(
+                    params, cache, tokens, lengths, slot_ids, keys, temps,
+                    top_ks)
 
             kw = {}
             if self._mesh is not None:

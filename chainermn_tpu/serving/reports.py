@@ -69,7 +69,9 @@ class ServingReport:
         # counters — acceptance_rate and tokens_per_dispatch in summary()
         self.draft_tokens_proposed = 0
         self.draft_tokens_accepted = 0
-        self.spec_dispatches = 0      # one per (slot, round) pair
+        self.spec_dispatches = 0      # one per (slot, round) pair, whoever
+        #                               ran the round (speculative.py's two
+        #                               dispatches, state_cache.py's scan)
         self.spec_tokens_emitted = 0
         self.ttft_s: List[float] = []
         self.token_gap_s: List[float] = []
@@ -144,18 +146,20 @@ class ServingReport:
         self.host_bytes += int(nbytes)
 
     def record_spec_round(self, proposed: int, accepted: int,
-                          emitted: int) -> None:
-        """One speculative round for ONE slot (the engine calls this per
-        live slot per propose+verify round): ``proposed`` draft tokens
-        went into the verify chunk, ``accepted`` matched the target's
-        own samples, and ``emitted`` tokens entered the stream
-        (``accepted + 1`` normally — the round's last token is always
-        target-sampled: correction, bonus, or terminal). The ratios an
-        operator sizes the draft model by — ``acceptance_rate`` and
+                          emitted: int, rounds: int = 1) -> None:
+        """One speculative round for ONE slot (``SpeculativeEngine`` calls
+        this per live slot per propose+verify round), or the sums over
+        ``rounds`` (slot, round) pairs of one dispatch (the engine's
+        self-drafted ``decode_k``, whose rounds run on the device):
+        ``proposed`` draft tokens went into a verify, ``accepted`` matched
+        the target's own samples, and ``emitted`` tokens entered the stream
+        (``accepted + 1`` a round normally — the round's last token is
+        always target-sampled: correction, bonus, or terminal). The ratios
+        an operator sizes the draft by — ``acceptance_rate`` and
         ``tokens_per_dispatch`` — fold out of these in ``summary()``."""
         self.draft_tokens_proposed += int(proposed)
         self.draft_tokens_accepted += int(accepted)
-        self.spec_dispatches += 1
+        self.spec_dispatches += int(rounds)
         self.spec_tokens_emitted += int(emitted)
 
     # ----------------------------------------------------------------

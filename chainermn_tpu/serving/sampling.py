@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["request_key", "init_keys", "split_keys", "sample_tokens",
-           "draft_shadow_keys"]
+           "draft_shadow_keys", "acceptance_scan"]
 
 
 def request_key(seed: int):
@@ -149,3 +149,53 @@ def draft_shadow_keys(keys):
     (draft-target-key-confusion) flags exactly that dataflow.
     """
     return jnp.asarray(keys, jnp.uint32).copy()
+
+
+def acceptance_scan(logits, drafts, keys, temps, top_ks, eos_ids, remaining,
+                    live):
+    """The acceptance scan every verifier shares (``speculative.py::
+    verify_apply`` after a separate draft model, ``state_cache.py``'s
+    self-drafting round after the model's own module).
+
+    ``logits [w, n, vocab]``: the TARGET's logits at ``w`` consecutive
+    stream positions of every row, position ``j`` computed with the drafts
+    ``d_1 .. d_j`` as its inputs; ``drafts [w - 1, n]`` int32: ``d_1 ..
+    d_{w-1}``. Position by position the scan samples ``s_j`` from
+    ``logits[j]`` with the row's REAL key and emits it while the row is
+    alive and every earlier ``s_i`` equalled ``d_{i+1}``: the longest
+    accepted prefix plus one more target-sampled token (the CORRECTION at
+    the first mismatch, the BONUS ``s_{w-1}`` after a full accept). A key
+    row advances ONLY when its row emits — one split a sampled token, as in
+    ``decode_k_apply`` — and EOS and the budget (``remaining``) stop a row
+    exactly as there. So what is emitted is what plain decode emits; the
+    drafts decide how far a round goes, never what it yields.
+
+    Returns ``(emitted [n, w] int32, -1 past each row's stop; keys;
+    remaining; alive — the rows that may emit again; m [n] — tokens
+    emitted)``."""
+    w, n = logits.shape[:2]
+    nxt = jnp.concatenate(
+        [jnp.asarray(drafts, jnp.int32),
+         jnp.full((1, n), -1, jnp.int32)], axis=0)          # [w, n]: d_{j+1}
+    is_bonus = jnp.arange(w) == w - 1
+
+    def body(carry, xs):
+        keys, rem, alive, accepting, m = carry
+        lj, dj, bonus = xs
+        s, keys2 = sample_tokens(lj, keys, temps, top_ks)
+        emit = accepting & alive
+        # only emitting rows consume a split — the key stream position
+        # stays a pure function of tokens sampled, as everywhere else
+        keys = jnp.where(emit[:, None], keys2, keys)
+        rem = rem - emit.astype(jnp.int32)
+        hit_eos = (s == eos_ids) & (eos_ids >= 0)
+        alive = alive & ~(emit & (hit_eos | (rem <= 0)))
+        accepting = accepting & alive & ~bonus & (s == dj)
+        out = jnp.where(emit, s, jnp.int32(-1))
+        return (keys, rem, alive, accepting,
+                m + emit.astype(jnp.int32)), out
+
+    init = (keys, remaining, live, live, jnp.zeros((n,), jnp.int32))
+    (keys, rem, alive, _, m), outs = jax.lax.scan(
+        body, init, (logits, nxt, is_bonus))
+    return outs.T, keys, rem, alive, m

@@ -26,7 +26,10 @@ dispatches for the whole slot grid:
    BITWISE the logits non-speculative decode would compute at that
    stream position (chunked == monolithic == squeezed-q decode — the
    pinned parity chain in models/transformer.py, ``attention=
-   'reference'``, no ring wrap). An on-device acceptance scan then
+   'reference'``, no ring wrap). The on-device acceptance scan
+   (:func:`~.sampling.acceptance_scan`, shared with the self-drafting
+   round of ``state_cache.py``, where a model's own multi-token-prediction
+   module is the draft and propose, verify and accept are ONE program) then
    samples ``s_j`` from ``L_j`` with the REAL key rows — advancing a
    row's key only when it actually emits, the one-split-per-sampled-
    token contract — and emits the longest accepted prefix
@@ -84,7 +87,8 @@ from chainermn_tpu.serving.kv_cache import (
     repack_cache,
     unpack_cache,
 )
-from chainermn_tpu.serving.sampling import draft_shadow_keys, sample_tokens
+from chainermn_tpu.serving.sampling import (acceptance_scan,
+                                            draft_shadow_keys, sample_tokens)
 from chainermn_tpu.serving.state_cache import refuse_recurrent
 
 __all__ = ["DraftStep", "SpeculativeEngine", "propose_apply",
@@ -145,10 +149,11 @@ def verify_apply(dm_chunk, params, cache, cur, drafts, keys, temps,
     """PURE target verification + acceptance for the whole grid.
 
     One chunked forward of ``[cur, d_1 .. d_spec_k]`` (width ``spec_k +
-    1``) at ``starts = fill`` yields per-position logits; the
-    acceptance scan samples ``s_j`` from position ``j`` with the real
-    key rows and emits while ``s_j == d_{j+1}``, then one correction or
-    bonus token. Key rows advance ONLY on emission — one split per
+    1``) at ``starts = fill`` yields per-position logits; the shared
+    acceptance scan (``sampling.acceptance_scan``, which the
+    self-drafting round of ``state_cache.py`` runs too) samples ``s_j``
+    from position ``j`` with the real key rows and emits while ``s_j ==
+    d_{j+1}``, then one correction or bonus token. Key rows advance ONLY on emission — one split per
     sampled token, the same contract as ``decode_k_apply`` — and the
     EOS/budget masks mirror its stop logic token for token.
 
@@ -157,8 +162,6 @@ def verify_apply(dm_chunk, params, cache, cur, drafts, keys, temps,
     ride-along rows stay parked. ``cache`` must be the f32 view
     (callers unpack/repack int8 pages around this).
     """
-    n = cur.shape[0]
-    w = spec_k + 1
     cur = jnp.asarray(cur, jnp.int32)
     drafts = jnp.asarray(drafts, jnp.int32)
     live = jnp.asarray(live, bool)
@@ -180,31 +183,9 @@ def verify_apply(dm_chunk, params, cache, cur, drafts, keys, temps,
         mutable=["cache"])
     new_cache = upd["cache"]
 
-    lo = jnp.moveaxis(logits, 1, 0)                       # [w, n, vocab]
-    nxt_draft = jnp.concatenate(
-        [drafts, jnp.full((n, 1), -1, jnp.int32)], axis=1)
-    dn = nxt_draft.T                                      # [w, n]: d_{j+1}
-    is_bonus = jnp.arange(w) == w - 1
-
-    def body(carry, xs):
-        keys, rem, alive, accepting, m = carry
-        lj, dj, bonus = xs
-        s, keys2 = sample_tokens(lj, keys, temps, top_ks)
-        emit = accepting & alive
-        # only emitting rows consume a split — the key stream position
-        # stays a pure function of tokens sampled, as everywhere else
-        keys = jnp.where(emit[:, None], keys2, keys)
-        rem = rem - emit.astype(jnp.int32)
-        hit_eos = (s == eos_ids) & (eos_ids >= 0)
-        alive = alive & ~(emit & (hit_eos | (rem <= 0)))
-        accepting = accepting & alive & ~bonus & (s == dj)
-        out = jnp.where(emit, s, jnp.int32(-1))
-        return (keys, rem, alive, accepting,
-                m + emit.astype(jnp.int32)), out
-
-    init = (keys, remaining, live, live, jnp.zeros((n,), jnp.int32))
-    (keys, _, _, _, m), outs = jax.lax.scan(body, init, (lo, dn, is_bonus))
-    emitted = outs.T
+    emitted, keys, _, _, m = acceptance_scan(
+        jnp.moveaxis(logits, 1, 0), drafts.T, keys, temps, top_ks, eos_ids,
+        remaining, live)
     idx = jnp.where(live, start + m, park)
     new_cache = {name: {**page, "idx": idx}
                  for name, page in new_cache.items()}
@@ -346,7 +327,12 @@ class SpeculativeEngine(Engine):
         if spec_k < 1:
             raise ValueError("spec_k must be >= 1")
         for m in (model, draft_model):
-            refuse_recurrent(m, "speculative decoding (rewind on reject)")
+            refuse_recurrent(
+                m, "speculative decoding (rewind on reject)",
+                instead="propose and verify here are written against K/V "
+                "pages; a model of declared positional pages drafts from "
+                "its own multi-token-prediction module, in the decode "
+                "program (n_mtp 1, EngineConfig.self_draft)")
         super().__init__(model, params, config, report=report,
                          time_fn=time_fn, weights_version=weights_version)
         if draft_model.vocab != model.vocab:

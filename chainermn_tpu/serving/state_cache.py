@@ -30,11 +30,21 @@ model takes the ``live`` mask and leaves such a row's state exactly as it was
 (``β = 0``, ``α = 1``), where the K/V path lets it write garbage past its
 cursor; a right-padded prefill row must stop at its true length — the model
 takes ``lengths`` and installs the state after the row's last real token.
-Speculative decoding (rewind on reject), ``int8-block`` pages (per-column
-requantisation) and ring wrap (overwrite the oldest column) are refused for
-all of them: a recurrent leaf forbids them by nature ("recurrent state"), and
-for a model of positional leaves alone these programs are not written (the
-message says so).
+``int8-block`` pages (per-column requantisation) and ring wrap (overwrite
+the oldest column) are refused for all of them: a recurrent leaf forbids
+them by nature ("recurrent state"), and for a model of positional leaves
+alone these programs are not written (the message says so).
+
+Speculation is SELF-DRAFTING here (``StateServingStep(self_draft=True)``,
+``EngineConfig.self_draft``): a model that carries a multi-token-prediction
+module (``n_mtp`` 1) drafts its own next token, and propose, verify and
+accept are one body of the ``decode_k`` scan
+(:func:`state_self_draft_k_apply`): the main layers over TWO positions a
+slot, the acceptance scan ``speculative.py`` uses too, the module over the
+positions just accepted. A rejected draft leaves one garbage row at the
+slot's fill in every page, which the next round's write covers before any
+mask reads it: positional leaves need no snapshot and no rewind. A
+recurrent leaf still refuses, by its name.
 """
 
 from __future__ import annotations
@@ -44,12 +54,18 @@ import jax.numpy as jnp
 import numpy as np
 
 from chainermn_tpu.serving.kv_cache import ServingStep
-from chainermn_tpu.serving.sampling import sample_tokens
+from chainermn_tpu.serving.sampling import (acceptance_scan,
+                                            draft_shadow_keys, sample_tokens)
 
 __all__ = ["StateServingStep", "serving_step", "declares_cache",
            "init_state_cache", "state_decode_apply", "state_prefill_apply",
            "state_decode_k_apply", "state_prefill_chunk_apply",
+           "state_self_draft_k_apply", "state_prefill_draft_apply",
            "recurrent_leaves", "refuse_recurrent"]
+
+#: the per-slot scalars of a declared cache: the cursor, and the draft a
+#: self-drafting slot holds between rounds. Neither is a page nor a state
+BOOKKEEPING_LEAVES = ("idx", "draft")
 
 
 def declares_cache(model) -> bool:
@@ -58,23 +74,26 @@ def declares_cache(model) -> bool:
 
 def recurrent_leaves(model):
     """Paths (``block_0/kda/state``) of the declared leaves that are a
-    recurrence: every leaf of the ``cache`` collection but the cursor and
-    the ones the model names in ``positional_leaves``."""
+    recurrence: every leaf of the ``cache`` collection but the per-slot
+    scalars (``BOOKKEEPING_LEAVES``) and the ones the model names in
+    ``positional_leaves``."""
     shapes = jax.eval_shape(lambda: init_state_cache(model, 1, 8))
     positional = tuple(getattr(model, "positional_leaves", ()))
     flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
     paths = ["/".join(str(getattr(k, "key", k)) for k in path)
              for path, _ in flat]
-    return [p for p in paths
-            if p != "idx" and p.rsplit("/", 1)[-1] not in positional]
+    return [p for p in paths if p not in BOOKKEEPING_LEAVES
+            and p.rsplit("/", 1)[-1] not in positional]
 
 
-def refuse_recurrent(model, what: str, *, positional_too: bool = True
-                     ) -> None:
+def refuse_recurrent(model, what: str, *, positional_too: bool = True,
+                     instead: str = "") -> None:
     """Raise for a feature that moves K/V rows by cursor. A model with a
     recurrent leaf is always refused, by that leaf's name; one whose
     declared leaves are all positional only where the feature has no
-    program for declared pages (``positional_too``)."""
+    program for declared pages (``positional_too``), with ``instead`` —
+    what serves such a model in the feature's place — in the message's
+    stead where there is one."""
     if not declares_cache(model):
         return
     name = type(model).__name__
@@ -88,9 +107,10 @@ def refuse_recurrent(model, what: str, *, positional_too: bool = True
             "or wrapped by moving a cursor")
     if positional_too:
         raise ValueError(
-            f"{what} is not available for {name}: its declared pages are "
-            "positional and could take it, but serving/state_cache.py has "
-            "no such program for declared pages yet")
+            f"{what} is not available for {name}: " + (instead or (
+                "its declared pages are positional and could take it, but "
+                "serving/state_cache.py has no such program for declared "
+                "pages yet")))
 
 
 def init_state_cache(model, n_slots: int, capacity: int):
@@ -115,6 +135,22 @@ def _apply(dm, params, cache, tokens, lengths, live, **at):
     return logits, upd["cache"], dict(upd.get("stats", {}))
 
 
+def _last(lengths):
+    return jnp.maximum(lengths - 1, 0)
+
+
+def _fresh_rows(cache, s):
+    """An ``s``-row zeroed copy of every declared leaf: a cohort's slab."""
+    return jax.tree_util.tree_map(
+        lambda page: jnp.zeros((s,) + page.shape[1:], page.dtype), cache)
+
+
+def _install_rows(cache, slab, sid):
+    """Every leaf's slab rows at ``sid`` on axis 0 (sentinel rows drop)."""
+    return jax.tree_util.tree_map(
+        lambda page, rows: page.at[sid].set(rows, mode="drop"), cache, slab)
+
+
 def state_decode_apply(dm, params, cache, tokens, live=None):
     """PURE one-token step: tokens ``[n]`` → (logits ``[n, vocab]``, cache,
     the model's counts). Rows that are not ``live`` keep their state and
@@ -136,15 +172,12 @@ def state_prefill_apply(dm, params, cache, tokens, lengths, slot_ids):
     n_slots = cache["idx"].shape[0]
     sid = jnp.asarray(slot_ids, jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
-    slab = jax.tree_util.tree_map(
-        lambda page: jnp.zeros((s,) + page.shape[1:], page.dtype), cache)
+    slab = _fresh_rows(cache, s)
     logits, slab, _ = _apply(dm, params, slab, tokens, lengths,
                              sid < n_slots)
     last = jnp.take_along_axis(
         logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
-    cache = jax.tree_util.tree_map(
-        lambda page, rows: page.at[sid].set(rows, mode="drop"), cache, slab)
-    return last, cache
+    return last, _install_rows(cache, slab, sid)
 
 
 def state_prefill_chunk_apply(dm, params, cache, tokens, starts, valid,
@@ -207,6 +240,124 @@ def state_decode_k_apply(dm, params, cache, tokens, keys, temps, top_ks,
     return toks.T, last, keys, cache, stats
 
 
+def state_prefill_draft_apply(dm, params, cache, tokens, lengths, slot_ids,
+                              keys, temps, top_ks):
+    """:func:`state_prefill_apply` for a self-drafting model, the first
+    token's sampling included (the module needs it): the main layers run
+    the cohort, the first token ``t_L`` is sampled from the last real
+    position with the slot's key, and the MTP module runs over the whole
+    prompt — inputs ``h_i`` and ``t_{i+1}``, the sampled token at ``L - 1``
+    — so that its page holds rows ``0 .. L-1`` before the first round, and
+    leaves the slot's first draft (of ``t_{L+1}``, sampled with the shadow
+    of the key the target will use there). Returns (first tokens ``[S]``,
+    keys, cache)."""
+    s, l = tokens.shape
+    n_slots = cache["idx"].shape[0]
+    sid = jnp.asarray(slot_ids, jnp.int32)
+    gid = jnp.clip(sid, 0, n_slots - 1)
+    lengths = jnp.asarray(lengths, jnp.int32)
+    real = sid < n_slots
+    slab = _fresh_rows(cache, s)
+    (last, hidden), slab, _ = _apply(dm, params, slab, tokens, lengths, real,
+                                     return_hidden=True, at=_last(lengths))
+    tok, newk = sample_tokens(last, keys[gid], temps[gid], top_ks[gid])
+    # sentinel rows (sid == n_slots) drop out of the key scatter
+    keys = keys.at[sid].set(newk, mode="drop")
+    nxt = jnp.concatenate([tokens[:, 1:], jnp.zeros((s, 1), jnp.int32)], 1)
+    nxt = jnp.where(jnp.arange(l)[None] == _last(lengths)[:, None],
+                    tok[:, None], nxt)
+    dlast, slab, _ = _apply(dm, params, slab, nxt, lengths, real,
+                            hidden=hidden, pos_offset=jnp.zeros((s,),
+                                                                jnp.int32),
+                            at=_last(lengths))
+    draft, _ = sample_tokens(dlast, draft_shadow_keys(newk), temps[gid],
+                             top_ks[gid])
+    return tok, keys, _install_rows(cache, {**slab, "draft": draft}, sid)
+
+
+def state_self_draft_k_apply(dm, params, cache, tokens, keys, temps, top_ks,
+                             eos_ids, remaining, live, park, k):
+    """``k`` self-drafted ROUNDS under one scan, no host pull between them.
+    A live slot holds ``cur`` (emitted, not yet in a page; ``tokens``) and
+    ``draft`` (the module's guess of the token after it; the cache's
+    ``draft`` leaf). One round:
+
+    1. the main layers over ``[cur, draft]`` at the slot's cursor ``c`` and
+       ``c + 1`` (two absorbed queries against one read of each page) —
+       logits ``L_0, L_1`` and hidden states ``h_c, h_{c+1}``;
+    2. the acceptance scan (``sampling.acceptance_scan``): ``s_0`` from
+       ``L_0`` with the row's REAL key; ``s_0 == draft`` emits ``draft`` and
+       the bonus ``s_1``, cursor ``+ 2``; else ``s_0`` alone, cursor ``+ 1``
+       — row ``c + 1`` of every main page then holds a rejected draft's
+       latent AT the new fill, which the next round's write covers before
+       any mask reads it. EOS and the budget stop a row mid-round as in
+       ``decode_k``;
+    3. the MTP module over the ``m`` positions just accepted (``h_c`` with
+       ``t_{c+1}``, then ``h_{c+1}`` with ``t_{c+2}``), so that its page
+       holds every accepted position, and the next ``draft`` sampled from
+       its last logits with the SHADOW of the row's key.
+
+    What is emitted is sampled by the target from the logits plain decode
+    would compute, with plain decode's keys: streams are bitwise those of
+    ``state_decode_k_apply``. Returns (tokens ``[n, 2k]``, round-major, -1
+    where a round's place stayed empty; the main logits of each row's last
+    emitted token; keys; cache; the model's counts and the rounds' —
+    ``drafts_verified``, ``drafts_accepted``, ``tokens_emitted``,
+    ``rounds`` — summed over the rounds; the module's logits of each row's
+    last draft)."""
+    tokens = jnp.asarray(tokens, jnp.int32)
+    live = jnp.asarray(live, bool)
+    remaining = jnp.asarray(remaining, jnp.int32)
+    eos_ids = jnp.asarray(eos_ids, jnp.int32)
+    temps = jnp.asarray(temps, jnp.float32)
+    top_ks = jnp.asarray(top_ks, jnp.int32)
+    n = tokens.shape[0]
+    cache = {**cache, "idx": jnp.where(live, cache["idx"],
+                                       jnp.asarray(park, jnp.int32))}
+    zeros = jnp.zeros((n, dm.vocab), jnp.float32)
+    two = jnp.full((n,), 2, jnp.int32)
+    rows = jnp.arange(n)
+
+    def body(carry, _):
+        cache, tok, keys, rem, alive, last, dlast = carry
+        start, draft = cache["idx"], cache["draft"]
+        (logits, hidden), cache, stats = _apply(
+            dm, params, cache, jnp.stack([tok, draft], 1), two, alive,
+            absorbed=True, return_hidden=True)
+        with jax.named_scope("mtp_accept"):
+            out, keys, rem2, alive2, m = acceptance_scan(
+                jnp.moveaxis(logits, 1, 0), draft[None], keys, temps, top_ks,
+                eos_ids, rem, alive)
+            # a draft is verified where the first token left the row alive
+            verified = alive & ~(((out[:, 0] == eos_ids) & (eos_ids >= 0))
+                                 | (rem <= 1))
+            at = _last(m)
+            tok = jnp.where(alive, out[rows, at], tok)
+            last = jnp.where(alive[:, None], logits[rows, at], last)
+        cache = {**cache, "idx": start + m}
+        dlogits, cache, mstats = _apply(
+            dm, params, cache, jnp.maximum(out, 0), m, alive, hidden=hidden,
+            pos_offset=start, absorbed=True, at=at)
+        with jax.named_scope("mtp_draft"):
+            nd, _ = sample_tokens(dlogits, draft_shadow_keys(keys), temps,
+                                  top_ks)
+        cache = {**cache, "draft": jnp.where(alive, nd, draft)}
+        dlast = jnp.where(alive[:, None], dlogits, dlast)
+        count = lambda a: a.sum(dtype=jnp.int32)
+        stats = {**stats, **mstats, "drafts_verified": count(verified),
+                 "drafts_accepted": count(verified & (m == 2)),
+                 "tokens_emitted": count(m), "rounds": jnp.int32(1)}
+        return (cache, tok, keys, rem2, alive2, last, dlast), (out, stats)
+
+    (cache, _, keys, _, _, last, dlast), (toks, stats) = jax.lax.scan(
+        body, (cache, tokens, keys, remaining, live, zeros, zeros), None,
+        length=k)
+    stats = jax.tree_util.tree_map(lambda a: a.sum(0), stats)
+    # [k, n, 2] -> [n, 2k], round-major
+    return (jnp.moveaxis(toks, 0, 1).reshape(n, 2 * k), last, keys, cache,
+            stats, dlast)
+
+
 class StateServingStep(ServingStep):
     """:class:`ServingStep` for a model that declares its cache: the same
     entry points, jit caches, trace counters, donation and placement; the
@@ -216,9 +367,27 @@ class StateServingStep(ServingStep):
     _decode_k_extra = 1     # the model's counts, summed over the steps
 
     def __init__(self, model, params, n_slots, capacity, *, kv_dtype=None,
-                 **kw):
+                 self_draft=False, **kw):
         if kv_dtype not in (None, "f32"):
             refuse_recurrent(model, f"kv_dtype={kv_dtype!r}")
+        #: ONE decode program, chosen here: ``decode_k`` runs self-drafted
+        #: rounds (two positions a slot) instead of one-token steps
+        self.self_draft = bool(self_draft)
+        if self.self_draft:
+            refuse_recurrent(model, "self-drafting (rewind on reject)",
+                             positional_too=False)
+            # the model says whether it can, and why not: the step knows
+            # nothing of modules or of how a page is read
+            refusal = getattr(model, "self_draft_refusal",
+                              lambda: "it declares no such rounds")()
+            if refusal:
+                raise ValueError(
+                    f"{type(model).__name__} cannot self-draft: {refusal}")
+
+            self._decode_k_extra = 2    # and the last draft's logits
+        #: the module's logits of each slot's last draft (device, like
+        #: ``last_decode_logits``); None unless self-drafting
+        self.last_draft_logits = None
         super().__init__(model, params, n_slots, capacity, **kw)
 
     @property
@@ -244,8 +413,23 @@ class StateServingStep(ServingStep):
         return state_prefill_apply(self.dm, params, cache, tokens, lengths,
                                    slot_ids)
 
+    def _prefill_sampled_program(self, params, cache, tokens, lengths,
+                                 slot_ids, keys, temps, top_ks):
+        if not self.self_draft:
+            return super()._prefill_sampled_program(
+                params, cache, tokens, lengths, slot_ids, keys, temps, top_ks)
+        return state_prefill_draft_apply(self.dm, params, cache, tokens,
+                                         lengths, slot_ids, keys, temps,
+                                         top_ks)
+
     def _decode_k_program(self, params, cache, *args):
+        if self.self_draft:
+            return state_self_draft_k_apply(self.dm, params, cache, *args)
         return state_decode_k_apply(self.dm, params, cache, *args)
+
+    def _keep_decode_extra(self, stats, draft_logits=None):
+        self.last_decode_stats = stats
+        self.last_draft_logits = draft_logits
 
     def _shardings(self, mesh, axis):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -267,6 +451,10 @@ class StateServingStep(ServingStep):
     def prefill_chunk(self, *args, **kw):
         if self._recurrent:
             refuse_recurrent(self.model, "chunked prefill")
+        if self.self_draft:
+            raise ValueError(
+                "chunked prefill is not written for a self-drafting step: "
+                "the module runs over the prompt in the bucketed prefill")
         return super().prefill_chunk(*args, **kw)
 
     def _export_rows(self, slot, fill):

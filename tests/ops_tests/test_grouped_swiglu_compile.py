@@ -6,6 +6,8 @@ library, and a second file could go to a worker that cannot.
 - The grouped expert product (d 2560, expert width 768, 128 held experts,
   bf16), both tile classes: the three double-buffered 3.9 MB weight blocks
   must fit the kernel's VMEM limit and the row tiles the chip's tiling.
+- The same product at deepseek-v3's widths (d 7168, expert width 2048, 16
+  held experts): in chunks of the width, since no whole block fits.
 - The per-slot cache write (ops/page_write.py) at StarCoder2-3B's page and
   over the row shapes ``rows_are_whole_tiles`` admits: it compiles, under
   its own name, with the pages aliased and no copy of a page; and what the
@@ -55,6 +57,30 @@ def test_compiles_for_v5e_at_the_published_widths(one_chip, monkeypatch,
         sds((1,), jnp.int32), sds((128, 2560, 768), jnp.bfloat16),
         sds((128, 2560, 768), jnp.bfloat16),
         sds((128, 768, 2560), jnp.bfloat16)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert ("grouped_swiglu_narrow" if tile == gs.NARROW_TILE
+            else "grouped_swiglu_wide") in text
+
+
+@pytest.mark.parametrize("tile,rows", [(gs.NARROW_TILE, 2048 + 16 * 15),
+                                       (gs.WIDE_TILE, 24576 + 16 * 127)])
+def test_a_wide_expert_compiles_for_v5e_in_chunks_of_its_width(
+        one_chip, monkeypatch, tile, rows):
+    """deepseek-v3's share: d 7168, expert width 2048, 16 held experts. One
+    28 MB block an operand would not fit VMEM even single-buffered; the
+    kernel takes 8 chunks of 256 columns (``width_block``) under the same
+    name, a float32 accumulator a row tile beside them."""
+    monkeypatch.setattr(gs, "on_tpu", lambda: True)     # Mosaic, not interpret
+    assert gs.width_block(7168, 2048, 2) == 256
+    rows = -(-rows // tile) * tile
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *a: gs.grouped_swiglu.__wrapped__(*a, tile=tile)).lower(
+        sds((rows, 7168), jnp.bfloat16), sds((rows // tile,), jnp.int32),
+        sds((1,), jnp.int32), sds((16, 7168, 2048), jnp.bfloat16),
+        sds((16, 7168, 2048), jnp.bfloat16),
+        sds((16, 2048, 7168), jnp.bfloat16)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     assert ("grouped_swiglu_narrow" if tile == gs.NARROW_TILE

@@ -353,9 +353,11 @@ def test_the_chunk_span_names_the_attention_its_program_traced(
 
 @pytest.mark.parametrize("what", ["speculative", "int8-block", "ring wrap"])
 def test_a_positional_page_still_refuses_what_has_no_program(what):
-    """Rewind, per-column requantisation and wrap could be done to a latent
-    page by cursor; the programs are not written, and the refusal says so
-    (it does not blame a recurrent state the model has not got)."""
+    """Per-column requantisation and wrap could be done to a latent page by
+    cursor; the programs are not written, and the refusal says so (it does
+    not blame a recurrent state the model has not got). Speculation with a
+    SEPARATE draft model is refused by what serves such a model instead:
+    its own module, in the decode program."""
     model, params = latent_setup()
     cfg = EngineConfig(n_slots=2, capacity=64, buckets=(32, 64))
     with pytest.raises(ValueError) as err:
@@ -367,7 +369,8 @@ def test_a_positional_page_still_refuses_what_has_no_program(what):
             Engine(model, params, cfg).submit(np.arange(40),
                                               max_new_tokens=30)
     assert "recurrent state" not in str(err.value)
-    assert ("no such program" in str(err.value)
+    assert ("self_draft" in str(err.value) if what == "speculative" else
+            "no such program" in str(err.value)
             or "no ring wrap" in str(err.value))
 
 
