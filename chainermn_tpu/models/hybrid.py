@@ -90,9 +90,12 @@ __all__ = ["HybridLM", "HybridBlock", "HyperConnection", "KDAMixer",
 _HI = jax.lax.Precision.HIGHEST
 KDA_CHUNK = 64
 _SUB = 16        # sub-block inside a chunk: keeps every exponent <= 0
-#: page columns of one row a blocked decode step reads per loop iteration: one
-#: query a row makes a block cheap to score, so it is wide to keep the loop
-#: short
+#: page columns of one row the blocked decode step's ``jax.numpy`` LOOP reads
+#: per iteration (``latent_decode_attention`` where it keeps the loop; the
+#: kernel sizes its own tile, ``ops/latent_attention.py::
+#: decode_column_tile``): one query a row makes a block cheap to score, and
+#: an iteration pays the carried accumulators' row out and in whatever the
+#: block's width, so it is wide to keep the loop short
 DECODE_BLOCK = 4096
 
 
@@ -491,9 +494,32 @@ def latent_decode_attention(q_cat, page, pos, live, scale, r,
     ``q_cat`` are several queries' heads side by side, query-major, and
     "head" ``i`` belongs to the query at position ``pos[b] + offs[i]``: a
     few queries at consecutive positions against ONE read of the row's
-    blocks (the self-drafting round's two, ``serving/state_cache.py``)."""
+    blocks (the self-drafting round's two, ``serving/state_cache.py``).
+
+    Two forms, chosen here at trace time by what the call shows
+    (``ops/latent_attention.py::decode_kernel_refusal``; the choice is noted
+    for whoever traces the program, ``record_paths``): on a TPU, with a
+    lane-aligned page width and rank, query-heads in eights and a page that
+    one device holds, ONE Pallas kernel a call
+    (``latent_decode_fwd``), whose grid is (row, block of columns): the
+    row's running ``m``, ``l`` and ``acc`` stay in VMEM across its blocks,
+    the ``[heads, block]`` score tile never leaves it, the page is an input
+    read where it lies, and a block past the row's fill is neither copied
+    nor computed — the column tile from the page's capacity and ``H``
+    (``decode_column_tile``), ``block`` unused. Else the ``jax.numpy`` loop
+    below over blocks of ``min(block, capacity)`` columns, which pays every
+    visit the block out of the carried page, the row of the ``[B, H, r]``
+    accumulator out and in, and a ``[block, H]`` float32 score array
+    through HBM (PERF.md §6, PR 35). Same operands, roundings and mask in
+    both; rows that are not live get zeros in both."""
     b, h, w = q_cat.shape
     t = page.shape[1]
+    refusal = latent_attention.decode_kernel_refusal(q_cat, page, r)
+    latent_attention.note_path(
+        "kernel" if refusal is None else f"loop:{refusal}")
+    if refusal is None:
+        return latent_attention.latent_decode_fwd(
+            q_cat, page, pos, live, scale, r, offs=offs), page
     block = min(block, t)
     if offs is None:
         n_of = jnp.where(live, pos // block + 1, 0)     # blocks a row reads

@@ -28,15 +28,26 @@ computed (its rows come back zero). The arithmetic is the loop's: operands
 in the page's dtype, float32 accumulation, float32 ``exp``, probabilities
 rounded to the page's dtype before the value product.
 
-:func:`chunk_kernel_refusal` is the dispatcher's rule: which calls the kernel
-can serve, by what it can see at trace time. :func:`record_paths` lets the
-caller that traces a program learn which path each chunk call took.
+The decode step's attention over the same page is the ABSORBED form: the
+queries already carry ``W_kvb``'s key half, so a column is scored and
+weighted as it lies, no expansion (``models/hybrid.py::
+latent_decode_attention``). :func:`latent_decode_fwd` is its kernel: one grid
+step takes ONE row's block of columns against all the row's query-heads
+(one query's, or a few consecutive positions' side by side), the running
+softmax of the row in VMEM scratch across its blocks, the blocks past the
+row's fill neither copied nor computed.
+
+:func:`chunk_kernel_refusal` and :func:`decode_kernel_refusal` are the
+dispatchers' rules: which calls a kernel can serve, by what it can see at
+trace time. :func:`record_paths` lets the caller that traces a program learn
+which path each call took.
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import math
 import threading
 from typing import List, Optional
 
@@ -49,7 +60,8 @@ from chainermn_tpu.ops.flash_attention import _fit_block
 from chainermn_tpu.ops.page_write import pages_are_partitioned
 from chainermn_tpu.utils import on_tpu
 
-__all__ = ["latent_chunk_fwd", "chunk_kernel_refusal", "record_paths",
+__all__ = ["latent_chunk_fwd", "chunk_kernel_refusal", "latent_decode_fwd",
+           "decode_kernel_refusal", "decode_column_tile", "record_paths",
            "note_path", "COLUMN_TILE", "QUERY_TILE"]
 
 LANES = 128
@@ -60,6 +72,19 @@ LANES = 128
 #: makes 1,024 columns half again as fast as 512, and 2,048 no faster
 COLUMN_TILE = 1024
 QUERY_TILE = 1024
+#: what fixes the decode kernel's column tile (decode_column_tile). A row
+#: pays every grid step a fixed cost whatever the block's width and, past
+#: its fill, half a block in the mean: the width that balances them grows
+#: with the root of the capacity over a column's cost, which is the MXU's
+#: under many query-heads (2,176 flop a head at 197 TFLOP/s) and the page's
+#: 1,280 B at 819 GB/s under few. The step's cost is fitted to a v5e at the
+#: two shapes served (PERF.md §6, PR 35; ms a call): 256 query-heads over
+#: 3,328 columns 1.17 / 1.13 / 1.10 / 1.15 / 1.22 at 256 / 384 / 512 / 768
+#: / 1,024 columns; 32 over 33,024 columns 0.55 / 0.47 / 0.49 / 0.53 / 0.59
+#: at 512 / 1,024 / 2,048 / 4,096 / 8,192: the rule gives 512 and 2,048
+DECODE_STEP_NS = 220.0
+DECODE_HEAD_COLUMN_NS = 2176 / 197e3
+DECODE_COLUMN_NS = 1280 / 819.0
 _NEG = -1e30     # finite stand-in for -inf: exp(_NEG - m) is exactly 0
 # q (two blocks), page block, weight block and the float32 output block,
 # each double-buffered, the three float32 accumulators over the chunk and a
@@ -109,6 +134,12 @@ def chunk_kernel_refusal(q_nope, q_rope, page, w_kvb) -> Optional[str]:
             q_nope.dtype == q_rope.dtype == w_kvb.dtype == page.dtype):
         return (f"page {page.dtype}, queries {q_nope.dtype}/{q_rope.dtype}, "
                 f"weights {w_kvb.dtype}: not one of bfloat16, float32")
+    return _placement_refusal()
+
+
+def _placement_refusal() -> Optional[str]:
+    """What both kernels ask of where the program runs: the page read where
+    it lies by one device, on a TPU."""
     if pages_are_partitioned():
         return "pages split over several devices"
     if not on_tpu():
@@ -242,3 +273,134 @@ def latent_chunk_fwd(q_nope, q_rope, page, w_kvb, pos, valid, slots, scale,
       q_nope.reshape(b, c, h * dn), q_rope.reshape(b, c, h * wr), page,
       w_kvb.reshape(r, h * (dn + dv)))
     return out.reshape(b, c, h, dv)
+
+
+def decode_kernel_refusal(q_cat, page, r) -> Optional[str]:
+    """Why :func:`latent_decode_fwd` cannot serve this call, or ``None`` if
+    it can: by :func:`chunk_kernel_refusal`'s rules."""
+    hq, width = q_cat.shape[1], page.shape[-1]
+    if r % LANES or width % LANES:
+        return (f"kv_rank {r}, page width {width} are not both multiples of "
+                f"{LANES}")
+    if q_cat.shape[-1] != width:
+        return f"queries {q_cat.shape[-1]} wide, the page {width}"
+    if hq % 8:
+        return f"{hq} query-heads a row are no multiple of 8"
+    if page.dtype not in (jnp.bfloat16, jnp.float32) or (
+            q_cat.dtype != page.dtype):
+        return (f"page {page.dtype}, queries {q_cat.dtype}: not one of "
+                "bfloat16, float32")
+    return _placement_refusal()
+
+
+def decode_column_tile(t: int, hq: int) -> int:
+    """Page columns one grid step of :func:`latent_decode_fwd` scores, from
+    what the call shows — the page's capacity ``t`` and the query-heads a
+    row ``hq`` —, in whole multiples of 256 and no more than the page has
+    (the reasoning and the chip's readings are beside ``DECODE_STEP_NS``)."""
+    column_ns = max(hq * DECODE_HEAD_COLUMN_NS, DECODE_COLUMN_NS)
+    bk = max(round(math.sqrt(t * DECODE_STEP_NS / column_ns) / 256), 1) * 256
+    return bk if bk < t else t
+
+
+def _decode_kernel(nblk_ref, pos_ref, q_ref, page_ref, o_ref, acc, mrow, lrow,
+                   *, scale, r, bk, t, offs):
+    b, j = pl.program_id(0), pl.program_id(1)
+    hq = q_ref.shape[1]
+    pos = pos_ref[b]
+    col0 = j * bk
+
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        mrow[...] = jnp.full_like(mrow, _NEG)
+        lrow[...] = jnp.zeros_like(lrow)
+
+    def fold(masked):
+        blk = page_ref[0]
+        s = jax.lax.dot_general(q_ref[0], blk, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        v = blk[:, :r]
+        if masked:
+            # query-head i sees columns <= pos + offs[i], inside the page
+            head = jax.lax.broadcasted_iota(jnp.int32, (hq, 1), 0)
+            off = jnp.full((hq, 1), offs[0], jnp.int32)
+            for i in range(1, hq):
+                if offs[i] != offs[i - 1]:
+                    off = off + jnp.where(head >= i, offs[i] - offs[i - 1], 0)
+            col = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            s = jnp.where(col <= jnp.minimum(pos + off, t - 1), s, _NEG)
+            # what lies past the row's last seen column (in the page's last
+            # block: past the page's end) need be no number, and a
+            # probability of zero would not make it one
+            seen = col0 + jax.lax.broadcasted_iota(
+                jnp.int32, (bk, 1), 0) <= jnp.minimum(pos + max(offs), t - 1)
+            v = jnp.where(seen, v, jnp.zeros_like(v))
+        # block 0 holds column 0, which every query sees: ``m`` is finite
+        # from the row's first block on
+        m_prev = mrow[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        lrow[:, :1] = alpha * lrow[:, :1] + jnp.sum(p, -1, keepdims=True)
+        acc[...] = alpha * acc[...] + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        mrow[:, :1] = m_new
+
+    live = j < nblk_ref[b]
+    # no column of the block above any query of the row, or past the page
+    whole = (col0 + bk - 1 <= pos + min(offs)) & (col0 + bk <= t)
+    pl.when(live & whole)(functools.partial(fold, False))
+    pl.when(live & jnp.logical_not(whole))(functools.partial(fold, True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        l = lrow[:, :1]
+        o_ref[0] = acc[...] / jnp.where(l == 0.0, 1.0, l)
+
+
+def latent_decode_fwd(q_cat, page, pos, live, scale, r, *, offs=None,
+                      column_tile: Optional[int] = None):
+    """``q_cat [B, Hq, W]``: the absorbed queries of row ``b`` (the no-rope
+    query through ``W_kvb``'s key half beside the rotary query, padded to
+    the page's width), ``Hq`` query-heads that are one position's heads or,
+    with ``offs`` (a tuple of ``Hq`` ints), several positions' side by side:
+    query-head ``i`` sees columns ``<= pos[b] + offs[i]`` of row ``b`` of
+    ``page [N, T, W]``, whose first ``r`` values a column are the latent.
+    Shapes as :func:`decode_kernel_refusal` admits them. Returns ``[B, Hq,
+    r]`` float32, zeros for a row that is not ``live``. The page is read
+    only, a block of ``column_tile`` columns (:func:`decode_column_tile`) a
+    grid step, and of a row only the blocks that hold a column it sees."""
+    b, hq, width = q_cat.shape
+    t = page.shape[1]
+    offs = (0,) * hq if offs is None else tuple(int(o) for o in offs)
+    bk = decode_column_tile(t, hq) if column_tile is None else min(
+        column_tile, t)
+    nb = pl.cdiv(t, bk)
+    pos = jnp.asarray(pos, jnp.int32)
+    nblk = jnp.where(live, jnp.minimum((pos + max(offs)) // bk + 1, nb), 0)
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, scale=scale, r=r, bk=bk, t=t,
+                          offs=offs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, nb),
+            in_specs=[
+                pl.BlockSpec((1, hq, width), lambda b, j, *_: (b, 0, 0)),
+                # a block past the row's last needed one repeats it: the
+                # pipeline sees the index it holds and starts no DMA
+                pl.BlockSpec((1, bk, width), lambda b, j, nblk, pos: (
+                    b, jnp.minimum(j, jnp.maximum(nblk[b] - 1, 0)), 0)),
+            ],
+            out_specs=pl.BlockSpec((1, hq, r), lambda b, j, *_: (b, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((hq, r), jnp.float32),
+                            pltpu.VMEM((hq, LANES), jnp.float32),
+                            pltpu.VMEM((hq, LANES), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, hq, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=not on_tpu(),
+        name="latent_decode_fwd",
+    )(nblk, pos, q_cat, page)

@@ -781,6 +781,8 @@ class Engine:
             toks_dev, self._keys = self.steps.decode_k(
                 self.cur_tokens, self._keys, self._temps, self._topks,
                 self._eos, remaining, live, park, cfg.decode_k)
+            if enq and self.steps.decode_attention:
+                enq.set(decode_attention=self.steps.decode_attention)
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(toks_dev)         # [n, k] int32 — the ONLY
             #                                     per-token host transfer

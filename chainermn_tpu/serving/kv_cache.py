@@ -464,6 +464,12 @@ def decode_k_apply(model, params, cache, tokens, keys, temps, top_ks,
     return toks.T, last, keys, cache
 
 
+def _forms(paths) -> Optional[str]:
+    """The forms a traced program's latent attention calls took
+    (``record_paths``), as one string; None where it has no such call."""
+    return ",".join(sorted(set(paths))) or None
+
+
 class ServingStep:
     """The compiled prefill/decode pair, owning the paged cache.
 
@@ -503,6 +509,10 @@ class ServingStep:
         #: latent_chunk_attention); None before a chunk program exists and
         #: for a model that has no such call
         self.chunk_attention: Optional[str] = None
+        #: the same for the ``decode_k`` program's latent attention
+        #: (latent_decode_attention); None before such a program exists
+        #: and for a model that has no such call
+        self.decode_attention: Optional[str] = None
         self._prefill_jits: Dict[tuple, Any] = {}
         self._prefill_sampled_jits: Dict[tuple, Any] = {}
         self._prefill_chunk_jits: Dict[tuple, Any] = {}
@@ -719,10 +729,12 @@ class ServingStep:
             def _decode_k(params, cache, tokens, keys, temps, top_ks,
                           eos_ids, remaining, live, park, _k=kk):
                 self.decode_k_traces += 1   # trace-time only
-                with self._page_write():
-                    return self._decode_k_program(
+                with self._page_write(), record_paths() as paths:
+                    out = self._decode_k_program(
                         params, cache, tokens, keys, temps, top_ks,
                         eos_ids, remaining, live, park, _k)
+                self.decode_attention = _forms(paths)
+                return out
 
             kw = {}
             if self._mesh is not None:
@@ -803,7 +815,7 @@ class ServingStep:
                 with self._page_write(), record_paths() as paths:
                     last, cache = self._prefill_chunk_program(
                         params, cache, tokens, starts, valid, slot_ids)
-                self.chunk_attention = ",".join(sorted(set(paths))) or None
+                self.chunk_attention = _forms(paths)
                 sid = jnp.asarray(slot_ids, jnp.int32)
                 gid = jnp.clip(sid, 0, self.n_slots - 1)
                 tok, newk = sample_tokens(last, keys[gid], temps[gid],

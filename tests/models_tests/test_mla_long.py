@@ -6,8 +6,9 @@ against hand-computed values, prefill in chunks against prefill in one piece
 and against the reference's full forward — through the ``jax.numpy`` loop and,
 at lane-aligned widths, through the kernel (ops/latent_attention.py, in the
 Pallas interpreter) —, a decode step through the page at cursors 0, mid-block
-and capacity - 1, and that the chunk program holds no score array of chunk x
-capacity a head in either form."""
+and capacity - 1, that the chunk program holds no score array of chunk x
+capacity a head in either form, and a ``decode_k`` dispatch through the decode
+kernel against the same dispatch through the loop."""
 import contextlib
 import math
 import re
@@ -115,9 +116,10 @@ ALIGNED = dict(n_heads=2, d_head=128, d_nope=128, d_rope=64, kv_rank=128)
 
 @contextlib.contextmanager
 def kernel_path():
-    """Inside, ``latent_chunk_attention`` takes the kernel as it does on the
-    chip, and the kernel runs in the Pallas TPU interpreter. Yields the list
-    of paths the calls traced inside took."""
+    """Inside, ``latent_chunk_attention`` and ``latent_decode_attention``
+    take their kernels as they do on the chip, and the kernels run in the
+    Pallas TPU interpreter. Yields the list of paths the calls traced inside
+    took (a serving step that traces a program keeps its own)."""
     from jax.experimental.pallas import tpu as pltpu
 
     with pytest.MonkeyPatch.context() as mp, \
@@ -127,12 +129,13 @@ def kernel_path():
         yield paths
 
 
-def prefill(model, params, tokens, chunks):
-    """One slot of a 2-slot cache: the prompt in the given chunk sizes (a
-    single size: the one-piece program). Returns (logits after the last
-    piece, cache)."""
+def prefill(model, params, tokens, chunks, cache=None):
+    """One slot of a 2-slot cache (a fresh one unless handed in): the
+    prompt in the given chunk sizes (a single size: the one-piece program).
+    Returns (logits after the last piece, cache)."""
     dm = model.clone(decode=True, max_len=CAP)
-    cache = init_state_cache(model, 2, CAP)
+    if cache is None:
+        cache = init_state_cache(model, 2, CAP)
     if len(chunks) == 1:
         toks = np.zeros((1, chunks[0]), np.int32)
         toks[0, :tokens.size] = tokens
@@ -186,8 +189,11 @@ def test_chunked_prefill_through_the_kernel_equals_the_loop_and_one_piece(
     and the logits are those of the loop over one piece of 80."""
     model, params = setup(**ALIGNED)
     tokens = np.random.RandomState(5).randint(0, 256, (73,))
+    # the cache's shapes come off a trace of the model's one-token call,
+    # whose decode attention would note its path too: outside the scope
+    cache = init_state_cache(model, 2, CAP)
     with kernel_path() as paths:
-        got, cache = prefill(model, params, tokens, chunks)
+        got, cache = prefill(model, params, tokens, chunks, cache)
     assert paths == ["kernel"] * 3      # one trace serves every chunk
     one, cache1 = prefill(model, params, tokens, (80,))
     assert cache["idx"].tolist() == cache1["idx"].tolist() == [0, 73]
@@ -210,8 +216,9 @@ def test_the_same_chunks_through_the_loop_off_the_chip():
     calls take the loop, name why, and give the same logits."""
     model, params = setup(**ALIGNED)
     tokens = np.random.RandomState(5).randint(0, 256, (73,))
+    cache = init_state_cache(model, 2, CAP)
     with latent_attention.record_paths() as paths:
-        got, _ = prefill(model, params, tokens, (32, 32, 9))
+        got, _ = prefill(model, params, tokens, (32, 32, 9), cache)
     assert paths == ["loop:not on a TPU"] * 3
     one, _ = prefill(model, params, tokens, (80,))
     np.testing.assert_allclose(got, one, rtol=1e-4, atol=1e-4)
@@ -308,3 +315,81 @@ def test_chunk_program_holds_no_score_array_over_the_page(form):
         tile = min(latent_attention.COLUMN_TILE, cap)
         assert not [s for s in shapes_seen
                     if h in s and c in s and tile in s]
+
+
+#: eight heads: the decode kernel takes query-heads in eights
+DECODE_ALIGNED = dict(ALIGNED, n_heads=8)
+
+
+def served(model, params, n_new=9):
+    """Two prompts through a 3-slot engine, ``decode_k`` 4, one greedy and
+    one sampled: (streams, the last dispatch's logits, the step)."""
+    from chainermn_tpu.serving import Engine, EngineConfig
+
+    eng = Engine(model, params, EngineConfig(
+        n_slots=3, capacity=CAP, buckets=(32, CAP), decode_k=4,
+        prefill_cohort=2))
+    rs = np.random.RandomState(8)
+    reqs = [eng.submit(rs.randint(0, 256, (n,)).astype(np.int32),
+                       max_new_tokens=n_new, temperature=temp, seed=3)
+            for n, temp in ((21, None), (30, 1.0))]
+    eng.run_until_drained()
+    return ([list(r.tokens) for r in reqs], np.asarray(eng.last_logits),
+            eng.steps)
+
+
+def test_a_decode_k_dispatch_through_the_kernel_gives_the_loops_tokens():
+    """Three latent layers, one query a row, a free slot beside the two
+    live ones: the kernel path's streams are the loop path's and the last
+    dispatch's logits agree within a decode step's tolerance."""
+    model, params = setup(**DECODE_ALIGNED)
+    with kernel_path():
+        got, logits, steps = served(model, params)
+    assert steps.decode_attention == "kernel" and steps.decode_k_traces == 1
+    want, want_logits, steps = served(model, params)
+    assert steps.decode_attention == "loop:not on a TPU"
+    assert got == want and all(len(t) == 9 for t in got)
+    np.testing.assert_allclose(logits[:2], want_logits[:2], rtol=5e-4,
+                               atol=5e-4)
+
+
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+def test_decode_program_holds_no_copy_of_the_page_and_no_score_array(form):
+    """A mid size: 8 heads, 16 slots, pages of 4,096 columns (16 x 4,096 x
+    256 x 4 B = 64 MB a layer), one decode step with the pages donated,
+    both latent layers through the form asked for. The loop's program keeps
+    its temporaries under one page — the page is written and read where it
+    lies — and holds the ``[block, heads]`` float32 score array of a visit.
+    The kernel's body is interpreted here: the interpreter stages the page
+    in a buffer of its own and its score tile is an array like any other,
+    so that the kernel's program holds neither in HBM is for
+    tests/ops_tests/test_grouped_swiglu_compile.py, which compiles the same
+    call for the chip at both cells' shapes; here it lowers, by the same
+    entry point, with both calls on the kernel."""
+    from chainermn_tpu.serving.state_cache import state_decode_apply
+
+    cap, h, n = 4096, 8, 16
+    model = HybridLM(**dict(SIZES, **DECODE_ALIGNED, max_len=cap,
+                            mla_block=128), pattern=PATTERN[:2])
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    dm = model.clone(decode=True, max_len=cap)
+    cache = jax.eval_shape(lambda: init_state_cache(model, n, cap))
+    with kernel_path() if form == "kernel" else \
+            latent_attention.record_paths() as paths:
+        compiled = jax.jit(
+            lambda p, ca, t, live: state_decode_apply(dm, p, ca, t, live),
+            donate_argnums=(1,)).lower(
+            shapes, cache, jax.ShapeDtypeStruct((n,), jnp.int32),
+            jax.ShapeDtypeStruct((n,), jnp.bool_)).compile()
+    assert len(paths) == 2 and all(
+        p == "kernel" if form == "kernel" else p.startswith("loop:")
+        for p in paths)
+    if form == "kernel":
+        return
+    page_bytes = n * cap * 256 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < page_bytes
+    shapes_seen = {tuple(int(d) for d in dims.split(","))
+                   for dims in re.findall(r"f32\[([\d,]+)\]",
+                                          compiled.as_text())}
+    assert [s for s in shapes_seen if s == (cap, h)]    # a visit's scores

@@ -16,7 +16,12 @@ library, and a second file could go to a worker that cannot.
   ``xing4-serve-longdoc``'s shapes, behind the page write as the mixer calls
   it: one custom call under its own name, the donated page updated in place
   and read where it lies (no copy of it), and no float32 score array among
-  the program's HBM temporaries."""
+  the program's HBM temporaries.
+- The decode step's absorbed attention (the same file's
+  ``latent_decode_fwd``) at both latent cells' shapes, behind the scatter
+  that writes the round's latents: one custom call under its own name, the
+  donated page in place, no copy of it and no ``[block, heads]`` float32
+  score array in HBM."""
 import re
 
 import numpy as np
@@ -176,3 +181,49 @@ def test_latent_chunk_attention_compiles_in_place_for_v5e(one_chip,
     # beside the page, no float32 array is larger than the result
     assert max(int(np.prod(s)) for s in f32
                if s != (n, t, w)) == b * c * h * 128
+
+
+@pytest.mark.parametrize("cell", ["dsv3-two-queries", "xing4-one-query"])
+def test_latent_decode_attention_compiles_in_place_for_v5e(one_chip,
+                                                           monkeypatch, cell):
+    """``dsv3-serve-mtp-reasongen``: 128 slots of 3,328 columns (6.5 tiles
+    of 512), two positions a slot side by side, 256 query-heads;
+    ``xing4-serve-longdoc``: 12 slots of 33,024 columns (8 tiles of 4,096
+    and 256 columns more), one position, 32 query-heads. The step's latents
+    scattered into the donated page, then the queries attend it, as
+    ``MLAMixer`` does under ``mla_absorbed``."""
+    monkeypatch.setattr(la, "on_tpu", lambda: True)     # Mosaic, not interpret
+    n, t, l, h = (128, 3328, 2, 128) if cell.startswith("dsv3") else (
+        12, 33024, 1, 32)
+    w, r, dtype = 640, 512, jnp.bfloat16
+    offs = tuple(j for j in range(l) for _ in range(h)) if l > 1 else None
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q_cat, page, ckv, pos, live):
+        page = page.at[jnp.arange(n)[:, None],
+                       pos[:, None] + jnp.arange(l)[None]].set(
+                           ckv, mode="drop")
+        return hybrid.latent_decode_attention(q_cat, page, pos, live, 0.1147,
+                                              r, offs=offs)
+
+    with la.record_paths() as paths:
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            sds((n, l * h, w), dtype), sds((n, t, w), dtype),
+            sds((n, l, w), dtype), sds((n,), jnp.int32),
+            sds((n,), jnp.bool_)).compile()
+    assert paths == ["kernel"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "latent_decode_fwd" in text
+    mem = compiled.memory_analysis()
+    page_bytes = n * t * w * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= page_bytes        # the page in place
+    assert mem.temp_size_in_bytes < page_bytes // 16    # and no copy of it
+    # what a visit's score array would be: float32, the query-heads beside
+    # a block of columns (the loop's, the kernel's tile) or the capacity
+    tile = la.decode_column_tile(t, l * h)
+    f32 = {tuple(int(d) for d in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    assert not [s for s in f32 if len(s) == 2 and l * h in s and (
+        tile in s or min(hybrid.DECODE_BLOCK, t) in s or t in s)]
+    # no float32 array is larger than the result
+    assert max(int(np.prod(s)) for s in f32) == n * l * h * r

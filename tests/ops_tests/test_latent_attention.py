@@ -3,8 +3,10 @@ Pallas interpreter at small lane-aligned widths: equal to the ``jax.numpy``
 loop it replaces (models/hybrid.py::latent_chunk_attention) and to a one-piece
 softmax over the expanded keys and values, at every place a cursor can stand;
 the page comes back byte for byte; and the dispatcher's rule, reason by reason.
-tests/ops_tests/test_grouped_swiglu_compile.py compiles the kernel for the
-chip at the benchmark's widths."""
+Then the same for the decode step's absorbed attention (``latent_decode_fwd``
+against ``latent_decode_attention``'s loop and a one-piece softmax over the
+page's columns as they lie). tests/ops_tests/test_grouped_swiglu_compile.py
+compiles both kernels for the chip at the benchmark's widths."""
 import numpy as np
 import pytest
 
@@ -248,3 +250,205 @@ def test_paths_are_recorded_only_inside_a_scope_and_scopes_nest():
         call()
     assert len(inner) == 2 and len(outer) == 2
     assert set(inner + outer) == {"loop:not on a TPU"}
+
+
+# ---------------------------------------------------------------------------
+# the decode step's absorbed attention
+# ---------------------------------------------------------------------------
+
+def draw_decode(dtype, hq, t, b=3, seed=0, width=WIDTH):
+    rs = np.random.RandomState(seed)
+    f = lambda *s: jnp.asarray(rs.randn(*s), jnp.float32).astype(dtype)
+    return f(b, hq, width), f(b, t, width)
+
+
+def decode_loop(q, page, pos, live, offs, block=32):
+    """The ``jax.numpy`` loop: what ``latent_decode_attention`` runs off
+    the chip (these tests run there)."""
+    assert la.decode_kernel_refusal(q, page, R) == "not on a TPU"
+    return np.asarray(hybrid.latent_decode_attention(
+        q, page, jnp.asarray(pos), jnp.asarray(live), SCALE, R, block=block,
+        offs=offs)[0])
+
+
+def decode_one_piece(q, page, pos, offs, b):
+    """Row ``b``: a softmax over every column each query-head sees,
+    float64."""
+    g = lambda x: np.asarray(x.astype(jnp.float32), np.float64)
+    row = g(page)[b]
+    s = g(q)[b] @ row.T * SCALE
+    last = pos[b] + np.asarray(offs or (0,) * q.shape[1])
+    s = np.where(np.arange(row.shape[0])[None] <= last[:, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    return (p / p.sum(-1, keepdims=True)) @ row[:, :R]
+
+
+def two_queries(hq):
+    return tuple(j for j in range(2) for _ in range(hq // 2))
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-6),
+                                       (jnp.bfloat16, 2e-3)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("t", [T, 96], ids=["capacity-80", "capacity-96"])
+@pytest.mark.parametrize("hq", [32, 256])
+@pytest.mark.parametrize("queries", [1, 2])
+@pytest.mark.parametrize("rows", ["all-live", "one-dead"])
+def test_decode_kernel_equals_the_loop_and_the_one_piece_softmax(
+        rows, queries, hq, t, dtype, tol):
+    """One query a row and two side by side (``offs``), 32 and 256
+    query-heads, a capacity that is (96) and is not (80) a multiple of the
+    32-column tile: same operands and roundings as the loop, another order
+    of additions. Rows at cursor 0, inside a block and on the page's last
+    column (a second query there would sit past the page: it sees the whole
+    page and no more), then a block's last and next first column beside a
+    row that is not live, which comes back zero."""
+    offs = two_queries(hq) if queries == 2 else None
+    q, page = draw_decode(dtype, hq, t, seed=hq + t)
+    pos, live = (([0, 37, t - 1], [True, True, True]) if rows == "all-live"
+                 else ([31, 5, 32], [True, False, True]))
+    got = np.asarray(la.latent_decode_fwd(
+        q, page, jnp.asarray(pos), jnp.asarray(live), SCALE, R, offs=offs,
+        column_tile=32))
+    want = decode_loop(q, page, pos, live, offs)
+    assert got.shape == want.shape == (3, hq, R)
+    assert got.dtype == np.float32 and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    for b, alive in enumerate(live):
+        if not alive:
+            assert not got[b].any()
+        elif dtype == jnp.float32:
+            np.testing.assert_allclose(
+                got[b], decode_one_piece(q, page, pos, offs, b),
+                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("column_tile", [16, 32, 48, 512, None])
+def test_any_decode_column_tile_gives_the_same_numbers(column_tile):
+    """Tiles that divide the page, that leave a partial last block, that are
+    wider than the page (one block: the page itself) and the one the call
+    picks for itself."""
+    q, page = draw_decode(jnp.float32, 16, T, seed=3)
+    pos, live = [35, 7, T - 2], [True, True, True]
+    offs = two_queries(16)
+    got = np.asarray(la.latent_decode_fwd(
+        q, page, jnp.asarray(pos), jnp.asarray(live), SCALE, R, offs=offs,
+        column_tile=column_tile))
+    np.testing.assert_allclose(got, decode_loop(q, page, pos, live, offs),
+                               rtol=2e-6, atol=2e-6)
+
+
+def test_the_decode_tile_follows_the_capacity_and_the_query_heads():
+    """What the benchmark's two latent cells show the call: 256 query-heads
+    over 3,328 columns take the tile the chip measured best there, 32 over
+    33,024 theirs; a longer page or fewer heads widen it, and a page
+    shorter than a tile is one block."""
+    assert la.decode_column_tile(3328, 256) == 512
+    assert la.decode_column_tile(33024, 32) == 2048
+    assert la.decode_column_tile(3328, 32) == 768
+    assert la.decode_column_tile(33024, 256) == 1536
+    assert la.decode_column_tile(96, 32) == 96
+    assert la.decode_column_tile(3328, 4096) == 256
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_decode_kernel_never_reads_past_a_fill_the_page_or_into_a_dead_row(
+        dtype):
+    """NaN past every row's last seen column (inside its last block and in
+    the blocks after it), past the page's end (the interpreter pads a
+    partial block with NaN) and all over a row that is not live: none
+    reaches the result. The kernel's twin of tests/models_tests/
+    test_mla_long.py::test_decode_reads_the_blocks_the_live_rows_have_filled.
+    """
+    q, clean = draw_decode(dtype, 16, T, seed=5)
+    offs = two_queries(16)
+    pos, live = [5, 40, T - 1], [True, False, True]
+    page = np.array(clean.astype(jnp.float32))
+    page[0, 5 + 2:] = np.nan        # row 0 sees 0..6 (its second query: 6)
+    page[1] = np.nan                # row 1 is not live
+    got = np.asarray(la.latent_decode_fwd(
+        q, jnp.asarray(page).astype(dtype), jnp.asarray(pos),
+        jnp.asarray(live), SCALE, R, offs=offs, column_tile=32))
+    assert np.isfinite(got).all() and not got[1].any()
+    tol = 2e-6 if dtype == jnp.float32 else 2e-3
+    np.testing.assert_allclose(got, decode_loop(q, clean, pos, live, offs),
+                               rtol=tol, atol=tol)
+
+
+DECODE_REFUSALS = {
+    "a-576-wide-page": (dict(width=576), 128, "page width 576"),
+    "a-rank-of-96": (dict(), 96, "kv_rank 96"),
+    "12-query-heads": (dict(hq=12), 128, "12 query-heads"),
+}
+
+
+@pytest.mark.parametrize("case", list(DECODE_REFUSALS))
+def test_the_decode_dispatcher_keeps_the_loop_and_names_the_reason(
+        case, monkeypatch):
+    monkeypatch.setattr(la, "on_tpu", lambda: True)
+    over, r, reason = DECODE_REFUSALS[case]
+    q, page = draw_decode(jnp.bfloat16, **{"hq": 16, "t": T, **over})
+    assert reason in la.decode_kernel_refusal(q, page, r)
+    with la.record_paths() as paths:
+        o, _ = hybrid.latent_decode_attention(
+            q, page, jnp.asarray([3, 0, 70]), jnp.ones((3,), bool), SCALE, r,
+            block=16)
+    assert paths == [f"loop:{la.decode_kernel_refusal(q, page, r)}"]
+    assert np.isfinite(np.asarray(o)).all()
+
+
+@pytest.mark.parametrize("what", ["queries-narrower", "dtypes-differ",
+                                  "an-int8-page", "several-devices",
+                                  "off-the-chip"])
+def test_the_decode_dispatcher_refuses_for_cause(what, monkeypatch):
+    q, page = draw_decode(jnp.bfloat16, 16, T)
+    if what == "off-the-chip":
+        assert la.decode_kernel_refusal(q, page, R) == "not on a TPU"
+        return
+    monkeypatch.setattr(la, "on_tpu", lambda: True)
+    assert la.decode_kernel_refusal(q, page, R) is None
+    if what == "several-devices":
+        with partitioned_pages():
+            assert la.decode_kernel_refusal(q, page, R) == (
+                "pages split over several devices")
+    elif what == "queries-narrower":
+        assert "queries 192 wide, the page 256" in la.decode_kernel_refusal(
+            q[..., :192], page, R)
+    else:
+        if what == "dtypes-differ":
+            q = q.astype(jnp.float32)
+        else:
+            page = page.astype(jnp.int8)
+        assert "not one of bfloat16, float32" in la.decode_kernel_refusal(
+            q, page, R)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_the_decode_dispatcher_takes_the_kernel_and_hands_the_page_back(
+        dtype, monkeypatch):
+    """Through the dispatcher with the page donated, as the serving step
+    donates it: the kernel's numbers, and the page byte for byte."""
+    monkeypatch.setattr(la, "on_tpu", lambda: True)     # take the kernel...
+    q, page = draw_decode(dtype, 16, T, seed=7)
+    before = np.asarray(page).view(np.uint8).copy()
+    pos, live = [30, 4, 79], [True, True, False]
+
+    def call(q, page):
+        with la.record_paths() as paths:
+            out = hybrid.latent_decode_attention(
+                q, page, jnp.asarray(pos), jnp.asarray(live), SCALE, R)
+        assert paths == ["kernel"]
+        return out
+
+    from jax.experimental.pallas import tpu as pltpu
+    with pltpu.force_tpu_interpret_mode():              # ...interpreted
+        o, same = jax.jit(call, donate_argnums=(1,))(q, page)
+    assert same.dtype == dtype
+    np.testing.assert_array_equal(np.asarray(same).view(np.uint8), before)
+    monkeypatch.setattr(la, "on_tpu", lambda: False)
+    tol = 2e-6 if dtype == jnp.float32 else 2e-3
+    np.testing.assert_allclose(np.asarray(o),
+                               decode_loop(q, same, pos, live, None),
+                               rtol=tol, atol=tol)

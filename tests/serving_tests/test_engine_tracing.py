@@ -129,6 +129,45 @@ def test_a_program_without_latent_attention_names_none(traced):
     assert traced["engine"].steps.chunk_attention is None
 
 
+def test_a_decode_program_without_latent_attention_names_none(traced):
+    """The same for ``decode_attention`` on ``engine.decode.enqueue``."""
+    enq = [r for r in traced["rows"] if r.name == "engine.decode.enqueue"]
+    assert enq and not any("decode_attention" in r.attrs for r in enq)
+    if traced["kind"] != "speculative":     # whose round is not ``decode_k``
+        assert traced["engine"].steps.decode_k_traces == 1
+    assert traced["engine"].steps.decode_attention is None
+
+
+def test_the_decode_span_names_the_attention_its_program_traced(
+        profiler_session):
+    """A model whose decode step reads latent pages in blocks, at widths the
+    kernel serves: nothing before a decode program exists; from its trace on
+    every ``engine.decode.enqueue`` span says which form the program's
+    latent attention took — off the chip the ``jax.numpy`` loop, and why."""
+    from tests.models_tests.test_hyper_connections import setup
+    from tests.models_tests.test_mla_long import DECODE_ALIGNED
+
+    model, params = setup(**DECODE_ALIGNED)
+    eng = Engine(model, params, EngineConfig(
+        n_slots=2, capacity=96, buckets=(32, 96), decode_k=2,
+        prefill_cohort=2))
+    rs = np.random.RandomState(0)
+    for n in (9, 20):
+        eng.submit(rs.randint(0, 256, (n,)).astype(np.int32),
+                   max_new_tokens=5)
+    tracing.clear()
+    with profiler_session():
+        eng._admit(float("inf"))
+        assert eng.steps.decode_attention is None       # nothing traced yet
+        eng.run_until_drained()
+    rows = tracing.rows()
+    tracing.clear()
+    enq = [r for r in rows if r.name == "engine.decode.enqueue"]
+    assert len(enq) >= 2 and eng.steps.decode_k_traces == 1
+    assert eng.steps.decode_attention == "loop:not on a TPU"
+    assert {r.attrs["decode_attention"] for r in enq} == {"loop:not on a TPU"}
+
+
 def test_the_step_span_sees_the_queue_it_started_with(traced):
     its = _iterations(traced["rows"])
     first, last = its[0][0], its[-1][0]

@@ -317,6 +317,42 @@ def test_the_first_draft_comes_from_the_prefill(toy):
     assert np.abs(page[r.slot, r.prompt.size:]).max() == 0
 
 
+# -- the round through the decode kernel ---------------------------------------
+
+def test_a_self_drafted_dispatch_through_the_kernel_is_the_loops():
+    """At widths the decode kernel serves (lane tiles, eight heads: sixteen
+    query-heads a row, two positions side by side) every latent layer of the
+    round — the module's too — takes the kernel, interpreted here; the
+    streams, the last main logits and the module's last draft logits are
+    those of the same weights through the loop."""
+    from tests.models_tests.test_mla_long import kernel_path
+
+    model, params = setup(n_heads=8, d_head=128, d_nope=128, d_rope=64,
+                          kv_rank=128)
+
+    def run():
+        eng = engine(model, params, self_draft=True, decode_k=3)
+        reqs = [eng.submit(**kw) for kw in requests(1.0)[:3] + requests(
+            None)[3:5]]
+        for _ in range(4):
+            eng.step()  # dlint: disable=DL104
+        assert any(r.state == "running" for r in reqs)
+        return ([list(r.tokens) for r in reqs], np.asarray(eng.last_logits),
+                np.asarray(eng.steps.last_draft_logits), eng)
+
+    with kernel_path():
+        toks, main, draft, eng = run()
+    assert eng.steps.decode_attention == "kernel"
+    assert eng.steps.decode_k_traces == 1
+    s = eng.report.summary()
+    assert 0 < s["draft_tokens_accepted"] < s["draft_tokens_proposed"]
+    want_toks, want_main, want_draft, eng = run()
+    assert eng.steps.decode_attention == "loop:not on a TPU"
+    assert toks == want_toks
+    np.testing.assert_allclose(main, want_main, atol=3e-4, rtol=3e-4)
+    np.testing.assert_allclose(draft, want_draft, atol=3e-4, rtol=3e-4)
+
+
 # -- refusals, counters, the scan ---------------------------------------------
 
 def test_a_recurrent_leaf_still_refuses_and_names_the_leaf():
