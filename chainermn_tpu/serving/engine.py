@@ -174,11 +174,17 @@ class Engine:
             raise ValueError(
                 "self_draft serves bucketed prefill only: the module runs "
                 "over the prompt there (prefill_chunk must be None)")
-        self.steps = serving_step(
-            model, params, config.n_slots, config.capacity,
-            cache_dtype=config.cache_dtype, mesh=mesh, axis=axis,
-            kv_dtype=config.kv_dtype,
-            **({"self_draft": True} if config.self_draft else {}))
+        with tracing.lifecycle_span("engine.build", n_slots=config.n_slots,
+                                    capacity=config.capacity) as sp:
+            self.steps = serving_step(
+                model, params, config.n_slots, config.capacity,
+                cache_dtype=config.cache_dtype, mesh=mesh, axis=axis,
+                kv_dtype=config.kv_dtype,
+                **({"self_draft": True} if config.self_draft else {}))
+            # per-slot sampling state, threaded through the compiled
+            # programs (sampling.py encoding: temp<=0 greedy, top_k<=0 full)
+            self._keys = self.steps.place(init_keys(config.n_slots))
+            sp.set(page_bytes=self.steps.cache_bytes())
         self.report = report or (ServingReport(time_fn) if time_fn
                                  else ServingReport())
         self.queue: deque[Request] = deque()
@@ -188,9 +194,6 @@ class Engine:
         #                                               export (handoff)
         self.free_slots: List[int] = list(range(config.n_slots))
         self.cur_tokens = np.zeros(config.n_slots, np.int32)
-        # per-slot sampling state, threaded through the compiled
-        # programs (sampling.py encoding: temp<=0 greedy, top_k<=0 full)
-        self._keys = self.steps.place(init_keys(config.n_slots))
         self._temps = np.zeros(config.n_slots, np.float32)
         self._topks = np.zeros(config.n_slots, np.int32)
         self._eos = np.full(config.n_slots, -1, np.int32)

@@ -85,6 +85,7 @@ here stay for the models that have K/V.
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any, Dict, Optional
 
@@ -92,6 +93,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chainermn_tpu import tracing
 from chainermn_tpu.collectives.quantized import QUANT_BLOCK
 from chainermn_tpu.models.transformer import bhld_to_blhd_params
 from chainermn_tpu.ops.latent_attention import record_paths
@@ -540,6 +542,7 @@ class ServingStep:
         self.cache = self.place(self.cache, pages=True)
         self._decode_jit = jax.jit(_decode, donate_argnums=donate_args,
                                    **kw)
+        self._decoded = False    # ``decode`` has dispatched its program
         self._donate = donate_args
 
     @property
@@ -623,6 +626,19 @@ class ServingStep:
         return partitioned_pages(
             self._mesh is not None and self._mesh.size > 1)
 
+    @contextlib.contextmanager
+    def _first_call(self, program: str, key):
+        """Around the FIRST dispatch of a program key: a
+        ``program.first_call`` lifecycle span (tracing.py) that ends when
+        the pages the program returned are there, so that the key's compile
+        rows and its executable's first run lie inside. ``key`` is the
+        shape the program is compiled for."""
+        shape = key if isinstance(key, tuple) else (key,)
+        with tracing.lifecycle_span("program.first_call", program=program,
+                                    key="x".join(map(str, shape))):
+            yield
+            jax.block_until_ready(self.cache)
+
     def place(self, tree, pages: bool = False):
         """Commit parameters, pages or per-slot state to this step's
         mesh; identity without one. They live there from the start:
@@ -682,8 +698,11 @@ class ServingStep:
         independence keeps them from perturbing live slots (tested
         bitwise)."""
         tokens = jnp.asarray(tokens, jnp.int32)
-        logits, self.cache = self._decode_jit(
-            self.params, self.cache, tokens)
+        first, self._decoded = not self._decoded, True
+        with (self._first_call("decode", self.n_slots) if first
+              else tracing.OFF):
+            logits, self.cache = self._decode_jit(
+                self.params, self.cache, tokens)
         return logits
 
     def prefill(self, tokens, lengths, slot_ids):
@@ -692,7 +711,8 @@ class ServingStep:
         ``prefill_traces``."""
         tokens = jnp.asarray(tokens, jnp.int32)
         key = tokens.shape
-        if key not in self._prefill_jits:
+        first = key not in self._prefill_jits
+        if first:
             def _prefill(params, cache, tokens, lengths, slot_ids,
                          _key=key):
                 self.prefill_traces[_key] = (
@@ -708,10 +728,11 @@ class ServingStep:
                     out_shardings=(repl, cache_sh))
             self._prefill_jits[key] = jax.jit(
                 _prefill, donate_argnums=self._donate, **kw)
-        logits, self.cache = self._prefill_jits[key](
-            self.params, self.cache, tokens,
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(slot_ids, jnp.int32))
+        with self._first_call("prefill", key) if first else tracing.OFF:
+            logits, self.cache = self._prefill_jits[key](
+                self.params, self.cache, tokens,
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(slot_ids, jnp.int32))
         return logits
 
     def decode_k(self, tokens, keys, temps, top_ks, eos_ids, remaining,
@@ -725,7 +746,8 @@ class ServingStep:
         ``self.last_decode_logits`` until somebody actually reads them.
         """
         kk = int(k)
-        if kk not in self._decode_k_jits:
+        first = kk not in self._decode_k_jits
+        if first:
             def _decode_k(params, cache, tokens, keys, temps, top_ks,
                           eos_ids, remaining, live, park, _k=kk):
                 self.decode_k_traces += 1   # trace-time only
@@ -745,13 +767,14 @@ class ServingStep:
                     + (repl,) * self._decode_k_extra)
             self._decode_k_jits[kk] = jax.jit(
                 _decode_k, donate_argnums=self._donate, **kw)
-        toks, last, keys, self.cache, *extra = self._decode_k_jits[kk](
-            self.params, self.cache, jnp.asarray(tokens, jnp.int32),
-            keys, jnp.asarray(temps, jnp.float32),
-            jnp.asarray(top_ks, jnp.int32),
-            jnp.asarray(eos_ids, jnp.int32),
-            jnp.asarray(remaining, jnp.int32),
-            jnp.asarray(live, bool), jnp.asarray(park, jnp.int32))
+        with self._first_call("decode_k", kk) if first else tracing.OFF:
+            toks, last, keys, self.cache, *extra = self._decode_k_jits[kk](
+                self.params, self.cache, jnp.asarray(tokens, jnp.int32),
+                keys, jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32),
+                jnp.asarray(eos_ids, jnp.int32),
+                jnp.asarray(remaining, jnp.int32),
+                jnp.asarray(live, bool), jnp.asarray(park, jnp.int32))
         self.last_decode_logits = last
         self._keep_decode_extra(*extra)
         return toks, keys
@@ -773,7 +796,8 @@ class ServingStep:
         unchanged)."""
         tokens = jnp.asarray(tokens, jnp.int32)
         key = tokens.shape
-        if key not in self._prefill_sampled_jits:
+        first = key not in self._prefill_sampled_jits
+        if first:
             def _pf(params, cache, tokens, lengths, slot_ids, keys,
                     temps, top_ks, _key=key):
                 self.prefill_traces[_key] = (
@@ -789,12 +813,14 @@ class ServingStep:
                           out_shardings=(repl, repl, cache_sh))
             self._prefill_sampled_jits[key] = jax.jit(
                 _pf, donate_argnums=self._donate, **kw)
-        tok, keys, self.cache = self._prefill_sampled_jits[key](
-            self.params, self.cache, tokens,
-            jnp.asarray(lengths, jnp.int32),
-            jnp.asarray(slot_ids, jnp.int32), keys,
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(top_ks, jnp.int32))
+        with (self._first_call("prefill_sampled", key) if first
+              else tracing.OFF):
+            tok, keys, self.cache = self._prefill_sampled_jits[key](
+                self.params, self.cache, tokens,
+                jnp.asarray(lengths, jnp.int32),
+                jnp.asarray(slot_ids, jnp.int32), keys,
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32))
         return tok, keys
 
     def prefill_chunk(self, tokens, starts, valid, slot_ids, final, keys,
@@ -807,7 +833,8 @@ class ServingStep:
         in ``prefill_chunk_traces``."""
         tokens = jnp.asarray(tokens, jnp.int32)
         key = tokens.shape
-        if key not in self._prefill_chunk_jits:
+        first = key not in self._prefill_chunk_jits
+        if first:
             def _pc(params, cache, tokens, starts, valid, slot_ids,
                     final, keys, temps, top_ks, _key=key):
                 self.prefill_chunk_traces[_key] = (
@@ -836,14 +863,15 @@ class ServingStep:
                           out_shardings=(repl, repl, cache_sh))
             self._prefill_chunk_jits[key] = jax.jit(
                 _pc, donate_argnums=self._donate, **kw)
-        tok, keys, self.cache = self._prefill_chunk_jits[key](
-            self.params, self.cache, tokens,
-            jnp.asarray(starts, jnp.int32),
-            jnp.asarray(valid, jnp.int32),
-            jnp.asarray(slot_ids, jnp.int32),
-            jnp.asarray(final, bool), keys,
-            jnp.asarray(temps, jnp.float32),
-            jnp.asarray(top_ks, jnp.int32))
+        with self._first_call("prefill_chunk", key) if first else tracing.OFF:
+            tok, keys, self.cache = self._prefill_chunk_jits[key](
+                self.params, self.cache, tokens,
+                jnp.asarray(starts, jnp.int32),
+                jnp.asarray(valid, jnp.int32),
+                jnp.asarray(slot_ids, jnp.int32),
+                jnp.asarray(final, bool), keys,
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(top_ks, jnp.int32))
         return tok, keys
 
     def export_slot(self, slot: int, fill: int) -> Dict[str, Dict[str, Any]]:

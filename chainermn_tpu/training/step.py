@@ -18,6 +18,26 @@ import optax
 from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
 
+from chainermn_tpu import tracing
+
+
+def _tree_bytes(tree) -> int:
+    return sum(getattr(leaf, "nbytes", 0)
+               for leaf in jax.tree_util.tree_leaves(tree))
+
+
+def _step_build(state=None):
+    """The ``step.build`` lifecycle span (tracing.py) of a factory below:
+    around the making of the jitted ``shard_map`` and, in
+    ``init_expert_parallel_state``, the parameters' placement and
+    ``jax.jit(optimizer.init)``. It carries ``param_bytes`` and
+    ``opt_state_bytes`` where the factory has the state at hand."""
+    if state is None:
+        return tracing.lifecycle_span("step.build")
+    return tracing.lifecycle_span(
+        "step.build", param_bytes=_tree_bytes(state[0]),
+        opt_state_bytes=_tree_bytes(state[1]))
+
 
 def _accepts_train(model) -> bool:
     import inspect
@@ -228,15 +248,16 @@ def make_data_parallel_train_step(
         in_specs = ((P(),) * n_state, batch_spec, batch_spec)
         if with_rng:
             in_specs = in_specs + (P(),)  # the PRNGKey, replicated
-        step = jax.jit(
-            shard_map(
-                local_step,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=((P(),) * n_state, P()),
-            ),
-            donate_argnums=(0,) if donate else (),
-        )
+        with _step_build():
+            step = jax.jit(
+                shard_map(
+                    local_step,
+                    mesh=mesh,
+                    in_specs=in_specs,
+                    out_specs=((P(),) * n_state, P()),
+                ),
+                donate_argnums=(0,) if donate else (),
+            )
         return step
 
     # Stateful reducer: the opt-state specs depend on the state's
@@ -261,15 +282,16 @@ def make_data_parallel_train_step(
         in_specs = (state_specs, batch_spec, batch_spec)
         if with_rng:
             in_specs = in_specs + (P(),)
-        return jax.jit(
-            shard_map(
-                local_step,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=(state_specs, P()),
-            ),
-            donate_argnums=(0,) if donate else (),
-        )
+        with _step_build(state):
+            return jax.jit(
+                shard_map(
+                    local_step,
+                    mesh=mesh,
+                    in_specs=in_specs,
+                    out_specs=(state_specs, P()),
+                ),
+                donate_argnums=(0,) if donate else (),
+            )
 
     compiled = {}
 
@@ -328,11 +350,14 @@ def init_expert_parallel_state(model, comm, rng, sample, optimizer,
         lambda path, _: P(ax) if _is_expert_path(path, expert_key) else P(),
         abs_params,
     )
-    params = jax.jit(shard_map(
-        init_fn, mesh=mesh, in_specs=(P(),), out_specs=param_specs,
-        check_vma=False,
-    ))(sample)
-    opt_state = jax.jit(optimizer.init)(params)  # shardings follow params
+    with _step_build() as sp:
+        params = jax.jit(shard_map(
+            init_fn, mesh=mesh, in_specs=(P(),), out_specs=param_specs,
+            check_vma=False,
+        ))(sample)
+        opt_state = jax.jit(optimizer.init)(params)  # shardings follow params
+        sp.set(param_bytes=_tree_bytes(params),
+               opt_state_bytes=_tree_bytes(opt_state))
     return (params, opt_state), param_specs
 
 
@@ -396,15 +421,16 @@ def make_expert_parallel_train_step(
     def build(state):
         params, opt_state = state
         opt_specs = opt_spec_like(opt_state)
-        return jax.jit(
-            shard_map(
-                local_step,
-                mesh=mesh,
-                in_specs=((param_specs, opt_specs), dspec, dspec),
-                out_specs=(((param_specs, opt_specs)), P()),
-            ),
-            donate_argnums=(0,) if donate else (),
-        )
+        with _step_build(state):
+            return jax.jit(
+                shard_map(
+                    local_step,
+                    mesh=mesh,
+                    in_specs=((param_specs, opt_specs), dspec, dspec),
+                    out_specs=(((param_specs, opt_specs)), P()),
+                ),
+                donate_argnums=(0,) if donate else (),
+            )
 
     compiled = {}
 

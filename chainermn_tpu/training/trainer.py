@@ -46,6 +46,7 @@ class StandardUpdater:
         self.comm = comm
         self.converter = converter
         self.iteration = 0
+        self._dispatched = False     # the step has had its first call
         self.last_metrics: Dict[str, float] = {}
         axes = comm.axis_names
         self._data_sharding = NamedSharding(
@@ -104,9 +105,21 @@ class StandardUpdater:
                                      for a in arrays))
                 arrays = self.shard_batch(arrays)
             with tracing.span("updater.dispatch"):
-                self.state, metrics = self.step_fn(self.state, *arrays)
+                if self._dispatched:
+                    self.state, metrics = self.step_fn(self.state, *arrays)
+                else:
+                    self.state, metrics = self._first_call(arrays)
             self.last_metrics = metrics
             self.iteration += 1
+
+    def _first_call(self, arrays):
+        """This updater's first dispatch of the step, blocked on: the step's
+        compile rows (tracing.py) and its executable's first run lie inside
+        the ``program.first_call`` span."""
+        self._dispatched = True
+        with tracing.lifecycle_span("program.first_call",
+                                    program="local_step"):
+            return jax.block_until_ready(self.step_fn(self.state, *arrays))
 
     # -- full-state resume (docs/fault_tolerance.md) --------------------
 

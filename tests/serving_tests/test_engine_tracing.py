@@ -220,3 +220,110 @@ def test_the_serving_programs_name_their_attention_and_their_sampling(
     sampling = [l for l in text.splitlines() if "/sample/" in l]
     assert any("/sample/while" in l for l in sampling), sampling[:5]
     assert not any("sort" in l for l in sampling)
+
+
+# -- lifecycle spans and the compile log: kept without a session ---------
+PROGRAMS = {"_pf", "_prefill", "_pc", "_decode_k", "_decode"}
+
+
+@pytest.fixture(scope="module", params=["plain", "chunked", "speculative"])
+def started(request):
+    """Each kind of engine built and drained with no profiler session: what
+    the process's lifecycle rows and compile log hold of it."""
+    import time
+
+    t0 = time.perf_counter()
+    eng = _engine(request.param)
+    t_built = time.perf_counter()
+    _drain(eng)
+    t_drained = time.perf_counter()
+    _drain(eng)                 # every key again: no first call among them
+    return {"kind": request.param, "engine": eng,
+            "rows": tracing.lifecycle_rows(t0, t_drained),
+            "later": tracing.lifecycle_rows(t_drained),
+            "compiles": tracing.compiles(t0),
+            "table": tracing.compile_table(t0), "t_built": t_built}
+
+
+def test_one_engine_build_with_what_it_allocated(started):
+    builds = [r for r in started["rows"] if r.name == "engine.build"]
+    assert len(builds) == 1 and builds[0].t1 <= started["t_built"]
+    steps = started["engine"].steps
+    assert builds[0].attrs == {"n_slots": 4, "capacity": 32,
+                               "page_bytes": steps.cache_bytes()}
+    assert builds[0].parent_id is None
+
+
+def test_one_first_call_per_program_key_reached_and_none_later(started):
+    calls = [r for r in started["rows"] if r.name == "program.first_call"]
+    keys = [(r.attrs["program"], r.attrs["key"]) for r in calls]
+    want = ({("prefill_chunk", "2x4"), ("decode_k", "2")}
+            if started["kind"] == "chunked" else
+            {("prefill_sampled", "2x8"), ("prefill_sampled", "2x32"),
+             ("decode_k", "2")})
+    if started["kind"] == "speculative":    # its round is not ``decode_k``
+        want.discard(("decode_k", "2"))
+    assert len(keys) == len(set(keys)) and set(keys) == want
+    assert started["later"] == []
+    assert {r.name for r in started["rows"]} == {"engine.build",
+                                                 "program.first_call"}
+
+
+def test_every_compile_of_a_serving_program_lies_in_its_first_call(started):
+    calls = [r for r in started["rows"] if r.name == "program.first_call"]
+    programs = [c for c in started["compiles"]
+                if c.fun_name[4:-1] in PROGRAMS]
+    assert len(programs) == len(calls)
+    for c in programs:
+        assert sum(r.t0 <= c.t_end <= r.t1 for r in calls) == 1
+    by_key = {(e["program"], e["key"]): e for e in started["table"]
+              if e["span"] == "program.first_call"}
+    assert len(by_key) == len(calls)
+    for (program, _), e in by_key.items():
+        assert e["fun_name"] == {
+            "prefill_sampled": "jit(_pf)", "prefill_chunk": "jit(_pc)",
+            "decode_k": "jit(_decode_k)"}[program]
+        assert e["compiles"] >= 1 and 0 <= e["first_run_s"] < e["span_s"]
+
+
+def test_the_one_token_and_logits_programs_have_first_calls_too():
+    import time
+
+    model, params = _setup()
+    from chainermn_tpu.serving.kv_cache import ServingStep
+
+    t0 = time.perf_counter()
+    steps = ServingStep(model, params, 2, 16)
+    for _ in range(2):
+        steps.prefill(np.zeros((2, 8), np.int32), [3, 4], [0, 1])
+        steps.decode(np.zeros(2, np.int32))
+    calls = tracing.lifecycle_rows(t0)
+    assert [(r.name, r.attrs) for r in calls] == [
+        ("program.first_call", {"program": "prefill", "key": "2x8"}),
+        ("program.first_call", {"program": "decode", "key": "2"})]
+    assert steps.decode_traces == 1 and steps.prefill_traces == {(2, 8): 1}
+
+
+def test_a_state_step_gets_its_first_calls_through_the_shared_paths():
+    """``StateServingStep`` overrides the programs, not the entry points:
+    the spans come with them."""
+    import time
+
+    from tests.models_tests.test_hyper_connections import setup
+    from tests.models_tests.test_mla_long import DECODE_ALIGNED
+
+    model, params = setup(**DECODE_ALIGNED)
+    t0 = time.perf_counter()
+    eng = Engine(model, params, EngineConfig(
+        n_slots=2, capacity=96, buckets=(32, 96), decode_k=2,
+        prefill_cohort=2))
+    eng.submit(np.arange(9, dtype=np.int32), max_new_tokens=4)
+    eng.run_until_drained()
+    rows = tracing.lifecycle_rows(t0)
+    assert type(eng.steps).__name__ == "StateServingStep"
+    assert [(r.name, r.attrs.get("program"), r.attrs.get("key"))
+            for r in rows] == [
+        ("engine.build", None, None),
+        ("program.first_call", "prefill_sampled", "2x32"),
+        ("program.first_call", "decode_k", "2")]
+    assert rows[0].attrs["page_bytes"] == eng.steps.cache_bytes() > 0

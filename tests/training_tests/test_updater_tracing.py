@@ -105,3 +105,61 @@ def test_the_step_names_its_optimizer_and_its_gradient_reduction():
     assert "optimizer_update/grad_reduce/" in text
     assert any("optimizer_update/" in l and "grad_reduce" not in l
                for l in text.splitlines())
+
+
+# -- lifecycle spans and the compile log: kept without a session ---------
+def test_the_factory_is_a_step_build_and_the_first_update_a_first_call():
+    import time
+
+    t0 = time.perf_counter()
+    step, _ = _run()
+    rows = tracing.lifecycle_rows(t0)
+    assert [r.name for r in rows] == ["step.build", "program.first_call"]
+    build, call = rows
+    assert build.attrs == {} and build.parent_id is None
+    assert call.attrs == {"program": "local_step"}
+    assert step._cache_size() == 1
+    steps = [c for c in tracing.compiles(t0)
+             if c.fun_name == "jit(local_step)"]
+    assert len(steps) == 1 and call.t0 <= steps[0].t_end <= call.t1
+    (entry,) = [e for e in tracing.compile_table(t0)
+                if e["span"] == "program.first_call"]
+    assert (entry["program"], entry["fun_name"]) == ("local_step",
+                                                     "jit(local_step)")
+    assert 0 <= entry["first_run_s"] < entry["span_s"]
+    assert tracing.rows() == []
+
+
+def test_a_step_built_on_its_first_state_says_how_large_the_state_is():
+    """A stateful gradient reducer's step is made when the state's structure
+    is known: its ``step.build`` carries the bytes, inside the updater's
+    first call."""
+    import time
+
+    from chainermn_tpu.collectives.quantized import QuantizedReducer
+
+    comm = chainermn_tpu.create_communicator("xla")
+    model = MLP(n_units=16, n_out=10)
+    params = model.init(jax.random.PRNGKey(0),
+                        np.zeros((2, 28, 28), np.float32))["params"]
+    opt = chainermn_tpu.create_multi_node_optimizer(
+        optax.sgd(0.1), comm,
+        grad_reducer=QuantizedReducer(comm, mode="int8", ef=True))
+    t0 = time.perf_counter()
+    step = make_data_parallel_train_step(model, opt, comm)
+    assert tracing.lifecycle_rows(t0) == []     # nothing to build yet
+    params = comm.bcast_data(params)
+    state = (params, jax.jit(opt.init)(params))
+    n_params = sum(l.nbytes for l in jax.tree_util.tree_leaves(state[0]))
+    updater = StandardUpdater(
+        SerialIterator(synthetic_mnist(128, seed=0), BATCH, shuffle=False),
+        step, state, comm)
+    t1 = time.perf_counter()
+    updater.update()
+    updater.update()
+    float(updater.last_metrics["main/loss"])
+    build, call = tracing.lifecycle_rows(t1)
+    assert (build.name, call.name) == ("step.build", "program.first_call")
+    assert build.parent_id == call.id
+    assert build.attrs["param_bytes"] == n_params
+    assert build.attrs["opt_state_bytes"] > 0
