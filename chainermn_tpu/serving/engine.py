@@ -30,6 +30,15 @@ TOKEN BUDGET (``EngineConfig.token_budget``; ``None`` → unbounded):
 4. **Retirement** — slots whose request emitted ``eos_id`` or reached
    its token budget are freed for the next admission.
 
+The monolithic admission of the NEXT iteration runs one decode ahead
+whenever its decision is already fixed (:meth:`Engine._admission_is_fixed`):
+the cohort is composed and its prefill dispatched right after ``decode_k``
+has been enqueued, while the device decodes, and the next ``step()``
+SETTLES it (waits for the first tokens, emits them) in place of admitting.
+The schedule is the late one carried out earlier: the same requests in the
+same slots in the same iteration, the same programs in the same device
+order.
+
 Prefill and decode co-exist without recompilation — the DL108
 invariant: after warmup, serving any traffic mix executes exactly one
 compiled ``decode_k`` program plus one prefill program per bucket (or
@@ -198,6 +207,10 @@ class Engine:
         self._topks = np.zeros(config.n_slots, np.int32)
         self._eos = np.full(config.n_slots, -1, np.int32)
         self._prefill_defer = 0
+        #: (cohort, first-token ids on the device) of the admission that was
+        #: dispatched one decode ahead and is not settled yet: its requests
+        #: hold their slots but join ``active`` only with their first token
+        self._ahead: Optional[Tuple[List[Request], object]] = None
         self.iteration = 0
         self._ids = itertools.count()
         self._buckets = config.bucket_table()
@@ -330,6 +343,7 @@ class Engine:
     def release_held(self, req: Request, aborted: bool = False) -> None:
         """Free a held request's slot (after ``export_handoff`` reached
         a terminal outcome — adopted by a peer, or abandoned)."""
+        self._settle()
         if req.state != "held" or self.held.get(req.slot) is not req:
             raise ValueError(
                 f"request {req.request_id} is not held by this engine")
@@ -350,6 +364,7 @@ class Engine:
         knobs. ``fleet/handoff.py`` serializes this dict to a
         manifest-versioned wire blob; raw-format round-trips are
         bitwise, so the importing engine continues the exact stream."""
+        self._settle()
         if req.state != "held" or self.held.get(req.slot) is not req:
             raise ValueError(
                 f"request {req.request_id} is not held by this engine")
@@ -385,6 +400,7 @@ class Engine:
         ``release_held`` after the peer adopts, ``abort_held`` if the
         transport gives up (the stream then replays from seed), or
         ``resume_session`` to keep decoding here."""
+        self._settle()
         if req.state == "held" and self.held.get(req.slot) is req:
             raise ValueError(
                 f"request {req.request_id} is a held prefill-handoff "
@@ -413,6 +429,7 @@ class Engine:
         rows, cursor, key, and sampling rows never moved, so decoding
         continues here exactly where it stopped (the migration was
         abandoned before the destination adopted)."""
+        self._settle()
         if req.state != "held" or self.held.get(req.slot) is not req:
             raise ValueError(
                 f"request {req.request_id} is not held by this engine")
@@ -448,6 +465,7 @@ class Engine:
         bitwise-identical to the exporting engine continuing (raw wire
         format) — the disaggregation contract
         (``tests/fleet_tests/test_handoff.py``)."""
+        self._settle()
         if not self.free_slots:
             raise RuntimeError("no free slot to import a handoff into")
         hv = handoff.get("weights_version")
@@ -505,6 +523,7 @@ class Engine:
         """Watchdog-bounded teardown: every in-flight request aborts (or
         requeues for a warm restart) and every queued request drains back
         to the caller. Returns the affected requests."""
+        self._settle()
         hit = []
         inflight = (list(self.active.values())
                     + list(self.prefilling.values())
@@ -548,6 +567,7 @@ class Engine:
         ``swap_weights(old_params, old_version, converted=True)``.
         ``converted=True`` skips the caller-layout conversion for
         exactly that round-trip."""
+        self._settle()
         if self.queue or self.active or self.prefilling or self.held:
             raise RuntimeError(
                 "swap_weights requires a drained engine — "
@@ -586,13 +606,67 @@ class Engine:
         """Chunked twin of :meth:`_on_prefill` — fired after every
         chunk dispatch with that dispatch's host-side arrays."""
 
-    def _admit(self, avail: float) -> int:
+    def _head_cohort(self) -> Tuple[int, int, bool]:
+        """``(bucket, size, closed)`` of the queue's head cohort: the leading
+        same-bucket run, at most ``prefill_cohort`` long. It is CLOSED when
+        the run is full or a request of another bucket stands behind it:
+        arrivals only append, so nothing can change a closed head before it
+        is admitted."""
+        s = self.config.prefill_cohort
+        bucket = self._bucket_for(self.queue[0].prompt.size)
+        size = 0
+        for req in self.queue:
+            if size == s or self._bucket_for(req.prompt.size) != bucket:
+                return bucket, size, True
+            size += 1
+        return bucket, size, size == s
+
+    def _admission_is_fixed(self) -> bool:
+        """Whether what :meth:`_admit` would decide at the top of the NEXT
+        iteration is fixed now, with this iteration's ``decode_k`` enqueued
+        and its tokens not yet seen: no cohort in flight already, monolithic
+        mode, no token budget (its ``avail`` depends on the retirements to
+        come), a closed head cohort, and a free slot for each of its
+        requests. ``free_slots`` is FIFO, so the slots this decode's
+        retirements free come behind the ones taken: the same requests in
+        the same slots as the late path would choose."""
+        cfg = self.config
+        if (self._ahead is not None or cfg.prefill_chunk is not None
+                or cfg.token_budget is not None or not self.queue):
+            return False
+        _, size, closed = self._head_cohort()
+        return closed and len(self.free_slots) >= size
+
+    def _in_flight(self) -> List[Request]:
+        """The requests of the cohort admitted ahead and not settled yet."""
+        return self._ahead[0] if self._ahead is not None else []
+
+    def _admit_ahead(self) -> None:
+        """Between ``decode_k``'s enqueue and the wait for its tokens: the
+        next iteration's admission, if it is fixed already. The device runs
+        the prefill right behind the decode, under the host's emit and the
+        caller's bookkeeping, instead of waiting for the host to compose it."""
+        if self._admission_is_fixed():
+            self._admit(float("inf"), ahead=True)
+
+    def _fork_slot_rows(self) -> None:
+        """Fresh copies of the per-slot host arrays the compiled programs
+        are given. An admission ahead writes rows of them while the decode
+        dispatch that was handed the same arrays may not have read them yet
+        (a backend may alias or read a NumPy argument after the call
+        returns): the new rows go into the copies."""
+        self._temps = self._temps.copy()
+        self._topks = self._topks.copy()
+        self._eos = self._eos.copy()
+
+    def _admit(self, avail: float, ahead: bool = False) -> int:
         """One monolithic prefill cohort: same-bucket FIFO prompts into
-        free slots, first token sampled on device."""
+        free slots, first token sampled on device. ``ahead`` leaves the
+        cohort in flight for the next iteration to settle."""
         if not self.queue or not self.free_slots:
             return 0
         s = self.config.prefill_cohort
-        bucket = self._bucket_for(self.queue[0].prompt.size)
+        bucket, size, _ = self._head_cohort()
         if (bucket > avail and self.active
                 and self._prefill_defer < self.config.max_prefill_defer):
             # over budget: let decode keep the iteration, try again next
@@ -601,12 +675,13 @@ class Engine:
             return 0
         self._prefill_defer = 0
         cohort: List[Request] = []
-        with tracing.span("engine.admit", bucket=bucket) as sp:
-            while (self.queue and self.free_slots and len(cohort) < s
-                   and self._bucket_for(self.queue[0].prompt.size) == bucket):
+        with tracing.span("engine.admit", bucket=bucket,
+                          ahead=int(ahead)) as sp:
+            if ahead:
+                self._fork_slot_rows()
+            for _ in range(min(size, len(self.free_slots))):
                 req = self.queue.popleft()
                 self._install(req, self.free_slots.pop(0))
-                self.active[req.slot] = req
                 cohort.append(req)
             tokens = np.zeros((s, bucket), np.int32)
             lengths = np.ones(s, np.int32)          # sentinel rows: length 1
@@ -624,11 +699,25 @@ class Engine:
                 sp.set(admitted=len(cohort), rows=s, prompt_tokens=filled,
                        padded_tokens=s * bucket - filled,
                        state_bytes=len(cohort) * self.steps.slot_bytes)
+        self._ahead = (cohort, tok)
+        return len(cohort) if ahead else self._settle()
+
+    def _settle(self) -> int:
+        """Wait for the first tokens of the cohort in flight, if any, and
+        emit them: its requests join ``active`` here. ``step()`` settles in
+        place of admitting; everything that changes the engine between two
+        steps, or reads a request's pages, settles first. Returns the
+        number of requests settled."""
+        if self._ahead is None:
+            return 0
+        cohort, tok = self._ahead
+        self._ahead = None
         with tracing.span("engine.prefill.wait"):
             first = np.asarray(tok)         # [S] int32 — ids, never logits
         self.report.record_host_bytes(first.nbytes)
         with tracing.span("engine.emit") as sp:
             for i, req in enumerate(cohort):
+                self.active[req.slot] = req
                 self._replay(req, first[i:i + 1])
             if sp:
                 sp.set(tokens=len(cohort),
@@ -786,6 +875,7 @@ class Engine:
                 self._eos, remaining, live, park, cfg.decode_k)
             if enq and self.steps.decode_attention:
                 enq.set(decode_attention=self.steps.decode_attention)
+        self._admit_ahead()
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(toks_dev)         # [n, k] int32 — the ONLY
             #                                     per-token host transfer
@@ -837,33 +927,45 @@ class Engine:
 
     def step(self) -> dict:
         """One scheduler iteration: chaos hook → token budget → prefill
-        (chunked or monolithic) → decode_k → retirement. Returns
-        counters for the caller's loop policy."""
+        (chunked or monolithic; or the settling of the cohort the last
+        iteration admitted ahead) → decode_k, with the next iteration's
+        admission dispatched behind it when that is fixed → retirement.
+        Returns counters for the caller's loop policy. A cohort in flight
+        counts as queued until it is settled: it has no token yet."""
         chaos.on_step(self.iteration)
         self.iteration += 1
         with tracing.span("engine.step", iteration=self.iteration) as sp:
             if sp:
-                sp.set(queued=len(self.queue), active=len(self.active),
+                # the queue as it stood before an admission ahead took its
+                # head, so that the sample reads as the late path's does
+                ahead = self._in_flight()
+                head = ahead or self.queue
+                sp.set(queued=len(self.queue) + len(ahead),
+                       active=len(self.active),
                        oldest_wait_s=(
-                           time.perf_counter() - self.queue[0].t_submit
-                           if self.queue else 0.0))
+                           time.perf_counter() - head[0].t_submit
+                           if head else 0.0))
             budget = self.config.token_budget
             avail = (float("inf") if budget is None else
                      budget - len(self.active) * self._max_decode_advance())
-            if self.config.prefill_chunk is not None:
+            if self._ahead is not None:
+                admitted = self._settle()
+            elif self.config.prefill_chunk is not None:
                 admitted = self._advance_prefill_chunks(avail)
             else:
                 admitted = self._admit(avail)
             emitted = self._decode() if self.active else 0
+            queued = len(self.queue) + len(self._in_flight())
             self.report.record_step(
-                len(self.queue),
+                queued,
                 (len(self.active) + len(self.prefilling))
                 / self.config.n_slots)
         return {"admitted": admitted, "emitted": emitted,
-                "active": len(self.active), "queued": len(self.queue)}
+                "active": len(self.active), "queued": queued}
 
     def idle(self) -> bool:
-        return not self.queue and not self.active and not self.prefilling
+        return (not self.queue and not self.active and not self.prefilling
+                and self._ahead is None)
 
     def run_until_drained(self, max_steps: int = 100_000) -> int:
         """Step until no queued or active work remains; returns the

@@ -377,6 +377,10 @@ class SpeculativeEngine(Engine):
         self._spec_full[slot] = False
         self._spec_prev[slot] = 0
 
+    def _fork_slot_rows(self) -> None:
+        super()._fork_slot_rows()
+        self._spec_prev = self._spec_prev.copy()    # given to ``propose``
+
     def _on_prefill(self, tokens, lengths, slot_ids) -> None:
         self.draft.mirror_prefill(tokens, lengths, slot_ids)
 
@@ -481,6 +485,7 @@ class SpeculativeEngine(Engine):
                 self._temps, self._topks, live, park, self.spec_k)
             emitted_dev, self._keys = self._verify(
                 self.cur_tokens, drafts, remaining, live, park)
+        self._admit_ahead()
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(emitted_dev)  # [n, spec_k+1] int32 — the
             #                                 round's ONLY host pull

@@ -1,6 +1,8 @@
 """The engine's spans: under a profiler session every iteration gives its
 phases in order inside its ``engine.step`` span, with admission counted where
-it happens; without one nothing is recorded and the tokens are the same."""
+it happens (at the top of its iteration, or one decode ahead: both orders,
+and each engine again with every admission held to the late path); without a
+session nothing is recorded and the tokens are the same."""
 import functools
 
 import numpy as np
@@ -31,6 +33,16 @@ def _setup():
 
 
 def _engine(kind):
+    """``kind`` names the engine; a ``-late`` suffix holds its admissions to
+    the top of their iteration (a test's patch of the predicate)."""
+    kind, _, late = kind.partition("-")
+    eng = _build(kind)
+    if late:
+        eng._admission_is_fixed = lambda: False
+    return eng
+
+
+def _build(kind):
     model, params = _setup()
     cfg = EngineConfig(n_slots=4, capacity=32, max_new_tokens=N_NEW,
                        prefill_cohort=2, buckets=[8, 32], decode_k=2,
@@ -62,7 +74,8 @@ def test_stepped_without_a_session_nothing_is_recorded(kind):
     assert tracing.rows() == []
 
 
-@pytest.fixture(scope="module", params=["plain", "chunked", "speculative"])
+@pytest.fixture(scope="module", params=["plain", "plain-late", "chunked",
+                                         "speculative", "speculative-late"])
 def traced(request, profiler_session):
     """Each kind of engine drained once without and once under a session."""
     kind = request.param
@@ -82,25 +95,54 @@ def test_a_session_changes_no_token(traced):
 
 
 def test_every_iteration_holds_its_phases_in_order(traced):
+    """An iteration is its prefill (an admission at its top: admit, wait,
+    emit; or the settling of the cohort the iteration before admitted ahead:
+    wait, emit; a chunked engine may run several admissions) and then its
+    decode (enqueue, the next iteration's admission where that ran ahead,
+    wait, emit)."""
     its = _iterations(traced["rows"])
     assert len(its) == traced["engine"].iteration
     assert [s.attrs["iteration"] for s, _ in its] == list(
         range(1, len(its) + 1))
     assert {r.name for r in traced["rows"]} == set(PHASES) | {"engine.step"}
+    in_flight = False           # the iteration before admitted ahead
+    orders = set()
     for step, kids in its:
         names = [k.name for k in kids]
-        prefills, decode = names[:-3], names[-3:]
-        if "engine.decode.enqueue" not in names:
-            prefills, decode = names, []
-        assert decode in ([], PHASES[3:])
-        assert len(prefills) % 3 == 0
-        for i in range(0, len(prefills), 3):
-            assert prefills[i:i + 3] == PHASES[:3]
+        prefills, decode = names, []
+        if "engine.decode.enqueue" in names:
+            at = names.index("engine.decode.enqueue")
+            prefills, decode = names[:at], names[at:]
+        ahead = decode[1:2] == ["engine.admit"]
+        assert decode in ([], PHASES[3:],
+                          PHASES[3:4] + PHASES[:1] + PHASES[4:])
+        if in_flight:
+            assert prefills == PHASES[1:3]          # settled, not admitted
+        else:
+            assert len(prefills) % 3 == 0
+            for i in range(0, len(prefills), 3):
+                assert prefills[i:i + 3] == PHASES[:3]
+        admits = [k for k in kids if k.name == "engine.admit"]
         if traced["kind"] != "chunked":
-            assert len(prefills) <= 3       # one cohort an iteration
+            # one cohort an iteration: this one's at the top unless it was
+            # in flight, and the next one's where it ran ahead
+            late = bool(prefills) and not in_flight
+            assert [a.attrs["ahead"] for a in admits] == (
+                [0] * late + [1] * ahead)
+            orders |= ({"late"} if late else set()) | (
+                {"settled"} if in_flight else set()) | (
+                {"ahead"} if ahead else set())
+        in_flight = ahead
         assert all(a.t1 <= b.t0 for a, b in zip(kids, kids[1:]))
         assert step.t0 <= kids[0].t0 and kids[-1].t1 <= step.t1
         assert sum(k.t1 - k.t0 for k in kids) <= step.t1 - step.t0
+    assert not in_flight
+    if traced["kind"] in ("plain", "speculative"):
+        # the stream takes both paths: (3, 5) finds no row decoding, (4) and
+        # (12) are closed heads with a slot free, (4, 6) finds no slot free
+        assert {"late", "ahead", "settled"} <= orders
+    else:
+        assert not {"ahead", "settled"} & orders
 
 
 def test_admission_is_counted_where_it_happens(traced):
@@ -110,6 +152,7 @@ def test_admission_is_counted_where_it_happens(traced):
     assert sum(a.attrs["prompt_tokens"] for a in admits) == sum(LENS)
     for a in admits:
         width = a.attrs["chunk" if traced["kind"] == "chunked" else "bucket"]
+        assert ("ahead" in a.attrs) == (traced["kind"] != "chunked")
         assert a.attrs["rows"] == 2
         assert (a.attrs["prompt_tokens"] + a.attrs["padded_tokens"]
                 == 2 * width)
@@ -133,7 +176,8 @@ def test_a_decode_program_without_latent_attention_names_none(traced):
     """The same for ``decode_attention`` on ``engine.decode.enqueue``."""
     enq = [r for r in traced["rows"] if r.name == "engine.decode.enqueue"]
     assert enq and not any("decode_attention" in r.attrs for r in enq)
-    if traced["kind"] != "speculative":     # whose round is not ``decode_k``
+    if not traced["kind"].startswith("speculative"):    # whose round is not
+        #                                                   ``decode_k``
         assert traced["engine"].steps.decode_k_traces == 1
     assert traced["engine"].steps.decode_attention is None
 
