@@ -23,6 +23,15 @@ and per layer one MIXER of
   and a decode step reads the columns the live rows have filled, not
   ``max_len`` — what 8k-32k prompts need.
 
+* ``"gqa"`` and ``"swa"`` — grouped-query attention over K/V leaves
+  (:class:`GQAMixer`, ``ops/kv_attention.py``): over a PAGE of ``max_len``
+  columns, and over a RING of ``window`` columns in which a query sees the
+  last ``window`` positions. The sizes that differ BY KIND are the model's
+  fields (``gqa_heads`` / ``swa_heads`` query heads over ``n_kv_heads``
+  shared K/V heads, the rotary share, theta and scaling of each, the
+  window); a chunk of queries at any cursor attends the filled columns in
+  blocks, a decode step reads what the live rows have filled.
+
 and one FEED-FORWARD of ``"dense"`` (SwiGLU) or ``"moe"`` (this chip's share
 of a routed expert layer, ``parallel/expert_share.py``, plus a shared expert).
 
@@ -37,7 +46,8 @@ themselves: ``X' = H_res X + H_postᵀ ⊗ F(norm(H_pre X))``.
 Serving contract (``serving/state_cache.py`` reads ``declares_cache``): with
 ``decode=True`` the ``cache`` collection holds, slot-major, whatever the
 layers declare — a recurrent state and a convolution tail per KDA layer, a
-latent page per MLA layer — and one cursor vector ``idx`` at the root.
+latent page per MLA layer, a K/V page per ``"gqa"`` layer, a K/V ring per
+``"swa"`` layer — and one cursor vector ``idx`` at the root.
 ``__call__(tokens [B, L], lengths=[B], live=[B])`` advances row ``b`` by
 ``lengths[b]`` tokens (right-padded rows: a recurrence integrates padding
 unless told not to, so positions at or past the length get ``β = 0``,
@@ -59,11 +69,14 @@ are decode positions: every row writes ``L`` latents at its cursor and the
 ``L`` queries take the absorbed form against one read of the page.
 
 What a leaf of that collection may do: a POSITIONAL leaf
-(``HybridLM.positional_leaves``: the latent page ``ckv``, axis 1 the
-position) is addressed by the cursor like K/V rows, so a call may start at
-any cursor (``pos``) and a prompt may arrive in chunks; a RECURRENT leaf
-(every other one but ``idx``: KDA's ``state`` and ``conv``) is the sum of
-its history, starts from what the slot holds and cannot be rewound,
+(``HybridLM.positional_leaves``: the latent page ``ckv``, the K/V pages
+``k`` and ``v``; axis 1 the position) is addressed by the cursor like K/V
+rows, so a call may start at any cursor (``pos``) and a prompt may arrive
+in chunks; a WINDOW leaf (``window_leaves``: the K/V rings ``k_win`` and
+``v_win``; axis 1 ``window`` columns) is addressed by the cursor ``mod
+window``, takes chunks too and wraps by nature; a RECURRENT leaf (every
+other one but ``idx``: KDA's ``state`` and ``conv``) is the sum of its
+history, starts from what the slot holds and cannot be rewound,
 re-windowed or written at a column. ``idx`` and ``draft`` are per-slot
 scalars, neither page nor state.
 """
@@ -79,11 +92,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.ops import latent_attention
+from chainermn_tpu.ops import kv_attention, latent_attention
+from chainermn_tpu.ops.kv_attention import (column_blocks as _blocks,
+                                            write_window as _write_window)
 from chainermn_tpu.parallel.expert_share import HeldExperts, RouteStats
 
-__all__ = ["HybridLM", "HybridBlock", "HyperConnection", "KDAMixer",
-           "MLAMixer", "MTPModule", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
+__all__ = ["GQAMixer", "HybridLM", "HybridBlock", "HyperConnection",
+           "KDAMixer", "MLAMixer", "MTPModule", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
            "latent_chunk_attention", "latent_decode_attention",
            "layer_pattern", "sinkhorn", "yarn_inv_freq", "yarn_mscale"]
 
@@ -359,47 +374,6 @@ def rope_interleaved(x, positions, theta, inv_freq=None, mscale=1.0):
     x1, x2 = x32[..., 0::2], x32[..., 1::2]
     out = jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
     return out.reshape(x.shape)
-
-
-def _write_window(page, chunk, pos, n, slots):
-    """``page [N, T, w]`` with ``chunk[b, :n[b]]`` written into row
-    ``slots[b]`` at columns ``pos[b] ..`` and everything else as it was
-    (``chunk [B, C, w]``, ``C <= T``; a slot past ``N`` writes nothing):
-    one window a chunk row, read, blended and put back where the page
-    lies."""
-    rows, t, w = page.shape
-    c = chunk.shape[1]
-    chunk = chunk.astype(page.dtype)
-    for b in range(chunk.shape[0]):
-        s0 = jnp.clip(pos[b], 0, t - c)     # the window stays inside the page
-        off = pos[b] - s0
-        j = jnp.arange(c)
-        keep = (j >= off) & (j - off < n[b]) & (slots[b] < rows)
-        at = (jnp.minimum(slots[b], rows - 1), s0, 0)
-        new = jnp.where(keep[:, None], jnp.roll(chunk[b], off, axis=0),
-                        jax.lax.dynamic_slice(page, at, (1, c, w))[0])
-        page = jax.lax.dynamic_update_slice(page, new[None], at)
-    return page
-
-
-def _blocks(page, top, block, slots):
-    """(block width, number of blocks that cover columns ``< top``, a
-    function ``(page, j) -> (block j of the rows ``slots`` [B, block, w], its
-    column ids, which of them are block j's own)``). The last block of a
-    page that is no multiple of the width starts early, inside the page, and
-    disowns the columns the block before it has."""
-    rows, t, w = page.shape
-    block = min(block, t)
-
-    def take(page, j):
-        s0 = jnp.minimum(j * block, t - block)
-        col = s0 + jnp.arange(block)
-        blk = jnp.concatenate([jax.lax.dynamic_slice(
-            page, (jnp.minimum(slots[b], rows - 1), s0, 0), (1, block, w))
-            for b in range(slots.shape[0])])
-        return blk, col, col >= j * block
-
-    return block, (top + block - 1) // block, take
 
 
 def latent_chunk_attention(q_nope, q_rope, page, w_kvb, pos, scale, block,
@@ -736,6 +710,155 @@ class MLAMixer(nn.Module):
 
 
 # ---------------------------------------------------------------------------
+# grouped-query attention over K/V leaves: a page, or a ring of the window
+# ---------------------------------------------------------------------------
+
+def rope_halves(x, positions, inv_freq, mscale=1.0):
+    """Rotate the FIRST ``2 · len(inv_freq)`` values of the last axis, the
+    first half of them against the second (``x_i`` with ``x_{i + n}``), by
+    ``positions · inv_freq`` with cos and sin scaled by ``mscale``; the
+    values behind them pass through. x ``[B, L, H, d]``, positions ``[B,
+    L]``."""
+    n = inv_freq.shape[0]
+    ang = positions.astype(jnp.float32)[..., None, None] * inv_freq
+    cos, sin = jnp.cos(ang) * mscale, jnp.sin(ang) * mscale
+    x32 = x.astype(jnp.float32)
+    x1, x2, rest = x32[..., :n], x32[..., n:2 * n], x32[..., 2 * n:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos, rest],
+                           -1)
+
+
+class GQAMixer(nn.Module):
+    """``n_heads`` query heads over ``n_kv_heads`` keys and values of
+    ``d_head``, causal, over FLAT K/V leaves (``ops/kv_attention.py``): a
+    PAGE of ``max_len`` columns (``window`` 0; leaves ``k``, ``v``) or a RING
+    of ``window`` columns in which a query sees the last ``window`` positions
+    (leaves ``k_win``, ``v_win``). Rotary on the first ``rotary`` share of a
+    head, half against half, plain or YaRN's (``rope_scaling``: its keys,
+    cos and sin scaled by ``attention_factor``, ``0.1 ln factor + 1`` where
+    it is not given); with ``gate`` one sigmoid gate a HEAD from the mixer's
+    input on the attended values. No bias, no q/k norm.
+
+    ``__call__(x [B, L, d], pos, lengths, live, slots)``: ``L > 1`` is a
+    chunk of a prompt at the rows' cursors (the page gets ``[pos, pos +
+    length)``, the ring the chunk's last ``min(length, window)`` rows at
+    ``position mod window``; with ``slots`` the call's rows are rows
+    ``slots`` of leaves that hold more); ``L == 1`` a decode step. A row
+    that is not live leaves its leaves as they were."""
+    n_heads: int
+    n_kv_heads: int
+    d_head: int
+    rope_theta: float
+    max_len: int
+    rotary: float = 1.0
+    rope_scaling: Optional[Mapping[str, Any]] = None
+    window: int = 0
+    gate: bool = False
+    dtype: Any = jnp.float32
+    decode: bool = False
+
+    def _rotation(self):
+        rot = int(self.d_head * self.rotary) // 2 * 2
+        if self.rope_scaling is None:
+            return (self.rope_theta ** (
+                -jnp.arange(rot // 2, dtype=jnp.float32) * 2.0 / rot), 1.0)
+        rs = dict(self.rope_scaling)
+        kind = rs.get("rope_type", rs.get("type", "yarn"))
+        if kind != "yarn":
+            raise ValueError(f"rope_scaling type {kind!r}: only 'yarn' is "
+                             "implemented")
+        return (yarn_inv_freq(rot, self.rope_theta, rs),
+                rs.get("attention_factor") or yarn_mscale(rs["factor"]))
+
+    @nn.compact
+    def __call__(self, x, pos, lengths=None, live=None, slots=None):
+        b, l, d = x.shape
+        h, n_kv, dh = self.n_heads, self.n_kv_heads, self.d_head
+        if h % n_kv:
+            raise ValueError(f"{h} query heads over {n_kv} K/V heads")
+        dense = lambda n, name: nn.Dense(n, use_bias=False, dtype=self.dtype,
+                                         param_dtype=self.dtype, name=name)
+        positions = pos[:, None] + jnp.arange(l)[None]
+        inv_freq, mscale = self._rotation()
+        rope = lambda a, n: rope_halves(
+            a.reshape(b, l, n, dh), positions, inv_freq, mscale).astype(
+                self.dtype)
+        q = rope(dense(h * dh, "q_proj")(x), h)
+        k = rope(dense(n_kv * dh, "k_proj")(x), n_kv).reshape(b, l, n_kv * dh)
+        v = dense(n_kv * dh, "v_proj")(x)
+        if self.gate:
+            gate = jax.nn.sigmoid(dense(h, "g_proj")(x).astype(jnp.float32))
+        n = jnp.full((b,), l, jnp.int32) if lengths is None else lengths
+        if live is not None:
+            n = jnp.where(live, n, 0)
+        ring = bool(self.window)
+        names = ("k_win", "v_win") if ring else ("k", "v")
+        if self.decode:
+            cols = self.window if ring else self.max_len
+            k_v, v_v = (self.variable("cache", name, jnp.zeros,
+                                      (b, cols, n_kv * dh), self.dtype)
+                        for name in names)
+            k_leaf, v_leaf = k_v.value, v_v.value
+        elif l == 1:
+            raise ValueError("a one-token call needs decode=True")
+        elif ring:      # no cache: an empty ring before the chunk
+            k_leaf = v_leaf = jnp.zeros((b, self.window, n_kv * dh),
+                                        self.dtype)
+        else:           # no cache: the chunk is the page
+            k_leaf, v_leaf = k, v
+        if slots is not None and not (l > 1 and self.decode):
+            raise ValueError("slots address the leaves of a chunk call "
+                             "(decode=True, L > 1)")
+        scale = dh ** -0.5
+        if l > 1 and ring:
+            with jax.named_scope("swa_chunk"):
+                o = kv_attention.ring_chunk_attention(
+                    q, k, v, k_leaf, v_leaf, pos, slots, scale)
+                if self.decode:
+                    k_leaf, v_leaf = (kv_attention.write_ring(
+                        leaf, new, pos, n, slots)
+                        for leaf, new in ((k_leaf, k), (v_leaf, v)))
+        elif l > 1:
+            with jax.named_scope("gqa_chunk"):
+                rows = jnp.arange(b) if slots is None else slots
+                if self.decode:
+                    k_leaf, v_leaf = (_write_window(leaf, new, pos, n, rows)
+                                      for leaf, new in ((k_leaf, k),
+                                                        (v_leaf, v)))
+                o, k_leaf, v_leaf = kv_attention.page_chunk_attention(
+                    q, k_leaf, v_leaf, pos, rows, scale)
+        else:
+            alive = n > 0
+            with jax.named_scope("swa_decode" if ring else "gqa_decode"):
+                if ring:
+                    k_leaf, v_leaf = (kv_attention.write_ring(
+                        leaf, new, pos, n)
+                        for leaf, new in ((k_leaf, k), (v_leaf, v)))
+                    o = kv_attention.ring_decode_attention(
+                        q[:, 0], k_leaf, v_leaf, pos, scale)
+                else:
+                    at = (jnp.arange(b), pos)
+
+                    def put(leaf, new):     # a row that is not live keeps
+                        # the column at its cursor too
+                        old = leaf.at[at].get(mode="clip")
+                        return leaf.at[at].set(jnp.where(
+                            alive[:, None], new[:, 0].astype(leaf.dtype),
+                            old), mode="drop")
+
+                    o, k_leaf, v_leaf = kv_attention.page_decode_attention(
+                        q[:, 0], put(k_leaf, k), put(v_leaf, v), pos, alive,
+                        scale)
+                o = o[:, None]
+        if self.decode:
+            k_v.value, v_v.value = k_leaf, v_leaf
+        if self.gate:
+            o = o * gate[..., None]
+        o = o.astype(self.dtype).reshape(b, l, h * dh)
+        return dense(d, "o_proj")(o)
+
+
+# ---------------------------------------------------------------------------
 # hyper-connections
 # ---------------------------------------------------------------------------
 
@@ -794,6 +917,13 @@ class HyperConnection(nn.Module):
 # block and model
 # ---------------------------------------------------------------------------
 
+#: what a decode call of a model with K/V layers sows beside ``RouteStats``,
+#: under these names (``HybridLM._read_stats``)
+AttnStats = collections.namedtuple("AttnStats", [
+    "attn_rows_live", "attn_rows_wrapped", "attn_page_columns",
+    "attn_ring_columns", "attn_fill_columns"])
+
+
 def layer_pattern(n_layers: int, group: int, first_dense: int
                   ) -> Tuple[Tuple[str, str], ...]:
     """Layer ``i`` mixes with MLA when ``(i + 1) % group == 0`` and KDA
@@ -822,12 +952,36 @@ class HybridBlock(nn.Module):
                             lower_bound=c.kda_lower_bound, eps=c.norm_eps,
                             dtype=c.dtype, decode=self.decode,
                             name="kda")(y, lengths, live)
-        return MLAMixer(c.n_heads, c.d_nope, c.d_rope, c.d_head, c.kv_rank,
-                        c.rope_theta, c.max_len, eps=c.norm_eps,
-                        dtype=c.dtype, decode=self.decode, q_rank=c.q_rank,
-                        gate=c.mla_gate, rope_scaling=c.rope_scaling,
-                        block=c.mla_block, name="mla")(y, pos, lengths, live,
-                                                       slots, absorbed)
+        if self.mixer == "mla":
+            return MLAMixer(c.n_heads, c.d_nope, c.d_rope, c.d_head,
+                            c.kv_rank, c.rope_theta, c.max_len,
+                            eps=c.norm_eps, dtype=c.dtype, decode=self.decode,
+                            q_rank=c.q_rank, gate=c.mla_gate,
+                            rope_scaling=c.rope_scaling, block=c.mla_block,
+                            name="mla")(y, pos, lengths, live, slots,
+                                        absorbed)
+        if self.mixer not in ("gqa", "swa"):
+            raise ValueError(f"unknown mixer kind {self.mixer!r}: one of "
+                             "'kda', 'mla', 'gqa', 'swa'")
+        if absorbed:
+            raise ValueError("several decode positions a row against one "
+                             "read are written for latent pages only")
+        # the sizes that differ BY KIND: the page's and the ring's
+        swa = self.mixer == "swa"
+        heads, theta = ((c.swa_heads, c.swa_theta) if swa
+                        else (c.gqa_heads, c.gqa_theta))
+        if not (heads and theta and c.n_kv_heads and (c.window or not swa)):
+            raise ValueError(
+                f"a {self.mixer!r} layer needs the model's n_kv_heads, "
+                f"{self.mixer}_heads and {self.mixer}_theta"
+                + (" and its window" if swa else ""))
+        return GQAMixer(
+            heads, c.n_kv_heads, c.d_head, theta, c.max_len,
+            rotary=c.swa_rotary if swa else c.gqa_rotary,
+            rope_scaling=c.swa_scaling if swa else c.gqa_scaling,
+            window=c.window if swa else 0, gate=c.attn_gate,
+            dtype=c.dtype, decode=self.decode, name=self.mixer)(
+                y, pos, lengths, live, slots)
 
     def _feed(self, y, lengths, live):
         c = self.cfg
@@ -904,7 +1058,9 @@ class MTPModule(nn.Module):
 
 class HybridLM(nn.Module):
     """See the module docstring. ``pattern`` is one ``(mixer, ffn)`` pair a
-    layer (:func:`layer_pattern`); the expert fields describe the routed
+    layer (:func:`layer_pattern` makes the KDA/MLA one; any tuple of pairs
+    over ``"kda"``, ``"mla"``, ``"gqa"``, ``"swa"`` will do, an unknown
+    mixer kind raises); the expert fields describe the routed
     layers: ``n_experts`` routed over, ``held_lo:held_hi`` held here.
 
     ``n_mtp`` 1 adds one :class:`MTPModule` (``mtp_0``; 0 creates no
@@ -949,16 +1105,33 @@ class HybridLM(nn.Module):
     rope_scaling: Optional[Mapping[str, Any]] = None     # YaRN's keys
     mla_block: int = 0               # page columns a block; 0: one piece
     n_mtp: int = 0                   # multi-token-prediction modules (0, 1)
+    # the "gqa" (K/V page) and "swa" (K/V ring) mixers; what differs by kind
+    # is given by kind, and a pattern that names a kind gives its sizes
+    n_kv_heads: int = 0
+    gqa_heads: int = 0
+    swa_heads: int = 0
+    gqa_rotary: float = 1.0          # share of a head that is rotated
+    swa_rotary: float = 1.0
+    gqa_theta: Optional[float] = None
+    swa_theta: Optional[float] = None
+    gqa_scaling: Optional[Mapping[str, Any]] = None     # YaRN's keys
+    swa_scaling: Optional[Mapping[str, Any]] = None
+    window: int = 0                  # positions a "swa" layer sees and keeps
+    attn_gate: bool = False          # one output gate a head on both kinds
     dtype: Any = jnp.float32
     decode: bool = False
 
     #: serving/kv_cache.py: the pages are what the ``cache`` collection
-    #: declares (recurrent state, convolution tail, latent page), not K/V
+    #: declares (recurrent state, convolution tail, latent page, K/V page,
+    #: K/V ring), not one K/V layout a model
     declares_cache = True
     #: serving/state_cache.py: the declared leaves a cursor addresses like
-    #: K/V rows (axis 1 is the position); any other but ``idx`` and
-    #: ``draft`` is a recurrence
-    positional_leaves = ("ckv",)
+    #: K/V rows (axis 1 is the position, ``max_len`` long) ...
+    positional_leaves = ("ckv", "k", "v")
+    #: ... the ones it addresses ``mod`` their length (axis 1 is a ring of
+    #: ``window`` columns); any other but ``idx`` and ``draft`` is a
+    #: recurrence
+    window_leaves = ("k_win", "v_win")
 
     @property
     def n_layers(self) -> int:
@@ -978,6 +1151,27 @@ class HybridLM(nn.Module):
         for name, v in stats._asdict().items():
             self.sow("stats", prefix + name, v, reduce_fn=lambda a, b: a + b,
                      init_fn=lambda v=v: jnp.zeros((), v.dtype))
+
+    def _read_stats(self, pos, live, decode_step):
+        """What the K/V layers read in a decode step, beside what they had
+        to: per call the live rows, those whose cursor has passed the window
+        (their rings have wrapped), the columns read from pages (the visits
+        of ``kv_attention.page_decode_attention``'s loop) and from rings
+        (whole, every row) over all layers — 0 for a chunk call — and the
+        columns ONE capacity-long page read up to each live row's fill would
+        give."""
+        count = lambda a: a.sum(dtype=jnp.int32)
+        zero = jnp.zeros((), jnp.int32)
+        layers = collections.Counter(m for m, _ in self.pattern)
+        return AttnStats(
+            attn_rows_live=count(live),
+            attn_rows_wrapped=count(live & (pos >= self.window))
+            if self.window else zero,
+            attn_page_columns=layers["gqa"] * kv_attention.decode_columns(
+                pos, live, self.max_len) if decode_step else zero,
+            attn_ring_columns=jnp.int32(
+                layers["swa"] * pos.shape[0] * self.window * decode_step),
+            attn_fill_columns=count(jnp.where(live, pos + 1, 0)))
 
     def _draft(self, emb, head, hidden, tokens, pos, lengths, live, absorbed,
                at):
@@ -1063,6 +1257,8 @@ class HybridLM(nn.Module):
                 moved, mode="drop")
             if self.n_experts:
                 self._sow(stats)
+            if {"gqa", "swa"} & {m for m, _ in self.pattern}:
+                self._sow(self._read_stats(pos, live, decode_step=l == 1))
         if self.hc_mult > 1:        # and the streams are summed at the end
             x = x.astype(jnp.float32).sum(2).astype(self.dtype)
         if self.n_mtp and self.is_initializing():

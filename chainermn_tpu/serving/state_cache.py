@@ -13,13 +13,24 @@ install, evict, reset, export and import work leaf by leaf on axis 0 with no
 knowledge of what a leaf means.
 
 What the cursor can and cannot do here goes by the KIND of each declared
-leaf, which the model names (``positional_leaves``):
+leaf, which the model names (``positional_leaves``, ``window_leaves``;
+:func:`leaf_kinds`):
 
-* a POSITIONAL leaf (a latent page: axis 1 is the position) is addressed by
-  the cursor like K/V rows. A prompt may arrive in chunks
-  (:func:`state_prefill_chunk_apply`, ``EngineConfig.prefill_chunk``): the
-  model runs ``[S, C]`` at the rows' cursors against the cohort's rows of
-  the pages, writes ``[start, start + valid)`` and attends what is filled;
+* a POSITIONAL leaf (a latent page, a K/V page: axis 1 is the position,
+  ``capacity`` long) is addressed by the cursor like K/V rows. A prompt may
+  arrive in chunks (:func:`state_prefill_chunk_apply`,
+  ``EngineConfig.prefill_chunk``): the model runs ``[S, C]`` at the rows'
+  cursors against the cohort's rows of the pages, writes ``[start, start +
+  valid)`` and attends what is filled. It does not wrap: ``prompt +
+  max_new_tokens`` is held to the capacity;
+* a WINDOW leaf (a K/V ring: axis 1 is ``window`` columns, the model's own
+  length whatever the capacity) is addressed by the cursor ``mod window``.
+  It wraps by nature — column ``j`` holds the newest position ``≡ j`` — and
+  a chunk may write it (the chunk's last ``min(valid, window)`` rows). Its
+  slot row and the cursor are the whole of its state, so export and import
+  move it like any leaf; a self-drafted round is refused by its name (a
+  rejected draft's row would have OVERWRITTEN the position a window back,
+  which no later write restores);
 * a RECURRENT leaf (every other one but ``idx``) is the sum of its history
   and cannot be rewound, re-windowed or wrapped by moving a cursor. A model
   with one is refused chunked prefill with a ``ValueError`` that names the
@@ -30,10 +41,12 @@ model takes the ``live`` mask and leaves such a row's state exactly as it was
 (``β = 0``, ``α = 1``), where the K/V path lets it write garbage past its
 cursor; a right-padded prefill row must stop at its true length — the model
 takes ``lengths`` and installs the state after the row's last real token.
-``int8-block`` pages (per-column requantisation) and ring wrap (overwrite
-the oldest column) are refused for all of them: a recurrent leaf forbids
-them by nature ("recurrent state"), and for a model of positional leaves
-alone these programs are not written (the message says so).
+``int8-block`` pages (per-column requantisation) are refused for all of
+them, and running a cursor past the capacity for every model with a
+positional or a recurrent leaf: a recurrent leaf forbids both by nature
+("recurrent state"), and for positional leaves these programs are not
+written (the message says so). A model of window leaves alone has nothing
+the capacity bounds.
 
 Speculation is SELF-DRAFTING here (``StateServingStep(self_draft=True)``,
 ``EngineConfig.self_draft``): a model that carries a multi-token-prediction
@@ -61,7 +74,7 @@ __all__ = ["StateServingStep", "serving_step", "declares_cache",
            "init_state_cache", "state_decode_apply", "state_prefill_apply",
            "state_decode_k_apply", "state_prefill_chunk_apply",
            "state_self_draft_k_apply", "state_prefill_draft_apply",
-           "recurrent_leaves", "refuse_recurrent"]
+           "leaf_kinds", "recurrent_leaves", "refuse_recurrent"]
 
 #: the per-slot scalars of a declared cache: the cursor, and the draft a
 #: self-drafting slot holds between rounds. Neither is a page nor a state
@@ -72,32 +85,41 @@ def declares_cache(model) -> bool:
     return bool(getattr(model, "declares_cache", False))
 
 
-def recurrent_leaves(model):
-    """Paths (``block_0/kda/state``) of the declared leaves that are a
-    recurrence: every leaf of the ``cache`` collection but the per-slot
-    scalars (``BOOKKEEPING_LEAVES``) and the ones the model names in
-    ``positional_leaves``."""
+def leaf_kinds(model):
+    """``{path: kind}`` of the declared leaves (``block_0/kda/state`` ->
+    ``"recurrent"``), the per-slot scalars (``BOOKKEEPING_LEAVES``) left
+    out: ``"positional"`` and ``"window"`` for the ones the model names in
+    ``positional_leaves`` and ``window_leaves``, ``"recurrent"`` for every
+    other."""
     shapes = jax.eval_shape(lambda: init_state_cache(model, 1, 8))
-    positional = tuple(getattr(model, "positional_leaves", ()))
+    named = {n: kind for kind in ("positional", "window")
+             for n in getattr(model, kind + "_leaves", ())}
     flat = jax.tree_util.tree_flatten_with_path(shapes)[0]
     paths = ["/".join(str(getattr(k, "key", k)) for k in path)
              for path, _ in flat]
-    return [p for p in paths if p not in BOOKKEEPING_LEAVES
-            and p.rsplit("/", 1)[-1] not in positional]
+    return {p: named.get(p.rsplit("/", 1)[-1], "recurrent") for p in paths
+            if p not in BOOKKEEPING_LEAVES}
+
+
+def recurrent_leaves(model):
+    """Paths of the declared leaves that are a recurrence."""
+    return [p for p, kind in leaf_kinds(model).items()
+            if kind == "recurrent"]
 
 
 def refuse_recurrent(model, what: str, *, positional_too: bool = True,
                      instead: str = "") -> None:
     """Raise for a feature that moves K/V rows by cursor. A model with a
     recurrent leaf is always refused, by that leaf's name; one whose
-    declared leaves are all positional only where the feature has no
-    program for declared pages (``positional_too``), with ``instead`` —
-    what serves such a model in the feature's place — in the message's
-    stead where there is one."""
+    declared leaves are all positional or window leaves only where the
+    feature has no program for declared pages (``positional_too``), with
+    ``instead`` — what serves such a model in the feature's place — in the
+    message's stead where there is one."""
     if not declares_cache(model):
         return
     name = type(model).__name__
-    leaves = recurrent_leaves(model)
+    kinds = leaf_kinds(model)
+    leaves = [p for p, kind in kinds.items() if kind == "recurrent"]
     if leaves:
         more = f" (and {len(leaves) - 1} more)" if len(leaves) > 1 else ""
         raise ValueError(
@@ -108,9 +130,10 @@ def refuse_recurrent(model, what: str, *, positional_too: bool = True,
     if positional_too:
         raise ValueError(
             f"{what} is not available for {name}: " + (instead or (
-                "its declared pages are positional and could take it, but "
-                "serving/state_cache.py has no such program for declared "
-                "pages yet")))
+                "its declared pages are positional"
+                + (" or window rings" if "window" in kinds.values() else "")
+                + " and could take it, but serving/state_cache.py has no "
+                "such program for declared pages yet")))
 
 
 def init_state_cache(model, n_slots: int, capacity: int):
@@ -376,6 +399,14 @@ class StateServingStep(ServingStep):
         if self.self_draft:
             refuse_recurrent(model, "self-drafting (rewind on reject)",
                              positional_too=False)
+            rings = [p for p, kind in leaf_kinds(model).items()
+                     if kind == "window"]
+            if rings:
+                raise ValueError(
+                    f"self-drafting is not available for "
+                    f"{type(model).__name__}: its leaf {rings[0]!r} is a "
+                    "window ring, in which a rejected draft's row has "
+                    "overwritten the position a window back")
             # the model says whether it can, and why not: the step knows
             # nothing of modules or of how a page is read
             refusal = getattr(model, "self_draft_refusal",
@@ -392,9 +423,13 @@ class StateServingStep(ServingStep):
 
     @property
     def no_wrap(self):
-        return ("a recurrent state forbids ring wrap" if self._recurrent else
-                "a declared page has no ring wrap (the model's decode write "
-                "drops past the capacity)")
+        kinds = set(self._kinds.values())
+        if "recurrent" in kinds:
+            return "a recurrent state forbids ring wrap"
+        if "positional" in kinds:
+            return ("a declared page has no ring wrap (the model's decode "
+                    "write drops past the capacity)")
+        return None         # window rings alone: they wrap by nature
 
     def _init_pages(self, model, params, cache_dtype):
         # each leaf has the dtype the model declares: no ``cache_dtype``
@@ -403,7 +438,9 @@ class StateServingStep(ServingStep):
         self.dm_chunk = None
         self.cache = init_state_cache(model, self.n_slots, self.capacity)
         # read off the declared tree once: a trace of the model's init
-        self._recurrent = recurrent_leaves(model)
+        self._kinds = leaf_kinds(model)
+        self._recurrent = [p for p, kind in self._kinds.items()
+                           if kind == "recurrent"]
         return params
 
     def _decode_program(self, params, cache, tokens):
@@ -459,7 +496,8 @@ class StateServingStep(ServingStep):
 
     def _export_rows(self, slot, fill):
         # whatever a leaf means: a recurrent state has no rows to cut at
-        # ``fill``; a page is taken whole
+        # ``fill``, a ring's columns are addressed by the cursor that
+        # travels with them; a page is taken whole
         return jax.tree_util.tree_map(
             lambda page: np.asarray(  # dlint: disable=DL121 — sanctioned migration pull
                 page[slot]), self.cache)
