@@ -92,13 +92,14 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.ops import kv_attention, latent_attention
+from chainermn_tpu.ops import kda_state, kv_attention, latent_attention
 from chainermn_tpu.ops.kv_attention import (column_blocks as _blocks,
                                             write_window as _write_window)
 from chainermn_tpu.parallel.expert_share import HeldExperts, RouteStats
 
 __all__ = ["GQAMixer", "HybridLM", "HybridBlock", "HyperConnection",
-           "KDAMixer", "MLAMixer", "MTPModule", "RMSNorm", "SwiGLU", "kda_chunk", "kda_step",
+           "KDAMixer", "MLAMixer", "MTPModule", "RMSNorm", "SwiGLU", "kda_chunk",
+           "kda_decode_step", "kda_step",
            "latent_chunk_attention", "latent_decode_attention",
            "layer_pattern", "sinkhorn", "yarn_inv_freq", "yarn_mscale"]
 
@@ -155,6 +156,29 @@ def kda_step(q, k, v, g, beta, state):
     s = s + (beta[..., None] * k)[..., None] * (v - ks)[..., None, :]
     o = (q[..., None] * s).sum(-2)
     return o, s
+
+
+def kda_decode_step(q, k, v, g, beta, state, live):
+    """The decode step's token of the recurrence: :func:`kda_step`'s
+    arguments and ``live [B]``, the rows that take a token (any other row
+    comes with ``g = 0, beta = 0`` and keeps its state in both forms).
+
+    Two forms, chosen here at trace time by what the call shows
+    (``ops/kda_state.py::step_kernel_refusal``; the choice is noted for
+    whoever traces the program, ``record_paths("state_step")``, as
+    ``"kernel"`` or ``"xla:<reason>"``): on a TPU, with a float32 state
+    that one device holds and ``d_k``, ``d_v`` whole lane tiles, ONE Pallas
+    kernel a call (``kda_step_fwd``) that visits the live rows' state only,
+    reads each block once and writes it once in place, and returns zeros as
+    a dead row's ``o`` (nobody reads it); else :func:`kda_step`, which
+    streams every row's state through two reductions and a write. Same
+    operands and order of operations in both."""
+    refusal = kda_state.step_kernel_refusal(q, v, state)
+    latent_attention.note_path(
+        "kernel" if refusal is None else f"xla:{refusal}", kda_state.PATHS)
+    if refusal is None:
+        return kda_state.kda_step_fwd(q, k, v, g, beta, state, live)
+    return kda_step(q, k, v, g, beta, state)
 
 
 def _pair_matrices(q, k, gc):
@@ -305,8 +329,10 @@ class KDAMixer(nn.Module):
         v = v.reshape(b, l, h, dv)
         if l == 1:
             with jax.named_scope("kda_step"):
-                o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                    beta[:, 0], state)
+                one = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+                # a full forward of one token differentiates through it
+                o, state = (kda_decode_step(*one, real[:, 0]) if self.decode
+                            else kda_step(*one))
                 o = o[:, None]
         else:
             with jax.named_scope("kda_chunk"):

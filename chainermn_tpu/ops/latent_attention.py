@@ -40,7 +40,8 @@ row's fill neither copied nor computed.
 :func:`chunk_kernel_refusal` and :func:`decode_kernel_refusal` are the
 dispatchers' rules: which calls a kernel can serve, by what it can see at
 trace time. :func:`record_paths` lets the caller that traces a program learn
-which path each call took.
+which path each call took (and, under another kind, which path any other
+dispatcher of the repo took: ``ops/kda_state.py``).
 """
 
 from __future__ import annotations
@@ -96,20 +97,26 @@ _trace = threading.local()
 
 
 @contextlib.contextmanager
-def record_paths():
+def record_paths(kind: str = "attention"):
     """Trace-time scope: yields a list that receives, in call order, the
-    path every chunk call traced inside took (``"kernel"`` or
-    ``"loop:<reason>"``)."""
-    before = getattr(_trace, "paths", None)
-    _trace.paths = paths = []
+    path every dispatcher of ``kind`` traced inside took: the latent
+    attention's calls (``"kernel"`` or ``"loop:<reason>"``) by default,
+    the recurrent state step's (``ops/kda_state.py``: ``"kernel"`` or
+    ``"xla:<reason>"``) under ``"state_step"``. Scopes of different kinds
+    nest without seeing each other."""
+    scopes = getattr(_trace, "paths", None)
+    if scopes is None:
+        scopes = _trace.paths = {}
+    before = scopes.get(kind)
+    scopes[kind] = paths = []
     try:
         yield paths
     finally:
-        _trace.paths = before
+        scopes[kind] = before
 
 
-def note_path(path: str) -> None:
-    paths: Optional[List[str]] = getattr(_trace, "paths", None)
+def note_path(path: str, kind: str = "attention") -> None:
+    paths: Optional[List[str]] = getattr(_trace, "paths", {}).get(kind)
     if paths is not None:
         paths.append(path)
 

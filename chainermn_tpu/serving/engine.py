@@ -875,6 +875,8 @@ class Engine:
                 self._eos, remaining, live, park, cfg.decode_k)
             if enq and self.steps.decode_attention:
                 enq.set(decode_attention=self.steps.decode_attention)
+            if enq and self.steps.state_step:
+                enq.set(state_step=self.steps.state_step)
         self._admit_ahead()
         with tracing.span("engine.decode.wait"):
             toks = np.asarray(toks_dev)         # [n, k] int32 — the ONLY

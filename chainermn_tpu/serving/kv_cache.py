@@ -467,7 +467,7 @@ def decode_k_apply(model, params, cache, tokens, keys, temps, top_ks,
 
 
 def _forms(paths) -> Optional[str]:
-    """The forms a traced program's latent attention calls took
+    """The forms a traced program's dispatched calls of one kind took
     (``record_paths``), as one string; None where it has no such call."""
     return ",".join(sorted(set(paths))) or None
 
@@ -515,6 +515,9 @@ class ServingStep:
         #: (latent_decode_attention); None before such a program exists
         #: and for a model that has no such call
         self.decode_attention: Optional[str] = None
+        #: the same for the ``decode_k`` program's recurrent state step
+        #: ("kernel" or "xla:<reason>", models/hybrid.py::kda_decode_step)
+        self.state_step: Optional[str] = None
         self._prefill_jits: Dict[tuple, Any] = {}
         self._prefill_sampled_jits: Dict[tuple, Any] = {}
         self._prefill_chunk_jits: Dict[tuple, Any] = {}
@@ -751,11 +754,13 @@ class ServingStep:
             def _decode_k(params, cache, tokens, keys, temps, top_ks,
                           eos_ids, remaining, live, park, _k=kk):
                 self.decode_k_traces += 1   # trace-time only
-                with self._page_write(), record_paths() as paths:
+                with self._page_write(), record_paths() as paths, \
+                        record_paths("state_step") as state_paths:
                     out = self._decode_k_program(
                         params, cache, tokens, keys, temps, top_ks,
                         eos_ids, remaining, live, park, _k)
                 self.decode_attention = _forms(paths)
+                self.state_step = _forms(state_paths)
                 return out
 
             kw = {}

@@ -35,6 +35,36 @@ import jax  # noqa: E402
 jax.config.update("jax_platforms", "cpu")  # holds even if jax was imported
 #                                            before the env var was set
 
+# ONE persistent compilation cache a run, new every run. Most of a test's
+# time is XLA compiling, and the suites compile the same toy programs over
+# and over: every ``Engine`` jits its own closures, every file sets its toy
+# model up again, every xdist worker starts with nothing. With no threshold
+# on an entry's compile time or size, a program is compiled once a run, by
+# the worker that meets it first (tier-1 here: 1,263 s -> 895 s of the
+# 1,470 s limit, PR 40). The process that makes the directory (the xdist
+# controller; its workers inherit ``_RUN_CACHE``) removes it when it exits,
+# so no run sees another's entries: a fresh function still reads "miss" in
+# the compile log (test_tracing.py). Set in ``jax.config`` and NOT as
+# ``JAX_COMPILATION_CACHE_DIR``: the tests' child processes keep what
+# ``utils.use_compile_cache`` gives them, because XLA's CPU loader writes a
+# long line to stderr at every hit, and a child whose stderr is a pipe that
+# nobody reads yet (fleet_tests/test_socket_plane.py) blocks on it. An
+# operator's ``JAX_COMPILATION_CACHE_DIR`` wins untouched.
+_RUN_CACHE = "CHAINERMN_TPU_TESTS_JAX_CACHE"
+if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+    if _RUN_CACHE not in os.environ:
+        import atexit
+        import shutil
+        import tempfile
+
+        os.environ[_RUN_CACHE] = tempfile.mkdtemp(
+            prefix="chainermn_tpu_tests_jax_cache_")
+        atexit.register(shutil.rmtree, os.environ[_RUN_CACHE],
+                        ignore_errors=True)
+    jax.config.update("jax_compilation_cache_dir", os.environ[_RUN_CACHE])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+
 import pytest  # noqa: E402
 
 

@@ -5,6 +5,7 @@ scan, prefill then decode through the declared cache against the full
 forward, a right-padded row against an unpadded one, MLA's absorbed decode
 against its expanded prefill, group-limited routing, the dropless grouped
 product under a skewed batch, and the share test."""
+import contextlib
 import functools
 
 import numpy as np
@@ -16,6 +17,8 @@ import jax.numpy as jnp
 from benchmark.references import ling_hybrid as ref
 from chainermn_tpu.models.hybrid import (HybridLM, kda_chunk, kda_step,
                                          layer_pattern)
+from chainermn_tpu.ops import kda_state
+from chainermn_tpu.ops.latent_attention import record_paths
 from chainermn_tpu.parallel.expert_share import (HeldExperts,
                                                  group_limited_topk,
                                                  held_expert_ffn)
@@ -144,12 +147,44 @@ def run_cached(model, params, toks, lens, bucket, total):
     return [jnp.stack(r) for r in rows], cache
 
 
-def test_prefill_then_decode_through_the_cache_matches_the_full_forward():
-    model, params = setup()
+#: the decode step's recurrence in its two forms (``kda_decode_step``): the
+#: toy widths keep ``kda_step``; heads of 128 x 128 are whole lane tiles
+STATE_STEP = {"xla": {}, "kernel": dict(d_head=128)}
+
+
+@contextlib.contextmanager
+def state_step(form):
+    """Inside, ``kda_decode_step`` takes ``form``: for the kernel its rule
+    (``step_kernel_refusal``) is asked as it is on the chip, and the kernel
+    then runs where the model's other kernels run off the chip, in the
+    Pallas interpreter that is plain HLO. (NOT ``force_tpu_interpret_mode``:
+    that one turns every load and store of every kernel of the model into a
+    host callback that calls ``jax.numpy``, and beside the eager calls of
+    ``run_cached`` the two threads deadlock, PR 40.) On leaving, every call
+    traced inside must have noted that form."""
+    rule = kda_state.step_kernel_refusal
+
+    def on_the_chip(q, v, state):
+        with pytest.MonkeyPatch.context() as chip:
+            chip.setattr(kda_state, "on_tpu", lambda: True)
+            return rule(q, v, state)
+
+    with pytest.MonkeyPatch.context() as mp, \
+            record_paths(kda_state.PATHS) as paths:
+        if form == "kernel":
+            mp.setattr(kda_state, "step_kernel_refusal", on_the_chip)
+        yield
+    assert paths and {p.split(":")[0] for p in paths} == {form}, paths
+
+
+@pytest.mark.parametrize("form", list(STATE_STEP))
+def test_prefill_then_decode_through_the_cache_matches_the_full_forward(form):
+    model, params = setup(**STATE_STEP[form])
     toks = jnp.asarray(np.random.RandomState(1).randint(0, 256, (2, 100)))
     want = reference_logits(model, params, toks)
     lens = [50, 37]
-    rows, cache = run_cached(model, params, toks, lens, 64, 100)
+    with state_step(form):
+        rows, cache = run_cached(model, params, toks, lens, 64, 100)
     for i, n in enumerate(lens):
         np.testing.assert_allclose(
             rows[i], want[i, n - 1:n - 1 + rows[i].shape[0]], atol=5e-4)
@@ -175,15 +210,17 @@ def test_a_right_padded_prefill_installs_the_state_of_an_unpadded_one():
     assert int(padded["idx"][1]) == int(alone["idx"][0]) == 37
 
 
-def test_a_row_that_is_not_live_keeps_its_state_and_cursor():
-    model, params = setup()
+@pytest.mark.parametrize("form", list(STATE_STEP))
+def test_a_row_that_is_not_live_keeps_its_state_and_cursor(form):
+    model, params = setup(**STATE_STEP[form])
     dm = model.clone(decode=True)
     toks = jnp.asarray(np.random.RandomState(3).randint(0, 256, (2, 40)))
-    _, cache = run_cached(model, params, toks, [32, 32], 32, 36)
-    _, upd = dm.apply({"params": params, "cache": cache}, toks[:, 36:37],
-                      lengths=jnp.ones((2,), jnp.int32),
-                      live=jnp.asarray([True, False]),
-                      mutable=["cache", "stats"])
+    with state_step(form):
+        _, cache = run_cached(model, params, toks, [32, 32], 32, 36)
+        _, upd = dm.apply({"params": params, "cache": cache}, toks[:, 36:37],
+                          lengths=jnp.ones((2,), jnp.int32),
+                          live=jnp.asarray([True, False]),
+                          mutable=["cache", "stats"])
     new = upd["cache"]
     assert new["idx"].tolist() == [37, 36]
     for name in ("block_0", "block_1", "block_3"):

@@ -21,7 +21,11 @@ library, and a second file could go to a worker that cannot.
   ``latent_decode_fwd``) at both latent cells' shapes, behind the scatter
   that writes the round's latents: one custom call under its own name, the
   donated page in place, no copy of it and no ``[block, heads]`` float32
-  score array in HBM."""
+  score array in HBM.
+- The decode step's KDA recurrence (ops/kda_state.py) at
+  ``ling3-flash-serve-reasongen``'s shapes, through the dispatcher: one
+  custom call under its own name, the donated state in place, no second
+  copy of it."""
 import re
 
 import numpy as np
@@ -32,6 +36,7 @@ import jax.numpy as jnp
 
 from chainermn_tpu.models import hybrid
 from chainermn_tpu.ops import grouped_swiglu as gs
+from chainermn_tpu.ops import kda_state as ks
 from chainermn_tpu.ops import latent_attention as la
 from chainermn_tpu.ops import page_write as pw
 
@@ -227,3 +232,25 @@ def test_latent_decode_attention_compiles_in_place_for_v5e(one_chip,
         tile in s or min(hybrid.DECODE_BLOCK, t) in s or t in s)]
     # no float32 array is larger than the result
     assert max(int(np.prod(s)) for s in f32) == n * l * h * r
+
+
+def test_kda_state_step_compiles_in_place_for_v5e(one_chip, monkeypatch):
+    """``ling3-flash-serve-reasongen``: 128 slots of 32 heads of a 128 x 128
+    float32 state, 268 MB a layer; all 32 heads of a row a grid step (2 MB
+    in, 2 MB out, each double-buffered)."""
+    monkeypatch.setattr(ks, "on_tpu", lambda: True)     # Mosaic, not interpret
+    n, h, d = 128, 32, 128
+    assert ks.head_block(h, d, d) == h
+    sds = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+        shape, dt, sharding=one_chip)
+    with la.record_paths(ks.PATHS) as paths:
+        compiled = jax.jit(hybrid.kda_decode_step, donate_argnums=(5,)).lower(
+            sds((n, h, d)), sds((n, h, d)), sds((n, h, d)), sds((n, h, d)),
+            sds((n, h)), sds((n, h, d, d)), sds((n,), jnp.bool_)).compile()
+    assert paths == ["kernel"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kda_step_fwd" in text
+    mem = compiled.memory_analysis()
+    state_bytes = n * h * d * d * 4
+    assert mem.alias_size_in_bytes >= state_bytes       # the state in place
+    assert mem.temp_size_in_bytes < state_bytes // 16   # and no copy of it

@@ -212,6 +212,47 @@ def test_the_decode_span_names_the_attention_its_program_traced(
     assert {r.attrs["decode_attention"] for r in enq} == {"loop:not on a TPU"}
 
 
+def test_the_decode_span_names_the_state_step_its_program_traced(
+        profiler_session):
+    """A model with a recurrent layer: from the ``decode_k`` program's trace
+    on every ``engine.decode.enqueue`` span carries ``state_step``, the form
+    the recurrence's decode step took (``models/hybrid.py::
+    kda_decode_step``) — off the chip ``kda_step``, and why; a dense model's
+    spans carry none (``traced``' engines hold no such layer)."""
+    from chainermn_tpu.models.hybrid import HybridLM
+
+    model = HybridLM(vocab=64, d_model=32, n_heads=2, d_head=16, d_ff=48,
+                     max_len=64, pattern=(("kda", "dense"),))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = Engine(model, params, EngineConfig(
+        n_slots=2, capacity=64, buckets=(16, 64), decode_k=2,
+        prefill_cohort=2))
+    rs = np.random.RandomState(0)
+    for n in (5, 9):
+        eng.submit(rs.randint(0, 64, (n,)).astype(np.int32),
+                   max_new_tokens=5)
+    tracing.clear()
+    with profiler_session():
+        eng._admit(float("inf"))
+        assert eng.steps.state_step is None             # nothing traced yet
+        eng.run_until_drained()
+    rows = tracing.rows()
+    tracing.clear()
+    enq = [r for r in rows if r.name == "engine.decode.enqueue"]
+    assert len(enq) >= 2 and eng.steps.decode_k_traces == 1
+    assert eng.steps.state_step == "xla:d_k 16, d_v 16 are not both " \
+        "multiples of 128"
+    assert {r.attrs["state_step"] for r in enq} == {eng.steps.state_step}
+    assert not any("decode_attention" in r.attrs for r in enq)
+
+
+def test_a_decode_program_without_a_recurrence_names_no_state_step(traced):
+    enq = [r for r in traced["rows"] if r.name == "engine.decode.enqueue"]
+    assert enq and not any("state_step" in r.attrs for r in enq)
+    assert traced["engine"].steps.state_step is None
+
+
 def test_the_step_span_sees_the_queue_it_started_with(traced):
     its = _iterations(traced["rows"])
     first, last = its[0][0], its[-1][0]
