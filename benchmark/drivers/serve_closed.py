@@ -401,6 +401,19 @@ def run(run):
         "chips": 1, "peaks": run.peaks, "config": run.config, "workload": w,
         "trace": run.trace, "spans": run.spans,
     }
+    iters = run.spans.named("engine.step", lo, hi)
+    steps_ms = sorted(1e3 * (e - s) for s, e in iters)
+    print(f"window iterations {len(steps_ms)}: engine.step ms median "
+          f"{steps_ms[len(steps_ms) // 2]:.2f} mean "
+          f"{sum(steps_ms) / len(steps_ms):.2f} lowest {steps_ms[0]:.2f} "
+          f"highest {steps_ms[-1]:.2f}; outside engine.step "
+          f"{1e3 * win['elapsed'] - sum(steps_ms):.1f} ms of the window",
+          flush=True)
+    # a run that stalls says where: one long iteration, or all of them slow
+    longest = sorted(iters, key=lambda se: se[0] - se[1])[:3]
+    print("longest iterations (ms at s into the window): " + ", ".join(
+        f"{1e3 * (e - s):.1f} at {s - lo:.2f}" for s, e in longest),
+        flush=True)
     for name in ("ttft_s", "tpot_s"):
         xs = sorted(facts[name])
         if xs:

@@ -9,12 +9,22 @@ The loop, the traffic, the window and the sampling of finished requests are
   (a layer's expert kernels alone are 1.5 GB; their float32 normals must not
   all exist at once).
 * seeded weights for heterogeneous layers (``make_leaf``): stacked expert
-  kernels ``[E, d, f]`` have fan-in ``d``, not ``E``; the expert bias is
-  0.02 N; ``a_log`` and ``dt_bias`` are drawn so that the per-channel decay
+  kernels ``[E, d, f]`` have fan-in ``d``, not ``E``; the expert bias
+  STARTS at 0.02 N (what this cell serves is ``balanced_biases``' below);
+  ``a_log`` and ``dt_bias`` are drawn so that the per-channel decay
   ``α = exp(-5 σ(exp(a_log)(W_f x + dt_bias)))`` lies in about 0.9–0.999 a
   token, as in a trained model, so the state holds hundreds of tokens and an
   error in carrying it shows in the logits; everything else follows
   ``harness/weights.py``'s rules.
+* ``balanced_biases`` — every expert layer's ``router_bias`` is what
+  ``noaux_tc``'s balancing rule comes to rest at
+  (``references/balance.py``, the one rule, handed ``ling_hybrid``'s
+  ``route`` with its sigmoid scores and its group choice), run by the
+  REFERENCE layer after layer on a seeded probe of ``check.balance_tokens``
+  tokens in ``check.balance_sequences`` sequences; program and reference
+  are handed the same float32 arrays (``Leaves``). It stands for the
+  published ``moe_router_enable_expert_bias: true``: a trained Ling arrives
+  with that bias at rest.
 * ``reference_gaps`` — what the timed path served against
   ``references/ling_hybrid.py``'s full forward on prompt + served tokens,
   two readings, each held twice: (1) the gap by which a served greedy
@@ -49,17 +59,23 @@ served tokens — reads far outside what flips do to any row or token, and is
 held by the largest, with limits between the sound program's largest over
 all seeds and what such a fault reads (PERF.md §4).
 
-Every weight is drawn from ``--seed``. That costs steadiness: which experts
-are popular — the router's columns, its 0.02 N bias, and the direction the
-blocks upstream give the hidden state — decides what share of the pairs
-lands on the 128 held experts and how many of their kernels a step reads,
-and those reads are a sixth of a step. Unlike a dense model's, this model's
-weights change how long a step takes (PERF.md §6 has the readings).
+Every weight is drawn from ``--seed``, and the one computed leaf, the choice
+bias, from those. Until PR 43 the bias was its 0.02 N noise, and that cost
+steadiness: which experts a seed's router preferred — its columns, its bias,
+the direction the blocks upstream give the hidden state — decided what
+share of the pairs landed on the 128 held experts (21.8–25.6%, one expert
+with 12 times the mean load) and how many of their kernels a step read,
+and those reads are a third of a step since PR 40: seeds spread by 1.3–2.0%
+of the median. With the bias at rest a quarter of the pairs lands here on
+every seed (PERF.md §6 has the readings of both). The balancing runs before
+``setup.build`` and on the reference's clock (``Run.reference``): it is the
+benchmark making its weights by the reference, not the program's set-up.
 
 After the window the engine's parameters and pages are dropped before the
 reference runs: the reference's float32 layer and the program's 12.4 GB do
 not fit one chip together.
 """
+import collections
 import functools
 import gc
 import time
@@ -68,6 +84,7 @@ import numpy as np
 
 from benchmark.drivers import serve_closed as base
 from benchmark.harness import runtime, weights
+from benchmark.references import balance
 from benchmark.references import ling_hybrid as ref
 # a program from before ISSUE 27 has no such module: its run of a cell of
 # this driver ends here, before any work on the device
@@ -180,8 +197,72 @@ def make_params(seed, spec, n_layers, dtype, sharding=None):
     return tree
 
 
+# -- the computed leaf ---------------------------------------------------------
+class Leaves(collections.namedtuple("Leaves", "spec biases")):
+    """What regenerates the model's weights from the seed: the tree's
+    ``{path: shape}`` and, beside the rules, the one leaf kind that is
+    computed — ``biases[i]``, expert layer ``i``'s router bias at rest."""
+
+
+ROUTER_BIAS = ("moe", "router_bias")
+
+
+def layer_maker(spec, layer, n_layers, dtype):
+    """``(seed, i, bias=None) -> block_i's leaves`` by the rules for any
+    layer ``i`` of block ``layer``'s kind (``i`` may be traced), the router
+    bias replaced by ``bias`` where one is handed in."""
+    inner = tuple((p[1:], spec[p]) for p in block_paths(spec, layer))
+
+    def make(seed, i, bias=None):
+        flat = {sub: make_leaf(seed, i, leaf_id(sub, n_layers),
+                               ("block_0",) + sub, shape, dtype)
+                for sub, shape in inner}
+        if bias is not None and ROUTER_BIAS in flat:
+            flat[ROUTER_BIAS] = bias
+        return weights.unflatten(flat)
+
+    return make
+
+
+def balanced_biases(run, spec):
+    """{expert layer: its router bias [E] float32}: ``references/balance.py``
+    with this cell's reference, weights and probe (module docstring)."""
+    import jax.numpy as jnp
+
+    cfg, chk = run.config["as_run"], run.workload["check"]
+    dtype, n = jnp.dtype(cfg["param_dtype"]), cfg["n_layers"]
+    seed = weights.seed_word(run.seed)
+    toks = balance.probe_tokens(seed, cfg["vocab"], chk["balance_tokens"],
+                                chk["balance_sequences"])
+    rest = ref.canonical_rest(make_rest(run.seed, spec, n, dtype))
+    return balance.balanced_biases(
+        ref, ref_cfg(cfg), cfg["pattern"],
+        lambda kind, layer: layer_maker(spec, layer, n, dtype), seed,
+        ref.embed(jnp.asarray(toks), rest))
+
+
+def seeded_leaves(run):
+    """(the model, its ``Leaves``): the balancing on the reference's clock,
+    and outside every ``setup.*`` span, so that what it compiles is not
+    counted as the program's set-up."""
+    import jax.numpy as jnp
+
+    cfg = run.config["as_run"]
+    model, spec = model_and_spec(cfg, jnp.dtype(cfg["compute_dtype"]))
+    t0 = time.perf_counter()
+    with run.reference():
+        biases = balanced_biases(run, spec)
+        for b in biases.values():
+            b.block_until_ready()
+    print(f"router biases at rest: {len(biases)} expert layers in "
+          f"{time.perf_counter() - t0:.1f} s (on the reference's clock)",
+          flush=True)
+    return model, Leaves(spec, biases)
+
+
 # -- the engine ----------------------------------------------------------------
-def build_engine(run):
+def build_engine(run, model, leaves):
+    import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -189,15 +270,16 @@ def build_engine(run):
 
     cfg, eng = run.config["as_run"], run.workload["engine"]
     mesh = Mesh(np.array(run.devices[:1]), ("serve",))
-    model, spec = model_and_spec(cfg, jnp.dtype(cfg["compute_dtype"]))
-    params = make_params(run.seed, spec, cfg["n_layers"],
-                         jnp.dtype(cfg["param_dtype"]),
-                         NamedSharding(mesh, P()))
-    engine = Engine(model, params, EngineConfig(
+    sharding = NamedSharding(mesh, P())
+    params = make_params(run.seed, leaves.spec, cfg["n_layers"],
+                         jnp.dtype(cfg["param_dtype"]), sharding)
+    for i, bias in leaves.biases.items():
+        params[f"block_{i}"]["moe"]["router_bias"] = jax.device_put(
+            bias, sharding)
+    return Engine(model, params, EngineConfig(
         n_slots=eng["n_slots"], capacity=eng["capacity"],
         buckets=tuple(eng["buckets"]), decode_k=eng["decode_k"],
         prefill_cohort=eng["prefill_cohort"]), mesh=mesh)
-    return engine, spec
 
 
 def ref_cfg(cfg):
@@ -221,12 +303,13 @@ def live_sample(seed, engine, k):
     return [longest] + [rest[i] for i in rs.permutation(len(rest))[:k - 1]]
 
 
-def reference_gaps(run, spec, sample, live=(), live_logits=None, quant=None,
-                   margins=False):
+def reference_gaps(run, leaves, sample, live=(), live_logits=None,
+                   quant=None, margins=False):
     """The reference's logits on prompt + served tokens for the finished
     greedy ``sample`` (stamps) and the ``live`` (slot, request) pairs, one
-    layer at a time from the seeded weights. ``live_logits`` holds the
-    program's logits rows of the live pairs. Returns the two readings
+    layer at a time from the seeded weights and ``leaves.biases``.
+    ``live_logits`` holds the program's logits rows of the live pairs.
+    Returns the two readings
     (module docstring) with what they were taken over; with ``quant`` also
     the control's (the reference computed with ``quant`` on every matmul
     operand, in the program's place); with ``margins`` the share of routed
@@ -247,17 +330,16 @@ def reference_gaps(run, spec, sample, live=(), live_logits=None, quant=None,
         seq = np.concatenate([r.prompt, np.asarray(r.tokens[:-1], np.int32)])
         toks[i, :seq.size] = seq
     kinds = [tuple(k) for k in cfg["pattern"]]
+    no_bias = jnp.zeros((0,), jnp.float32)
 
     def layer_fn(kind, layer, q):
-        inner = tuple((p[1:], spec[p]) for p in block_paths(spec, layer))
+        make = layer_maker(leaves.spec, layer, n_layers, dtype)
 
         @jax.jit
-        def f(seed, i, x):
-            blk = weights.unflatten({
-                sub: make_leaf(seed, i, leaf_id(sub, n_layers),
-                               ("block_0",) + sub, shape, dtype)
-                for sub, shape in inner})
-            p = ref.canonical_layer(blk, upcast_experts=False)
+        def f(seed, i, x, bias):
+            p = ref.canonical_layer(
+                make(seed, i, bias if bias.size else None),
+                upcast_experts=False)
             out = ref.block(x, p, kind, rcfg, q)
             if margins and kind[1] == "moe":
                 y = ref.ffn_input(x, p, kind, rcfg)
@@ -268,7 +350,8 @@ def reference_gaps(run, spec, sample, live=(), live_logits=None, quant=None,
         return f
 
     def forward(q):
-        rest = ref.canonical_rest(make_rest(run.seed, spec, n_layers, dtype))
+        rest = ref.canonical_rest(make_rest(run.seed, leaves.spec, n_layers,
+                                            dtype))
         head = jax.jit(lambda x, rest: ref.head_logits(x, rest, rcfg, q))
         fns, near, routed = {}, 0, 0
         with jax.default_matmul_precision("highest"):
@@ -276,7 +359,8 @@ def reference_gaps(run, spec, sample, live=(), live_logits=None, quant=None,
             for i, kind in enumerate(kinds):
                 if kind not in fns:
                     fns[kind] = layer_fn(kind, i, q)
-                x, m = fns[kind](seed, jnp.int32(i), x)
+                x, m = fns[kind](seed, jnp.int32(i), x,
+                                 leaves.biases.get(i, no_bias))
                 if m.size:
                     m = np.asarray(m).reshape(len(reqs), pad)
                     for j, r in enumerate(reqs):
@@ -337,7 +421,7 @@ def drop_engine(engine):
     gc.collect()
 
 
-def after_window(run, engine, spec, win, **kw):
+def after_window(run, engine, leaves, win, **kw):
     """Pick the samples, pull the live logits, free the engine, run the
     reference. Returns ``reference_gaps``'s readings."""
     chk = run.workload["check"]
@@ -351,7 +435,7 @@ def after_window(run, engine, spec, win, **kw):
     # not under run.reference(): that clock is taken off ``setup_s``, and
     # this reference runs after the window, outside set-up
     t0 = time.perf_counter()
-    gaps = reference_gaps(run, spec, sample, live, live_logits, **kw)
+    gaps = reference_gaps(run, leaves, sample, live, live_logits, **kw)
     print(f"reference after the window: {time.perf_counter() - t0:.1f} s "
           f"({len(sample)} finished + {len(live)} live sequences)",
           flush=True)
@@ -361,8 +445,9 @@ def after_window(run, engine, spec, win, **kw):
 def run(run):
     w = run.workload
     tr, chk = w["traffic"], w["check"]
+    model, leaves = seeded_leaves(run)
     with run.spans.span("setup.build"):
-        engine, spec = build_engine(run)
+        engine = build_engine(run, model, leaves)
     traffic = base.Traffic(run.seed, tr, run.config["as_run"]["vocab"])
     loop = base.ClosedLoop(engine, traffic, run.spans)
     with run.spans.span("setup.warm_up_and_ramp"):
@@ -390,7 +475,7 @@ def run(run):
                   prefill=dict(steps.prefill_traces))
     queued_at_close = len(engine.queue)
     slot_bytes = steps.slot_bytes
-    gaps = after_window(run, engine, spec, win)
+    gaps = after_window(run, engine, leaves, win)
 
     limits = chk["limits"]
     rows = gaps["state_rms_rows"]
@@ -483,14 +568,15 @@ def calibrate(run, seeds, control):
     place."""
     for seed in seeds:
         run.seed = seed
-        engine, spec = build_engine(run)
+        model, leaves = seeded_leaves(run)
+        engine = build_engine(run, model, leaves)
         loop = base.ClosedLoop(engine, base.Traffic(
             seed, run.workload["traffic"], run.config["as_run"]["vocab"]),
             run.spans)
         base.warm_up(run, engine, loop)
         win = base.window(run, loop)
         gaps = after_window(
-            run, engine, spec, win, margins=True,
+            run, engine, leaves, win, margins=True,
             quant=ref.fake_fp8 if seed in control else None)
         gaps.update(seed=seed, completed=len(win["completed"]),
                     tokens_per_s=win["tokens"] / win["elapsed"])

@@ -99,11 +99,29 @@ def check(line, metrics_declared, trace):
                 _finite(row[1], f"breakdown.{k} {row[0]!r}")
 
 
+def compared(checks):
+    """{name: {"value", "limit"}} of a driver's checks, for the line's last
+    key: every number ``correct`` compared beside its limit. What JSON cannot
+    hold as it is (an infinite reading, a tuple) goes as its ``repr``."""
+    def plain(x):
+        if x is None or isinstance(x, (int, str)):     # bool is an int
+            return x
+        if isinstance(x, float):
+            return x if math.isfinite(x) else repr(x)
+        if isinstance(x, (list, tuple)):
+            return [plain(y) for y in x]
+        return repr(x)
+
+    return {c["name"]: {"value": plain(c["value"]), "limit": plain(c["limit"])}
+            for c in checks}
+
+
 def build(*, correct, attempted, failed, values, metrics_declared, device,
-          trace, breakdown=None):
+          trace, breakdown=None, checks=None):
     """The line as text. ``values`` maps metric name -> number (or None for
     a reader that found nothing, which is left out and then fails the check
-    where the cell declares it)."""
+    where the cell declares it). ``checks`` (the driver's) go under
+    ``compared``, the line's last key."""
     units = {m["name"]: m["unit"] for m in metrics_declared}
     line = {
         "correct": bool(correct), "attempted": attempted, "failed": failed,
@@ -113,6 +131,8 @@ def build(*, correct, attempted, failed, values, metrics_declared, device,
     }
     if breakdown is not None:
         line["breakdown"] = breakdown
+    if checks is not None:
+        line["compared"] = compared(checks)
     check(line, metrics_declared, trace)
     text = json.dumps(line, allow_nan=False)
     json.loads(text)

@@ -28,7 +28,9 @@ Pre-norm residual blocks, ``x̃ = RMSNorm(x)`` with epsilon ``norm_eps``:
   two best), the ``top_k`` best experts among them, weights ``s`` over the
   chosen, normalised, times ``routed_scale``; the experts held here
   (``held_lo:held_hi``) by a dense loop, the others' pairs left out of the
-  sum; plus the shared expert.
+  sum; plus the shared expert. The cell's ``b`` is the bias that
+  ``noaux_tc``'s balancing comes to rest at, computed with ``route`` below
+  by ``references/balance.py``.
 
 ``quant`` is the hook the lower-precision control uses: it is applied to both
 operands of every matrix multiplication EXCEPT the router's, whose scores are

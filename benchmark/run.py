@@ -95,10 +95,15 @@ def measure(args, bench, cell, workload, config, devices, peaks):
             correct=correct, attempted=outcome["attempted"],
             failed=outcome["failed"], values=read_metrics(declared, facts),
             metrics_declared=declared, device=device, trace=bool(args.trace),
-            breakdown=breakdown)
+            breakdown=breakdown, checks=outcome["checks"])
     except line_mod.LineError as e:
         say(f"benchmark: no result line: {e}")
         return EXIT_BAD_LINE, None
+    # every number compared beside its limit: the last lines on standard
+    # error, as under the line's last key
+    for c in outcome["checks"]:
+        print(f"compared {c['name']}: {c['value']!r} limit {c['limit']!r} "
+              f"{'ok' if c['ok'] else 'NOT OK'}", file=sys.stderr)
     return 0, text
 
 

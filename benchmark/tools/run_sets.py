@@ -8,6 +8,9 @@ to the run.
 
     python benchmark/tools/run_sets.py --workload <cell> --seeds 1,2,3,4,5,6 \\
         [--sets 2] [--traced-seed 7] [--out chiprun_out/<file>.jsonl]
+
+The ``--out`` file also keeps, under ``said``, the last lines each run printed
+before its result line.
 """
 import argparse
 import json
@@ -56,11 +59,13 @@ def main():
     for k in range(a.sets):
         per_metric = {}
         for seed in seeds:
-            code, line, _ = one(a.workload, seed, seconds, 0)
+            code, line, earlier = one(a.workload, seed, seconds, 0)
             row = {"set": k, "seed": seed, "code": code, "line": line}
             print(json.dumps(row), flush=True)
             if out:
-                out.write(json.dumps(row) + "\n")
+                # with what the run said before its line: a run that reads
+                # far off says there where (its longest iterations)
+                out.write(json.dumps(dict(row, said=earlier[-24:])) + "\n")
                 out.flush()
             if line:
                 for name, m in line["metrics"].items():

@@ -2,10 +2,12 @@
 its configuration file against the catalog's keys, the driver end to end
 through ``run.measure`` untraced and with the recorded fixture as its trace,
 the comparison broken underneath, the fp8 control failing the limits the
-sound program passes, the seeded weight rules, the counts and the new
-readers. No number a CPU run gives is a device metric."""
+sound program passes, the seeded weight rules, the router bias run to rest
+(``references/balance.py``), the counts and the new readers. No number a CPU
+run gives is a device metric."""
 import argparse
 import copy
+import functools
 import json
 
 import numpy as np
@@ -13,7 +15,8 @@ import pytest
 
 from benchmark import run as bench_run
 from benchmark.harness import line as line_mod
-from benchmark.harness import phases, registry, runtime
+from benchmark.harness import phases, registry, runtime, weights
+from benchmark.references import balance
 from benchmark.references import ling_hybrid as ref
 
 from .test_drivers import fixture_for_trace  # noqa: F401  (a fixture)
@@ -43,8 +46,11 @@ def toy_cell():
                               decode_k=4, prefill_cohort=2)
     workload["check"].update(reference_len=96, reference_out=32,
                              min_tokens=9, sample_requests=2, sample_live=2,
+                             balance_tokens=512, balance_sequences=8,
                              limits=dict(TOY_LIMITS))
-    workload["trace"]["seconds"] = 0.2
+    # half of the 1 s window: on a loaded machine an iteration takes 0.1 s,
+    # and a reader of spans needs one that lies whole inside the sub-window
+    workload["trace"]["seconds"] = 0.5
     return bench, cell, workload, config
 
 
@@ -62,6 +68,20 @@ def measure(trace_flag=0, seed=2 ** 31 + 3):
         cell, workload, config, jax.devices()[:1],
         registry.load_peaks("TPU v5 lite"))
     return code, (json.loads(text) if text else None), bench
+
+
+def toy_run(seed):
+    """(the cell's driver, an untraced toy run of it on ``seed``)."""
+    import jax
+
+    bench, cell, workload, config = toy_cell()
+    drv = registry.load_module("drivers", workload["driver"])
+    return drv, runtime.Run(
+        t_process=0.0, args=argparse.Namespace(seed=seed, seconds=1.0,
+                                               trace=0),
+        cell=cell, workload=workload, config=config,
+        peaks=registry.load_peaks("TPU v5 lite"), devices=jax.devices()[:1],
+        scratch=str(registry.ROOT) + "/.bench_scratch")
 
 
 def test_configuration_file_holds_the_catalogs_keys_and_states_the_cut():
@@ -127,13 +147,24 @@ def test_cell_declares_the_serving_metrics_and_its_own():
     assert not any("moe" in n or "hybrid" in n for n in old)
 
 
-def test_cell_runs_end_to_end_untraced():
+def test_cell_runs_end_to_end_untraced(capsys):
     code, line, bench = measure()
+    err = capsys.readouterr().err.strip().splitlines()
     assert code == 0 and line["correct"] is True
     declared = line_mod.declared(bench, CELL, 0)
     line_mod.check(line, declared, False)
     assert set(line["metrics"]) == {m["name"] for m in declared}
     assert line["attempted"] > 0 and line["failed"] == 0
+    # every number compared, beside its limit, under the line's last key
+    assert list(line)[-1] == "compared"
+    assert set(TOY_LIMITS) < set(line["compared"])
+    for name, limit in TOY_LIMITS.items():
+        assert line["compared"][name]["limit"] == limit
+        assert 0 <= line["compared"][name]["value"] <= limit
+    # and as the last lines on standard error
+    last = err[-len(line["compared"]):]
+    assert [l.split()[1].rstrip(":") for l in last] == list(line["compared"])
+    assert all(l.startswith("compared ") and l.endswith(" ok") for l in last)
 
 
 def test_cell_runs_end_to_end_with_the_fixture_as_its_trace(
@@ -207,16 +238,7 @@ def test_a_state_that_integrates_padding_is_not_correct(monkeypatch):
 
 @pytest.mark.parametrize("seed", [5, 2 ** 31 + 9])
 def test_control_in_fp8_fails_both_limits_the_program_passes(seed):
-    import jax
-
-    bench, cell, workload, config = toy_cell()
-    drv = registry.load_module("drivers", "serve_closed_hybrid")
-    run = runtime.Run(
-        t_process=0.0, args=argparse.Namespace(seed=seed, seconds=1.0,
-                                               trace=0),
-        cell=cell, workload=workload, config=config,
-        peaks=registry.load_peaks("TPU v5 lite"), devices=jax.devices()[:1],
-        scratch=str(registry.ROOT) + "/.bench_scratch")
+    drv, run = toy_run(seed)
     gaps = next(drv.calibrate(run, [seed], {seed}))
     print(gaps)
     assert gaps["served_gap"] <= TOY_LIMITS["served_logit_gap"] \
@@ -276,11 +298,9 @@ def test_every_leaf_follows_the_seed():
 def test_one_live_row_off_fails_the_largest_and_not_the_quartile(monkeypatch):
     """A fault that hits a few slots only: the quartile over the live rows
     does not see it, the largest row does, and the run is not correct."""
-    import jax
     import jax.numpy as jnp
 
-    bench, cell, workload, config = toy_cell()
-    drv = registry.load_module("drivers", workload["driver"])
+    drv, run = toy_run(11)
     real = drv.after_window
 
     def one_row_off(run, engine, spec, win, **kw):
@@ -292,11 +312,6 @@ def test_one_live_row_off_fails_the_largest_and_not_the_quartile(monkeypatch):
         return real(run, engine, spec, win, **kw)
 
     monkeypatch.setattr(drv, "after_window", one_row_off)
-    run = runtime.Run(
-        t_process=0.0, args=argparse.Namespace(seed=11, seconds=1.0, trace=0),
-        cell=cell, workload=workload, config=config,
-        peaks=registry.load_peaks("TPU v5 lite"), devices=jax.devices()[:1],
-        scratch=str(registry.ROOT) + "/.bench_scratch")
     checks = {c["name"]: c for c in drv.run(run)["checks"]}
     assert checks["state_logit_rms"]["ok"], checks
     assert not checks["state_logit_rms_largest"]["ok"], checks
@@ -372,3 +387,141 @@ def test_reference_route_margin_and_fake_fp8():
         rest = np.delete(scores[t], chosen[t])
         assert np.isclose(rest, runner_up, atol=1e-6).any()
         assert runner_up <= scores[t, chosen[t]].min()
+
+
+# -- the router bias at rest (PR 43) -------------------------------------------
+BALANCE_SEEDS = [5, 6, 7, 2 ** 31 + 9]
+
+
+@functools.lru_cache(maxsize=None)
+def toy_leaves(seed):
+    """(driver, run, model, leaves) of a seed, balanced once a process."""
+    drv, run = toy_run(seed)
+    return (drv, run) + drv.seeded_leaves(run)
+
+
+def probe_routing(drv, run, leaves, biases):
+    """{expert layer: (chosen [T, k], biased scores [T, E])} of the
+    REFERENCE on the cell's probe, the layers' router biases ``biases[i]``
+    (the rule's 0.02 N noise where a layer has none)."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, chk = run.config["as_run"], run.workload["check"]
+    rcfg, n = drv.ref_cfg(cfg), cfg["n_layers"]
+    seed = weights.seed_word(run.seed)
+    toks = balance.probe_tokens(seed, cfg["vocab"], chk["balance_tokens"],
+                                chk["balance_sequences"])
+    rest = ref.canonical_rest(drv.make_rest(run.seed, leaves.spec, n,
+                                            jnp.float32))
+    x, out = ref.embed(jnp.asarray(toks), rest), {}
+    with jax.default_matmul_precision("highest"):
+        for i, kind in enumerate(tuple(k) for k in cfg["pattern"]):
+            p = ref.canonical_layer(drv.layer_maker(
+                leaves.spec, i, n, jnp.float32)(seed, i, biases.get(i)))
+            if kind[1] == "moe":
+                y = ref.ffn_input(x, p, kind, rcfg)
+                chosen, _, scores = ref.route(y.reshape(-1, y.shape[-1]), p,
+                                              rcfg)
+                out[i] = (np.asarray(chosen),
+                          np.asarray(scores + p["router_bias"]))
+            x = ref.block(x, p, kind, rcfg)
+    return out
+
+
+def loads(routing, n_experts):
+    return {i: np.bincount(chosen.ravel(), minlength=n_experts)
+            for i, (chosen, _) in routing.items()}
+
+
+def test_program_and_reference_are_handed_the_same_bias_arrays():
+    import jax.numpy as jnp
+
+    drv, run, model, leaves = toy_leaves(5)
+    cfg = run.config["as_run"]
+    moe = [i for i, (_, f) in enumerate(cfg["pattern"]) if f == "moe"]
+    assert sorted(leaves.biases) == moe
+    engine = drv.build_engine(run, model, leaves)
+    noise = drv.make_params(5, leaves.spec, cfg["n_layers"], jnp.float32)
+    for i in moe:
+        bias = leaves.biases[i]
+        assert bias.dtype == jnp.float32 and bias.shape == (cfg["n_experts"],)
+        served = engine.steps.params[f"block_{i}"]["moe"]["router_bias"]
+        assert served.dtype == jnp.float32
+        assert np.array_equal(np.asarray(served), np.asarray(bias))
+        # what ``reference_gaps`` builds its layer from
+        blk = drv.layer_maker(leaves.spec, i, cfg["n_layers"], jnp.float32)(
+            weights.seed_word(5), i, bias)
+        assert blk["moe"]["router_bias"] is bias
+        assert not np.array_equal(
+            np.asarray(bias), np.asarray(noise[f"block_{i}"]["moe"]
+                                         ["router_bias"]))
+    # a dense layer has no such leaf and takes none
+    dense = drv.layer_maker(leaves.spec, 0, cfg["n_layers"], jnp.float32)(
+        weights.seed_word(5), 0, leaves.biases[moe[0]])
+    assert "moe" not in dense
+
+
+@pytest.mark.parametrize("seed", [5, 2 ** 31 + 9])
+def test_balanced_load_is_even_on_the_probe_where_the_noise_bias_is_not(seed):
+    drv, run, _, leaves = toy_leaves(seed)
+    e = run.config["as_run"]["n_experts"]
+    over = lambda load: load.max() / load.mean()
+    rest = {i: over(l) for i, l in loads(
+        probe_routing(drv, run, leaves, leaves.biases), e).items()}
+    noise = {i: over(l) for i, l in loads(
+        probe_routing(drv, run, leaves, {}), e).items()}
+    assert all(v < 1.2 for v in rest.values()), rest       # the issue: < 2
+    assert all(noise[i] > 1.5 * rest[i] for i in rest), (rest, noise)
+    assert max(noise.values()) > 2.0, noise
+
+
+@pytest.mark.parametrize("seed", BALANCE_SEEDS)
+def test_share_of_pairs_on_the_held_experts_is_held_over_all(seed):
+    drv, run, _, leaves = toy_leaves(seed)
+    cfg = run.config["as_run"]
+    want = (cfg["held_hi"] - cfg["held_lo"]) / cfg["n_experts"]
+    for i, load in loads(probe_routing(drv, run, leaves, leaves.biases),
+                         cfg["n_experts"]).items():
+        held = load[cfg["held_lo"]:cfg["held_hi"]].sum() / load.sum()
+        assert abs(held - want) < 0.01, (i, held)
+
+
+def test_balanced_choice_stays_inside_the_kept_groups():
+    drv, run, _, leaves = toy_leaves(5)
+    cfg = run.config["as_run"]
+    ng, keep, e = cfg["n_group"], cfg["topk_group"], cfg["n_experts"]
+    for i, (chosen, biased) in probe_routing(drv, run, leaves,
+                                             leaves.biases).items():
+        t = biased.shape[0]
+        two_best = np.sort(biased.reshape(t, ng, e // ng), -1)[..., -2:]
+        kept = np.argsort(-two_best.sum(-1), -1, kind="stable")[:, :keep]
+        groups = chosen // (e // ng)
+        assert (groups[:, :, None] == kept[:, None, :]).any(-1).all(), i
+        assert chosen.shape == (t, cfg["top_k"])
+        assert all(len(set(row)) == cfg["top_k"] for row in chosen)
+
+
+@pytest.mark.parametrize("module", ["xing_mhc", "deepseek_mtp",
+                                    "laguna_mixed"])
+def test_the_one_rule_equals_each_older_copy_bit_for_bit(module):
+    """The three later cells' references carry copies of ``balance_bias``
+    written against their own routers (PR 43 left them: their files stay as
+    they are). On the same tokens the one rule, handed such a module, comes
+    to rest at the same bits."""
+    import jax
+    import jax.numpy as jnp
+
+    old = registry.load_module("references", module)
+    rs = np.random.RandomState(3)
+    y = jnp.asarray(rs.randn(96, 16) + 0.5, jnp.float32)
+    p = {"router": jnp.asarray(rs.randn(16, 32), jnp.float32),
+         "router_bias": jnp.asarray(0.02 * rs.randn(32), jnp.float32)}
+    cfg = dict(n_group=4, topk_group=2, top_k=4, routed_scale=2.5)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(jax.jit(
+            lambda y, p: old.balance_bias(y, p, cfg))(y, p))
+        got = np.asarray(jax.jit(
+            lambda y, p: balance.balance_bias(old, y, p, cfg))(y, p))
+    assert np.array_equal(got, want)
+    assert not np.array_equal(got, np.asarray(p["router_bias"]))

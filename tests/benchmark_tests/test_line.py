@@ -107,3 +107,33 @@ def test_declared_metrics_follow_benchmark_json():
     dp1 = {m["name"] for m in line_mod.declared(bench, "gpt2m-train-dp1", 1)}
     dp4 = {m["name"] for m in line_mod.declared(bench, "gpt2m-train-dp4", 1)}
     assert dp4 - dp1 == {"train_collective_exposed_pct"}
+
+
+def test_every_number_compared_stands_beside_its_limit_under_the_last_key():
+    checks = [
+        {"name": "served_logit_gap", "value": 0.07, "limit": 0.2, "ok": True},
+        {"name": "state_logit_rms", "value": float("inf"), "limit": 0.28,
+         "ok": False},
+        {"name": "served_tokens_compared", "value": 900, "limit": ">= 256",
+         "ok": True},
+        {"name": "cursors_off", "value": [(3, 17, 18)], "limit": [],
+         "ok": False}]
+    g = good(True)
+    text = line_mod.build(
+        correct=False, attempted=10, failed=0,
+        values={k: v["value"] for k, v in g["metrics"].items()},
+        metrics_declared=DECL, device=g["device"], trace=True,
+        breakdown={"device_ops": [["fusion", 1.0]], "idle_gaps": []},
+        checks=checks)
+    line = json.loads(text)
+    assert list(line)[-1] == "compared"
+    assert line["compared"] == {
+        "served_logit_gap": {"value": 0.07, "limit": 0.2},
+        "state_logit_rms": {"value": "inf", "limit": 0.28},
+        "served_tokens_compared": {"value": 900, "limit": ">= 256"},
+        "cursors_off": {"value": [[3, 17, 18]], "limit": []}}
+    # a line without checks is as it was
+    assert "compared" not in json.loads(line_mod.build(
+        correct=True, attempted=10, failed=0,
+        values={k: v["value"] for k, v in g["metrics"].items()},
+        metrics_declared=DECL, device=g["device"], trace=True))

@@ -140,10 +140,10 @@ def test_cell_declares_the_serving_metrics_and_its_own():
         "serve_queue_age_s", "serve_admitted_per_iter",
         "serve_prefill_pad_pct", "serve_grouped_swiglu_roofline",
         "serve_moe_experts_touched_pct",
-        "serve_moe_load_max_over_mean"} == set(per_layer)
+        "serve_moe_load_max_over_mean"} <= set(per_layer)
     for name in NEW:
         m = per_layer[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         reader = registry.load_module("metrics", name)
         assert (reader.UNIT, reader.SOURCE, reader.LAYER, reader.MOVES) == (
             m["unit"], m["source"], m["layer"], m["moves"])

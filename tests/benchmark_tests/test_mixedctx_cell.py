@@ -219,10 +219,10 @@ def test_cell_declares_the_serving_metrics_and_its_own():
         "serve_moe_pairs_held_pct", "setup_programs_lowered",
         "setup_cache_misses", "setup_trace_lower_s",
         "setup_backend_compile_s", "setup_first_run_s",
-        "setup_build_s"} == set(per_layer)
+        "setup_build_s"} <= set(per_layer)
     for name in NEW:
         m = per_layer[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         reader = registry.load_module("metrics", name)
         assert (reader.UNIT, reader.SOURCE, reader.LAYER, reader.MOVES) == (
             m["unit"], m["source"], m["layer"], m["moves"])

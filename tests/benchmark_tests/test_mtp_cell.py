@@ -151,10 +151,10 @@ def test_cell_declares_the_serving_metrics_and_its_own():
         "serve_queue_age_s", "serve_admitted_per_iter",
         "serve_prefill_pad_pct", "serve_grouped_swiglu_roofline",
         "serve_moe_experts_touched_pct", "serve_moe_load_max_over_mean",
-        "serve_moe_pairs_held_pct"} == set(per_layer)
+        "serve_moe_pairs_held_pct"} <= set(per_layer)
     for name in NEW:
         m = per_layer[name]
-        assert m["workloads"] == [CELL] and m["moves"] == "serve_tokens_per_s"
+        assert CELL in m["workloads"] and m["moves"] == "serve_tokens_per_s"
         reader = registry.load_module("metrics", name)
         assert (reader.UNIT, reader.SOURCE, reader.LAYER, reader.MOVES) == (
             m["unit"], m["source"], m["layer"], m["moves"])
@@ -165,10 +165,12 @@ def test_cell_declares_the_serving_metrics_and_its_own():
                   "xing4-serve-longdoc"):
         names = {m["name"] for m in line_mod.declared(bench, other, 1)}
         assert not names & set(NEW)
-    # the new entries stand at the end of their lists
-    assert bench["configs"][-1]["name"] == CONFIG
-    assert bench["workloads"][-1]["name"] == CELL
-    assert [m["name"] for m in bench["per_layer"][-4:]] == list(NEW)
+    # the cell's entries stand in their lists, in the order they were added,
+    # wherever later configurations' entries stand
+    assert CONFIG in [c["name"] for c in bench["configs"]]
+    assert CELL in [w["name"] for w in bench["workloads"]]
+    assert [m["name"] for m in bench["per_layer"]
+            if m["name"] in NEW] == list(NEW)
 
 
 def test_the_round_of_128_sizes_is_the_issues_traffic():
