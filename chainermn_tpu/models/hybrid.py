@@ -770,7 +770,19 @@ class GQAMixer(nn.Module):
     length)``, the ring the chunk's last ``min(length, window)`` rows at
     ``position mod window``; with ``slots`` the call's rows are rows
     ``slots`` of leaves that hold more); ``L == 1`` a decode step. A row
-    that is not live leaves its leaves as they were."""
+    that is not live leaves its leaves as they were.
+
+    A chunk's attention (scopes ``gqa_chunk``, ``swa_chunk``, with the
+    page's and the ring's writes) goes through ``kv_attention``'s two
+    dispatchers, which choose at trace time by what the call shows — widths,
+    dtype, placement, platform; no model name, no flag: ONE Pallas kernel
+    for both kinds of leaf (``kv_chunk_fwd``: scores in VMEM, the work
+    following each row's cursor and ``lengths``, so the queries past a row's
+    length come back zero) or the ``jax.numpy`` bodies, and note which for
+    whoever traces the program (``record_paths``; the serving step puts it
+    on ``engine.admit`` as ``chunk_attention``). The call without a cache
+    (``decode=False``: the chunk is the page, before an empty ring) takes the
+    same dispatchers and the same rule."""
     n_heads: int
     n_kv_heads: int
     d_head: int
@@ -839,7 +851,7 @@ class GQAMixer(nn.Module):
         if l > 1 and ring:
             with jax.named_scope("swa_chunk"):
                 o = kv_attention.ring_chunk_attention(
-                    q, k, v, k_leaf, v_leaf, pos, slots, scale)
+                    q, k, v, k_leaf, v_leaf, pos, slots, scale, n)
                 if self.decode:
                     k_leaf, v_leaf = (kv_attention.write_ring(
                         leaf, new, pos, n, slots)
@@ -852,7 +864,7 @@ class GQAMixer(nn.Module):
                                       for leaf, new in ((k_leaf, k),
                                                         (v_leaf, v)))
                 o, k_leaf, v_leaf = kv_attention.page_chunk_attention(
-                    q, k_leaf, v_leaf, pos, rows, scale)
+                    q, k_leaf, v_leaf, pos, rows, scale, n)
         else:
             alive = n > 0
             with jax.named_scope("swa_decode" if ring else "gqa_decode"):

@@ -284,3 +284,67 @@ def test_an_unknown_mixer_kind_raises():
         with pytest.raises(ValueError, match=said):
             model(**missing).init(jax.random.PRNGKey(0),
                                   np.zeros((1, 8), np.int32))
+
+
+# ---------------------------------------------------------------------------
+# the chunk attention's dispatchers, through the engine
+# ---------------------------------------------------------------------------
+
+def lane_wide_engine():
+    """The served pattern at a head of 128 (the least the chunk kernel
+    takes), 4 and 6 query heads over 2 KV heads, window 8, chunks of two
+    windows into 3 slots of 64."""
+    from chainermn_tpu.serving import Engine, EngineConfig
+
+    m = model(d_head=128, gqa_heads=4, swa_heads=6)
+    params = m.init(jax.random.PRNGKey(0),
+                    np.zeros((1, 8), np.int32))["params"]
+    return Engine(m, params, EngineConfig(
+        n_slots=3, capacity=CAP, buckets=(CAP,), decode_k=4,
+        prefill_chunk=2 * WINDOW, prefill_cohort=2))
+
+
+def streams(eng):
+    """Five prompts — under a window, a chunk exactly, several chunks with a
+    partial last one — greedy and sampled in turn."""
+    rs = np.random.RandomState(0)
+    reqs = [eng.submit(rs.randint(0, VOCAB, (n,)), max_new_tokens=new,
+                       **({} if i % 2 == 0 else
+                          dict(temperature=0.8, top_k=20, seed=i)))
+            for i, (n, new) in enumerate([(5, 6), (16, 4), (37, 7), (23, 5),
+                                          (44, 6)])]
+    eng.run_until_drained()
+    assert all(r.state == "done" for r in reqs)
+    return [list(r.tokens) for r in reqs]
+
+
+def test_chunk_spans_name_the_loop_and_the_kernel_serves_the_same_tokens(
+        profiler_session, monkeypatch):
+    """Every ``engine.admit`` span of a chunk dispatch names the form the
+    chunk program's K/V attention took when it was traced: off the chip the
+    ``jax.numpy`` bodies, all five layers for the one reason. With the rule
+    alone patched the same engine takes the kernel on all five (its body in
+    the Pallas interpreter) and serves the same streams: slots that permute,
+    a cohort with a sentinel row, last chunks that are partial, rings not
+    yet full and wrapped."""
+    from chainermn_tpu import tracing
+    from chainermn_tpu.ops import latent_attention
+
+    eng = lane_wide_engine()
+    assert eng.steps.chunk_attention is None        # nothing traced yet
+    tracing.clear()
+    with profiler_session():
+        want = streams(eng)
+    rows = tracing.rows()
+    tracing.clear()
+    admits = [r for r in rows if r.name == "engine.admit"]
+    assert len(admits) >= 5
+    assert {r.attrs["chunk_attention"] for r in admits} == {
+        "loop:not on a TPU"}
+    assert eng.steps.chunk_attention == "loop:not on a TPU"
+    assert eng.steps.prefill_chunk_traces == {(2, 2 * WINDOW): 1}
+    monkeypatch.setattr(latent_attention, "on_tpu", lambda: True)
+    eng = lane_wide_engine()
+    got = streams(eng)
+    assert eng.steps.chunk_attention == "kernel"
+    assert got == want

@@ -25,7 +25,13 @@ library, and a second file could go to a worker that cannot.
 - The decode step's KDA recurrence (ops/kda_state.py) at
   ``ling3-flash-serve-reasongen``'s shapes, through the dispatcher: one
   custom call under its own name, the donated state in place, no second
-  copy of it."""
+  copy of it.
+- The chunk attention over flat K/V leaves (ops/kv_attention.py::
+  ``kv_chunk_fwd``) at ``laguna-xs2-serve-mixedctx``'s shapes, through both
+  dispatchers as ``GQAMixer`` calls them: behind the page's write with the
+  pages donated (one custom call under its own name, the pages in place, no
+  copy of one) and behind the ring's lay-out; no float32 score array among
+  either program's HBM temporaries."""
 import re
 
 import numpy as np
@@ -37,6 +43,7 @@ import jax.numpy as jnp
 from chainermn_tpu.models import hybrid
 from chainermn_tpu.ops import grouped_swiglu as gs
 from chainermn_tpu.ops import kda_state as ks
+from chainermn_tpu.ops import kv_attention as kva
 from chainermn_tpu.ops import latent_attention as la
 from chainermn_tpu.ops import page_write as pw
 
@@ -254,3 +261,54 @@ def test_kda_state_step_compiles_in_place_for_v5e(one_chip, monkeypatch):
     state_bytes = n * h * d * d * 4
     assert mem.alias_size_in_bytes >= state_bytes       # the state in place
     assert mem.temp_size_in_bytes < state_bytes // 16   # and no copy of it
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("leaf", ["page", "ring"])
+def test_kv_chunk_attention_compiles_in_place_for_v5e(one_chip, monkeypatch,
+                                                      leaf, dtype):
+    """A chunk of 2,048 queries over 8 KV heads of 128: 48 query heads over
+    a page of 20 x 32,768 columns of 1,024 values (the chunk's keys and
+    values written into the donated pages, then the chunk attends them, as
+    ``GQAMixer`` does under ``gqa_chunk``), 64 over rings of 512 columns
+    (the ring laid in order before the chunk, then written, under
+    ``swa_chunk``)."""
+    monkeypatch.setattr(la, "on_tpu", lambda: True)     # the kernel, and
+    monkeypatch.setattr(kva, "on_tpu", lambda: True)    # Mosaic, not interpret
+    b, c, n, d, w = 1, 2048, 20, 128, 1024
+    h, t = (48, 32768) if leaf == "page" else (64, 512)
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def chunk(q, k, v, k_leaf, v_leaf, pos, valid, slots):
+        if leaf == "ring":
+            o = kva.ring_chunk_attention(q, k, v, k_leaf, v_leaf, pos, slots,
+                                         0.0884, valid)
+            return (o,) + tuple(kva.write_ring(a, new, pos, valid, slots)
+                                for a, new in ((k_leaf, k), (v_leaf, v)))
+        k_leaf, v_leaf = (hybrid._write_window(a, new, pos, valid, slots)
+                          for a, new in ((k_leaf, k), (v_leaf, v)))
+        return kva.page_chunk_attention(q, k_leaf, v_leaf, pos, slots, 0.0884,
+                                        valid)
+
+    with la.record_paths() as paths:
+        compiled = jax.jit(chunk, donate_argnums=(3, 4)).lower(
+            sds((b, c, h, d), dtype), sds((b, c, w), dtype),
+            sds((b, c, w), dtype), sds((n, t, w), dtype),
+            sds((n, t, w), dtype), *[sds((b,), jnp.int32)] * 3).compile()
+    assert paths == ["kernel"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "kv_chunk_fwd" in text
+    mem = compiled.memory_analysis()
+    leaf_bytes = n * t * w * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= 2 * leaf_bytes    # both leaves in place
+    if leaf == "page":
+        assert mem.temp_size_in_bytes < leaf_bytes // 8     # no copy of one
+    # what a score array (or a running maximum) would be: float32, the KV
+    # heads and their groups beside queries and columns, no axis a head wide
+    f32 = {tuple(int(x) for x in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    assert not [s for s in f32 if len(s) >= 4 and d not in s]
+    # beside a float32 leaf, no float32 array is larger than the result
+    assert max(int(np.prod(s)) for s in f32
+               if s != (n, t, w)) == b * c * h * d

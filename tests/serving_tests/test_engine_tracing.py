@@ -164,9 +164,11 @@ def test_admission_is_counted_where_it_happens(traced):
 
 
 def test_a_program_without_latent_attention_names_none(traced):
-    """``chunk_attention`` is the chunk program's latent attention as it
-    was traced (tests/serving_tests/test_state_cache.py): a dense model has
-    no such call, chunked or not, so its spans carry no such attribute."""
+    """``chunk_attention`` is the chunk program's dispatched attention as it
+    was traced — the latent page's (tests/serving_tests/test_state_cache.py)
+    or the K/V leaves' (tests/models_tests/test_kv_window_mixers.py): a dense
+    ``TransformerLM`` has no such call, chunked or not, so its spans carry
+    no such attribute."""
     admits = [r for r in traced["rows"] if r.name == "engine.admit"]
     assert admits and not any("chunk_attention" in a.attrs for a in admits)
     assert traced["engine"].steps.chunk_attention is None
