@@ -43,6 +43,7 @@ from chainermn_tpu.parallel.tensor_parallel import (
     vocab_parallel_cross_entropy,
 )
 from chainermn_tpu.parallel.ulysses import ulysses_attention
+from chainermn_tpu.ops import latent_attention, page_attention
 from chainermn_tpu.ops.page_write import write_rows
 from chainermn_tpu.ops.rotary import apply_rope, apply_rope_bhld
 
@@ -52,7 +53,12 @@ __all__ = ["TransformerLM", "TransformerBlock", "generate",
 
 def _grouped_cache_attention(q, kpage, vpage, row, window):
     """One query per row over the whole cache page, the page read once at
-    the dtype it is stored in.
+    the dtype it is stored in: the plain ``jax.numpy`` form of the decode
+    step's attention, which reads every slot's CAPACITY whatever its fill.
+    :func:`cache_decode_attention` takes it wherever the kernel that reads
+    the filled blocks alone (``ops/page_attention.py::page_decode_fwd``)
+    cannot serve, and it is that kernel's oracle: this docstring is the
+    arithmetic both are held to.
 
     q ``[b, n_heads, d]``; kpage/vpage ``[b, cap, h_kv, d]`` (ring pages:
     slot j of a row holds the newest position ≡ j mod cap); row ``[b]``
@@ -85,6 +91,36 @@ def _grouped_cache_attention(q, kpage, vpage, row, window):
     return att.reshape(b, -1, dh)
 
 
+def cache_decode_attention(q, kpage, vpage, row, window):
+    """The decode step's attention of every model that did not ask for the
+    reference: :func:`_grouped_cache_attention`'s arguments, result and
+    arithmetic, in one of two forms chosen here at trace time by what the
+    call shows (``ops/page_attention.py::decode_refusal``; the choice is
+    noted for whoever traces the program, ``record_paths``, as ``"kernel"``
+    or ``"xla:<reason>"`` — the serving step puts it on
+    ``engine.decode.enqueue`` as ``decode_attention``):
+
+    * on a TPU, with bfloat16 or float32 pages that one device holds, a
+      cache row ``[h_kv, d_head]`` of whole memory tiles 128 lanes wide, a
+      capacity in whole blocks and no window: ONE Pallas kernel a layer
+      (``page_decode_fwd``) that reads, of each slot, the blocks of columns
+      its cursor reaches and no others — a parked slot one block, a slot
+      past the capacity all of them. Its softmax is online over the blocks,
+      so it agrees with the other form within rounding, not bitwise;
+    * else :func:`_grouped_cache_attention`, which reads every slot's
+      capacity: off a TPU (every CPU test and example), ``d_head`` 64
+      (``gpt2-medium``, ``chip_smoke.py``), one KV head in bfloat16 or 3, 6,
+      12 of them, pages split over a mesh, an ``attention_window`` (no cell
+      serves a windowed dense model). int8-block pages arrive here unpacked
+      to float32 and are served as float32 pages are."""
+    refusal = page_attention.decode_refusal(q, kpage, window)
+    latent_attention.note_path(
+        "kernel" if refusal is None else f"xla:{refusal}")
+    if refusal is None:
+        return page_attention.page_decode_fwd(q, kpage, vpage, row)
+    return _grouped_cache_attention(q, kpage, vpage, row, window)
+
+
 class TransformerBlock(nn.Module):
     """Pre-LN block: causal attention + (dense | MoE) FFN.
 
@@ -102,6 +138,16 @@ class TransformerBlock(nn.Module):
     chunked contract assumes NO ring wrap during prefill (prompt length
     <= capacity — cache slot j holds absolute position j); garbage
     beyond each row's fill level is masked out, not read.
+
+    The one-token step (``l == 1``) attends in one of three ways.
+    ``attention="reference"`` keeps its own branch, bitwise a row of the
+    full forward (the tests' oracle). Every other model goes through
+    :func:`cache_decode_attention`, which picks at trace time from what the
+    call shows: ONE Pallas kernel a layer that reads the blocks of columns
+    each slot's cursor reaches (on a TPU, cache rows of whole memory tiles,
+    pages one device holds, no window), else
+    :func:`_grouped_cache_attention` over the whole page. One arithmetic,
+    results within rounding of each other (docs/serving.md §Numerics).
     """
 
     d_model: int
@@ -306,13 +352,15 @@ class TransformerBlock(nn.Module):
                                           window=self.attention_window)
             elif self.attention != "reference":
                 # every model that did not ask for the oracle: the page is
-                # read once, at its stored dtype, grouped, on the MXU —
+                # read at its stored dtype, grouped, on the MXU — the
+                # filled blocks by one kernel where the call's shapes let
+                # it, else all of it (cache_decode_attention picks) —
                 # logits within tolerance of the float32 reference, NOT
                 # bitwise a row of the full forward (docs/serving.md
                 # §numerics; the branch below keeps that contract and
                 # shares no arithmetic with this one)
                 with jax.named_scope("attend_cache"):
-                    att = _grouped_cache_attention(
+                    att = cache_decode_attention(
                         q[:, 0], ck.value, cv.value, rows[..., -1],
                         self.attention_window)[:, None]
             else:

@@ -106,6 +106,32 @@ def test_fast_step_matches_oracle_step(heads, pos_emb, cursor, window,
         assert np.abs(wide - fast).max() > 1e-3
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("cursor", list(CURSORS))
+@pytest.mark.parametrize("heads", list(HEAD_LAYOUTS))
+def test_fast_step_through_the_kernel_matches_oracle_step(
+        monkeypatch, heads, cursor, dtype):
+    """The same step with the dispatcher's rule held open
+    (``cache_decode_attention`` takes ``ops/page_attention.py::
+    page_decode_fwd``, in the Pallas interpreter off the chip): the online
+    softmax over blocks of the page is held to the tolerance the one-shot
+    form is held to."""
+    from chainermn_tpu.ops import latent_attention, page_attention
+
+    monkeypatch.setattr(page_attention, "decode_refusal", lambda *a: None)
+    args = (dtype, "rope", HEAD_LAYOUTS[heads], cursor, None)
+    with latent_attention.record_paths() as paths:
+        fast, fast_cache = _step.__wrapped__("flash", *args)
+    assert set(paths) == {"kernel"}                 # every layer's call
+    want, want_cache = _step("reference", *args)
+    assert np.isfinite(fast).all()
+    np.testing.assert_allclose(fast, want, **TOLERANCE[dtype])
+    for leaf in ("k", "v", "idx"):
+        np.testing.assert_array_equal(
+            np.asarray(fast_cache["block_0"][leaf], np.float32),
+            np.asarray(want_cache["block_0"][leaf], np.float32))
+
+
 def test_positions_beyond_the_fill_reach_no_logit():
     """What a page holds beyond its slot's fill changes no bit of the
     logits, an empty slot (fill 0) included."""

@@ -31,7 +31,13 @@ library, and a second file could go to a worker that cannot.
   dispatchers as ``GQAMixer`` calls them: behind the page's write with the
   pages donated (one custom call under its own name, the pages in place, no
   copy of one) and behind the ring's lay-out; no float32 score array among
-  either program's HBM temporaries."""
+  either program's HBM temporaries.
+- The dense model's one-query decode attention (ops/page_attention.py::
+  ``page_decode_fwd``) at the served page shapes, through the model's
+  dispatcher behind the per-slot write with the pages donated: one custom
+  call under its own name, both pages in place and read where they lie (the
+  flattened view is the same bytes: no ``copy``, ``reshape`` or ``convert``
+  of a page), no score array in HBM."""
 import re
 
 import numpy as np
@@ -40,11 +46,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from chainermn_tpu.models import hybrid
+from chainermn_tpu.models import hybrid, transformer
 from chainermn_tpu.ops import grouped_swiglu as gs
 from chainermn_tpu.ops import kda_state as ks
 from chainermn_tpu.ops import kv_attention as kva
 from chainermn_tpu.ops import latent_attention as la
+from chainermn_tpu.ops import page_attention as pa
 from chainermn_tpu.ops import page_write as pw
 
 
@@ -312,3 +319,56 @@ def test_kv_chunk_attention_compiles_in_place_for_v5e(one_chip, monkeypatch,
     # beside a float32 leaf, no float32 array is larger than the result
     assert max(int(np.prod(s)) for s in f32
                if s != (n, t, w)) == b * c * h * d
+
+
+@pytest.mark.parametrize("h_kv,h,dtype", [
+    (2, 24, jnp.bfloat16),      # sc2-3b-serve-batchgen's page: 12 to 1
+    (2, 24, jnp.float32),
+    (4, 32, jnp.bfloat16),
+    (8, 32, jnp.bfloat16),
+    (8, 8, jnp.bfloat16),       # no grouping
+])
+def test_page_decode_attention_compiles_in_place_for_v5e(one_chip,
+                                                         monkeypatch, h_kv,
+                                                         h, dtype):
+    """64 slots of 2,048 columns of ``h_kv`` heads of 128: each slot's new
+    row written at its cursor into the donated pages, then one query a slot
+    attends them, as ``TransformerBlock`` does under ``cache_write`` and
+    ``attend_cache``."""
+    for op in (la, pa, pw):     # the kernels, and Mosaic, not interpret
+        monkeypatch.setattr(op, "on_tpu", lambda: True)
+    n, cap, d = 64, 2048, 128
+    sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def step(q, k_page, v_page, k_new, v_new, row):
+        k_page, v_page = pw.write_rows(k_page, v_page, k_new, v_new,
+                                       row % cap)
+        return transformer.cache_decode_attention(
+            q, k_page, v_page, row, None), k_page, v_page
+
+    page, new = sds((n, cap, h_kv, d), dtype), sds((n, 1, h_kv, d), dtype)
+    with la.record_paths() as paths:
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            sds((n, h, d), dtype), page, page, new, new,
+            sds((n,), jnp.int32)).compile()
+    assert paths == ["kernel"]
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "page_decode_fwd" in text
+    mem = compiled.memory_analysis()
+    page_bytes = n * cap * h_kv * d * jnp.dtype(dtype).itemsize
+    assert mem.alias_size_in_bytes >= 2 * page_bytes    # both pages in place
+    assert mem.temp_size_in_bytes < page_bytes // 64    # and no copy of one
+    # no operation but the two kernels gives a page, in either shape
+    name = {jnp.bfloat16: "bf16", jnp.float32: "f32"}[dtype]
+    shapes = (f"{name}[{n},{cap},{h_kv},{d}]", f"{name}[{n},{cap * h_kv},{d}]")
+    made = [line for line in text.splitlines()
+            if any(f" = {s}" in line for s in shapes)
+            and not re.search(r"\b(parameter|bitcast|custom-call|"
+                              r"get-tuple-element)\(", line)]
+    assert not made, made
+    # and beside a float32 page no float32 array is larger than the
+    # result: no score array
+    f32 = {tuple(int(x) for x in dims.split(","))
+           for dims in re.findall(r"f32\[([\d,]+)\]", text)}
+    assert max(int(np.prod(s)) for s in f32 if int(np.prod(s))
+               != n * cap * h_kv * d) == n * h * d

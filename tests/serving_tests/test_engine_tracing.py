@@ -214,6 +214,39 @@ def test_the_decode_span_names_the_attention_its_program_traced(
     assert {r.attrs["decode_attention"] for r in enq} == {"loop:not on a TPU"}
 
 
+def test_the_decode_span_names_the_dense_models_attention(profiler_session):
+    """A dense ``TransformerLM`` that did not ask for the reference: its
+    decode step's attention goes through ``models/transformer.py::
+    cache_decode_attention``, so from the ``decode_k`` program's trace on
+    every ``engine.decode.enqueue`` span says which form it took — off the
+    chip, and at a toy row, the whole-page ``jax.numpy`` form, and why (the
+    reference models of ``traced`` have no such call and name none)."""
+    model = TransformerLM(vocab=43, d_model=32, n_heads=4, n_kv_heads=2,
+                          n_layers=2, d_ff=48, max_len=64, attention="flash",
+                          pos_emb="rope")
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 4), jnp.int32))["params"]
+    eng = Engine(model, params, EngineConfig(
+        n_slots=4, capacity=32, max_new_tokens=N_NEW, prefill_cohort=2,
+        buckets=[8, 32], decode_k=2))
+    rs = np.random.RandomState(0)
+    for n in (3, 5, 12):
+        eng.submit(rs.randint(0, 43, (n,)).astype(np.int32))
+    tracing.clear()
+    with profiler_session():
+        eng._admit(float("inf"))
+        assert eng.steps.decode_attention is None       # nothing traced yet
+        eng.run_until_drained()
+    rows = tracing.rows()
+    tracing.clear()
+    enq = [r for r in rows if r.name == "engine.decode.enqueue"]
+    assert len(enq) >= 2 and eng.steps.decode_k_traces == 1
+    assert eng.steps.decode_attention == (
+        "xla:a cache row [2, 8] of float32 is not whole tiles of 128 lanes")
+    assert {r.attrs["decode_attention"] for r in enq} == {
+        eng.steps.decode_attention}
+
+
 def test_the_decode_span_names_the_state_step_its_program_traced(
         profiler_session):
     """A model with a recurrent layer: from the ``decode_k`` program's trace
